@@ -68,6 +68,11 @@ def encode_blocks(times, vbits, starts, n_points,
         encode_fn = m3tsz_tpu.encode_bits
         jitted = m3tsz_tpu._encode_bits_jit
     dispatch.counters["m3tsz_encode_device"] += 1
+    n_rows = times.shape[0]
+    if n_rows == 0:
+        return []
+    times, vbits, starts, n_points = _pad_encode_batch(
+        times, vbits, np.asarray(starts), np.asarray(n_points))
     # plan-cache attribution: did this shape bucket hit the jit cache or
     # pay a trace+compile? (compute.jit_* on /metrics); the sig keys the
     # per-program execute histogram on the batch rectangle
@@ -80,7 +85,40 @@ def encode_blocks(times, vbits, starts, n_points,
         )
     if bool(blocks.overflow):
         raise OverflowError("batched encode overflow")
-    return m3tsz_tpu.blocks_to_bytes(blocks)
+    return m3tsz_tpu.blocks_to_bytes(blocks, n_rows)
+
+
+def _pad_encode_batch(times, vbits, starts, n_points):
+    """Pad a sealed [B, T] window to half-octave shape buckets on both
+    axes. Every shard's window is its own rectangle and every distinct
+    rectangle is a compile (tens of seconds each on a TPU: an 8-shard
+    flush paid 8 of them before this), so the device encoder sees
+    bucketed shapes like the compiler and the index do. Pad rows carry
+    zero points and a real row's (unit-aligned) start; pad lanes repeat
+    the row's last timestamp (seal's monotone-tail rule). The encoder
+    reads exactly n_points lanes per row, so streams are unchanged."""
+    from m3_tpu.utils import compute_stats
+
+    B, T = times.shape
+    Bp, Tp = dispatch.next_bucket(B), dispatch.next_bucket(max(T, 1))
+    compute_stats.record_waste("encode_batch", "series", B, Bp)
+    compute_stats.record_waste("encode_batch", "points", T, Tp)
+    if Tp > T:
+        last = times[:, -1:] if T else starts[:, None]
+        times = np.concatenate(
+            [times, np.broadcast_to(last, (B, Tp - T))], axis=1)
+        vbits = np.concatenate(
+            [vbits, np.zeros((B, Tp - T), vbits.dtype)], axis=1)
+    if Bp > B:
+        times = np.concatenate(
+            [times, np.full((Bp - B, Tp), starts[0], times.dtype)])
+        vbits = np.concatenate(
+            [vbits, np.zeros((Bp - B, Tp), vbits.dtype)])
+        starts = np.concatenate(
+            [starts, np.full(Bp - B, starts[0], starts.dtype)])
+        n_points = np.concatenate(
+            [n_points, np.zeros(Bp - B, n_points.dtype)])
+    return times, vbits, starts, n_points
 
 
 def encode_blocks_ragged(times, vbits, offsets, starts,
@@ -169,8 +207,11 @@ def _decode_streams_device(streams: list[bytes], unit: TimeUnit,
                            int_optimized: bool):
     """One vmapped XLA decode over the whole group. Streams whose rows come
     back flagged (annotation/time-unit markers the kernels don't decode)
-    fall back to the scalar decoder individually. Shapes are padded to
-    powers of two so repeated groups share compiled kernels."""
+    fall back to the scalar decoder individually. Every axis is padded
+    to a shape bucket (half-octave rows, power-of-two words and points)
+    so repeated groups share compiled kernels: each (shard, block) group
+    has its own row count, and an unbucketed row axis compiled the scan
+    once per group."""
     import numpy as _np
 
     from m3_tpu.encoding.m3tsz import tpu as m3tsz_tpu
@@ -178,8 +219,12 @@ def _decode_streams_device(streams: list[bytes], unit: TimeUnit,
     from m3_tpu.utils import compute_stats
 
     maxlen = max(len(s) for s in streams)
+    n_rows = dispatch.next_bucket(len(streams))
+    compute_stats.record_waste("decode_batch", "series", len(streams),
+                               n_rows)
     words = m3tsz_tpu.bytes_to_words(
-        streams, dispatch.next_pow2((maxlen + 7) // 8))
+        streams + [b""] * (n_rows - len(streams)),
+        dispatch.next_pow2((maxlen + 7) // 8))
     # a datapoint costs >= 2 bits, so the longest stream bounds the points
     max_points = dispatch.next_pow2(maxlen * 4 + 16)
     # padding-waste ledger: real stream words vs the pow2 word rectangle

@@ -34,8 +34,8 @@ This module lowers the whole boolean combine onto the compute plane:
   bit-identical at any device count.
 
 Dispatch doctrine: the executor's scalar walk stays the counted
-fallback — unpacked segments, nested boolean shapes, small work and
-cold-jax processes never pay device overhead, and every fallback is
+fallback — unpacked segments, nested boolean shapes and small work
+never pay device overhead, and every fallback is
 recorded with a reason (`querystats` index block, `dispatch` counters).
 """
 
@@ -60,10 +60,6 @@ from m3_tpu.utils import dispatch, querystats
 # same economics as the executor's bitmap threshold: below this many
 # (selected postings + doc-space) elements the sorted-array walk wins
 WORK_THRESHOLD = 1 << 17
-
-# operator hatch accepting the jax import on a query thread (see
-# dispatch.jax_ready: a query thread must never be the first importer)
-FORCE_ENV = "M3_TPU_INDEX_COMPILE"
 
 _LEAVES = (TermQuery, RegexpQuery, FieldQuery)
 
@@ -201,8 +197,6 @@ def match(seg, query):
     shape = _classify(query)
     if isinstance(shape, str):
         return _fallback(shape)
-    if not dispatch.jax_ready(FORCE_ENV):
-        return _fallback("jax_not_ready")
     conjunction, pos_leaves, neg_leaves = shape
 
     sels = [_resolve(seg, q) for q in pos_leaves + neg_leaves]
@@ -239,7 +233,7 @@ def match(seg, query):
 
     from m3_tpu.utils.instrument import default_registry
 
-    col = seg.device_postings()
+    col = seg.device_postings(mesh)
     prog = _program(n_pos, M - n_pos, conjunction, mesh)
     # padding-waste ledger: selected CSR rows vs the kb bucket, postings
     # lanes vs lb, doc-space bits vs the word-aligned npad

@@ -12,6 +12,7 @@ field names/values for label APIs.
 from __future__ import annotations
 
 import re
+import threading
 
 from m3_tpu.index import packed
 from m3_tpu.index.executor import search
@@ -21,6 +22,12 @@ from m3_tpu.index.segment import MutableSegment, Segment
 
 class IndexBlock:
     def __init__(self) -> None:
+        # inserts arrive on request threads while the tick thread compacts
+        # and persists: without the lock a doc inserted between
+        # compaction's snapshot of the mutable segment and its swap for a
+        # fresh one vanished from the index for good (`_seen` kept saying
+        # the block had it). Found by chip_smoke's read-back, PR 21.
+        self._lock = threading.Lock()
         self.mutable = MutableSegment()
         self.sealed: list[Segment] = []
         self._cache: Segment | None = None  # sealed view of `mutable`
@@ -35,7 +42,7 @@ class IndexBlock:
         # yet (or invalidated by an external sealed-segment install).
         self._seen: set[bytes] | None = None
 
-    def seen_series(self) -> set[bytes]:
+    def _seen_locked(self) -> set[bytes]:
         """The block's series membership set (built on first use). Sealed
         segments contribute via series_ids() — id-blob slices, NOT the
         docs facade, which would decode every tag blob just to read ids
@@ -54,13 +61,36 @@ class IndexBlock:
         return self._seen
 
     def insert(self, series_id: bytes, fields) -> None:
-        seen = self.seen_series()
-        if series_id in seen:
-            return  # already present (mutable or sealed): nothing to add
-        self.mutable.insert(series_id, fields)
-        seen.add(series_id)
+        self.insert_many([series_id], [fields])
+
+    def insert_many(self, series_ids, fields_list) -> int:
+        """Insert the docs the block does not hold yet (mutable or
+        sealed); returns how many were new."""
+        inserted = 0
+        with self._lock:
+            seen = self._seen_locked()
+            for sid, fields in zip(series_ids, fields_list):
+                if sid in seen:
+                    continue
+                self.mutable.insert(sid, fields)
+                seen.add(sid)
+                inserted += 1
+        return inserted
+
+    def install_sealed(self, seg: Segment) -> None:
+        """Add a restored sealed segment (bootstrap from persisted index
+        files): membership grew outside insert, so the seen-set rebuilds."""
+        with self._lock:
+            self.sealed.append(seg)
+            self._seen = None
+            self.persisted_docs = sum(
+                s.n_docs for s in self._segments_locked())
 
     def segments(self) -> list[Segment]:
+        with self._lock:
+            return self._segments_locked()
+
+    def _segments_locked(self) -> list[Segment]:
         segs = list(self.sealed)
         if self.mutable.n_docs:
             # the doc-count check is the (single) cache invalidation: docs
@@ -81,8 +111,12 @@ class IndexBlock:
         Per-block segment count stays bounded under churn without
         rewriting every doc each pass. ``full=True`` folds everything into
         ONE packed segment (the persist path wants a single artifact)."""
+        with self._lock:
+            self._compact_locked(full)
+
+    def _compact_locked(self, full: bool) -> None:
         if full:
-            segs = self.segments()
+            segs = self._segments_locked()
             if not segs:
                 return
             if len(segs) > 1 or not isinstance(segs[0], packed.PackedSegment):
@@ -93,7 +127,7 @@ class IndexBlock:
         from m3_tpu.index import compaction
 
         if self.mutable.n_docs:
-            sealed_view = self.segments()[-1]  # cached sealed view
+            sealed_view = self._segments_locked()[-1]  # cached sealed view
             self.sealed.append(packed.merge([sealed_view])
                                if not isinstance(sealed_view, packed.PackedSegment)
                                else sealed_view)
@@ -109,13 +143,22 @@ class NamespaceIndex:
     def __init__(self, block_size_ns: int):
         self.block_size_ns = block_size_ns
         self._blocks: dict[int, IndexBlock] = {}
+        # request threads create blocks while the tick thread walks and
+        # expires them: two creators of one block would drop a block's
+        # worth of docs
+        self._blocks_lock = threading.Lock()
 
     def _block_for(self, t_ns: int) -> IndexBlock:
         bs = t_ns - (t_ns % self.block_size_ns)
-        blk = self._blocks.get(bs)
-        if blk is None:
-            blk = self._blocks[bs] = IndexBlock()
-        return blk
+        with self._blocks_lock:
+            blk = self._blocks.get(bs)
+            if blk is None:
+                blk = self._blocks[bs] = IndexBlock()
+            return blk
+
+    def _snapshot(self) -> list[tuple[int, IndexBlock]]:
+        with self._blocks_lock:
+            return sorted(self._blocks.items())
 
     def insert(self, series_id: bytes, fields: list[tuple[bytes, bytes]], t_ns: int) -> None:
         self._block_for(t_ns).insert(series_id, fields)
@@ -137,19 +180,14 @@ class NamespaceIndex:
         # 1-2 blocks), then the per-row work is a single set probe
         for bs in np.unique(bs_arr).tolist():
             blk = self._block_for(bs)  # bs is already block-aligned
-            seen = blk.seen_series()
-            for i in np.nonzero(bs_arr == bs)[0].tolist():
-                sid = series_ids[i]
-                if sid in seen:
-                    continue
-                blk.mutable.insert(sid, fields_list[i])
-                seen.add(sid)
-                inserted += 1
+            rows = np.nonzero(bs_arr == bs)[0].tolist()
+            inserted += blk.insert_many([series_ids[i] for i in rows],
+                                        [fields_list[i] for i in rows])
         return inserted
 
     def _overlapping(self, start_ns: int, end_ns: int) -> list[IndexBlock]:
         out = []
-        for bs, blk in sorted(self._blocks.items()):
+        for bs, blk in self._snapshot():
             if bs + self.block_size_ns <= start_ns or bs >= end_ns:
                 continue
             out.append(blk)
@@ -186,15 +224,16 @@ class NamespaceIndex:
         return sorted(values)
 
     def compact(self, full: bool = False) -> None:
-        for blk in self._blocks.values():
+        for _bs, blk in self._snapshot():
             blk.compact(full=full)
 
     def expire_before(self, cutoff_ns: int) -> int:
         dropped = 0
-        for bs in list(self._blocks):
-            if bs + self.block_size_ns <= cutoff_ns:
-                del self._blocks[bs]
-                dropped += 1
+        with self._blocks_lock:
+            for bs in list(self._blocks):
+                if bs + self.block_size_ns <= cutoff_ns:
+                    del self._blocks[bs]
+                    dropped += 1
         return dropped
 
     @property

@@ -129,7 +129,7 @@ class PackedSegment:
         self._term_idx_cache: OrderedDict = OrderedDict()
         self._vocab_clean_cache: bool | None = None
         self._term_keys_cache: np.ndarray | None = None
-        self._device_postings = None
+        self._device_postings: dict = {}  # compute mesh (or None) -> column
 
     def series_ids(self):
         """Every doc's series id, sliced straight out of the id blob —
@@ -434,16 +434,22 @@ class PackedSegment:
         lens = self._post_off[term_idxs + 1].astype(np.int64) - starts
         return starts, lens
 
-    def device_postings(self):
+    def device_postings(self, mesh=None):
         """The flat doc-id postings column committed to device as int32,
-        built once per sealed segment and cached forever (the segment is
-        immutable, so seal/compaction time is the only transfer). Padded
-        to a half-octave bucket so similarly-sized segments share device
-        buffer shapes; the pad cells are never addressed by a valid CSR
-        row, and the fused program's gather clips into them only for
-        lanes it masks out anyway."""
-        col = self._device_postings
+        built once per sealed segment (and compute mesh) and cached
+        forever (the segment is immutable, so seal/compaction time is the
+        only transfer). Padded to a half-octave bucket so similarly-sized
+        segments share device buffer shapes; the pad cells are never
+        addressed by a valid CSR row, and the fused program's gather
+        clips into them only for lanes it masks out anyway.
+
+        On a compute mesh the column is committed REPLICATED, one copy
+        per device: every device gathers its slice of the doc space from
+        the whole column, and a column left on the first device would be
+        re-broadcast to the others on every query."""
+        col = self._device_postings.get(mesh)
         if col is None:
+            import jax
             import jax.numpy as jnp
 
             from m3_tpu.utils import compute_stats, dispatch
@@ -451,10 +457,17 @@ class PackedSegment:
             n = len(self._postings)
             host = np.zeros(dispatch.next_bucket(max(n, 64)), np.int32)
             host[:n] = self._postings
-            col = self._device_postings = jnp.asarray(host)
+            if mesh is None:
+                col = jnp.asarray(host)
+            else:
+                from m3_tpu.parallel.mesh import replicated_sharding
+
+                col = jax.device_put(host, replicated_sharding(mesh))
+            self._device_postings[mesh] = col
             # device-cache ledger: committed column bytes live as long
             # as the segment; a GC'd segment releases its share
-            _track_device_column(self, int(col.nbytes))
+            n_dev = len(col.sharding.device_set)
+            _track_device_column(self, int(col.nbytes) * n_dev, n_dev)
             compute_stats.record_waste("postings", "column", n, host.size)
         return col
 
@@ -474,23 +487,33 @@ class PackedSegment:
 
 _dev_cols_lock = threading.Lock()
 _dev_cols = {"entries": 0, "bytes": 0}
+_dev_cols_width: dict = {}  # devices a column sits on -> live columns
 
 
-def _untrack_device_column(nbytes: int) -> None:
+def _dev_cols_stats() -> dict:
+    with _dev_cols_lock:
+        widths = [w for w, n in _dev_cols_width.items() if n > 0]
+        # `devices`: how many devices the widest live column sits on (a
+        # mesh that leaves every column on its first device shows 1)
+        return {**_dev_cols, "devices": max(widths, default=0)}
+
+
+def _untrack_device_column(nbytes: int, n_dev: int) -> None:
     with _dev_cols_lock:
         _dev_cols["entries"] -= 1
         _dev_cols["bytes"] -= nbytes
+        _dev_cols_width[n_dev] -= 1
 
 
-def _track_device_column(seg, nbytes: int) -> None:
+def _track_device_column(seg, nbytes: int, n_dev: int) -> None:
     from m3_tpu.utils import compute_stats
 
     with _dev_cols_lock:
         _dev_cols["entries"] += 1
         _dev_cols["bytes"] += nbytes
-    weakref.finalize(seg, _untrack_device_column, nbytes)
-    compute_stats.register_device_cache(
-        "postings_columns", lambda: dict(_dev_cols))
+        _dev_cols_width[n_dev] = _dev_cols_width.get(n_dev, 0) + 1
+    weakref.finalize(seg, _untrack_device_column, nbytes, n_dev)
+    compute_stats.register_device_cache("postings_columns", _dev_cols_stats)
 
 
 def build(docs) -> PackedSegment:

@@ -21,7 +21,7 @@ import struct
 import zlib
 
 from m3_tpu.index import packed
-from m3_tpu.index.index import IndexBlock, NamespaceIndex
+from m3_tpu.index.index import NamespaceIndex
 from m3_tpu.index.segment import Segment
 from m3_tpu.utils import faults
 
@@ -48,7 +48,7 @@ def persist_index(index: NamespaceIndex, root: str, namespace: str,
     every tick."""
     os.makedirs(_index_dir(root, namespace), exist_ok=True)
     written = 0
-    for bs, blk in list(index._blocks.items()):
+    for bs, blk in index._snapshot():
         if seal_before_ns is not None and \
                 bs + index.block_size_ns > seal_before_ns:
             continue  # still accepting writes: tiered compaction only
@@ -135,12 +135,7 @@ def load_index(index: NamespaceIndex, root: str, namespace: str,
                 seg = Segment.from_bytes(payload)  # legacy round-1 format
         except Exception:
             continue
-        blk = index._blocks.get(bs)
-        if blk is None:
-            blk = index._blocks[bs] = IndexBlock()
-        blk.sealed.append(seg)
-        blk._seen = None  # membership grew outside insert: rebuild lazily
-        blk.persisted_docs = sum(s.n_docs for s in blk.segments())
+        index._block_for(bs).install_sealed(seg)
         restored.add(bs)
     return restored
 

@@ -1,11 +1,11 @@
 """u32 multi-limb bit-stream machinery for the batched M3TSZ kernels.
 
-TPUs have no native 64-bit integers: every u64 op in a kernel is emulated by
-the XLA X64 rewriter (~2-10x cost), and scatter/gather lower to
-per-element loops (estimated ~10ns/element from their serialized lowering —
-NOT validated on TPU hardware from this environment — i.e. hundreds of ms
-for a 1M-datapoint block). These helpers exist so the codec hot loops can
-run as
+TPUs have no native 64-bit integers: every u64 op in a kernel is emulated
+by the XLA X64 rewriter, and scatter/gather lower to per-element loops. On
+the v5e (PR 21, one run each) the u64 scatter packer took 0.50 s for a
+[12288, 128] block against 0.027 s for the limb-tree packer built from
+these helpers, and the gather decoder 0.145 s against 0.003 s at
+[1024, 1024 steps]. These helpers exist so the codec hot loops can run as
 pure 32-bit elementwise ops on whole `[..., W]` limb tensors:
 
 - **limb registers**: a bit stream is a row of u32 limbs, MSB-first

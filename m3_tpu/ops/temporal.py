@@ -30,8 +30,6 @@ from m3_tpu.utils import dispatch
 
 NS = 1_000_000_000
 
-# elementwise matrix math wins earlier than sort-based ops
-DEVICE_THRESHOLD = 16_384
 
 
 def _pad_samples(values: np.ndarray, times: np.ndarray | None = None):

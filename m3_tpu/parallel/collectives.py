@@ -21,12 +21,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:
-    from jax import shard_map
-except ImportError:  # jax<0.5 ships shard_map under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 import m3_tpu.ops  # noqa: F401  (x64)

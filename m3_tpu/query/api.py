@@ -646,7 +646,8 @@ class CoordinatorAPI:
                 entries.append((name, tags, ts_ms * 1_000_000, value))
         self._admit_write(len(entries))
         batch = getattr(self.db, "write_batch", None)
-        if self.writer is None and batch is not None:
+        if batch is not None and (
+                self.writer is None or self.writer.downsampler is None):
             # no downsampler rules to run per-sample: one op-batched
             # request per storage node (host-queue batching role) with
             # PER-ENTRY results — one sub-consistency sample degrades its

@@ -73,7 +73,6 @@ from __future__ import annotations
 import contextlib
 import functools
 import os
-import sys
 import threading
 import time
 from collections import OrderedDict
@@ -294,10 +293,7 @@ def _program(sig: tuple, mesh=None):
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
-        try:
-            from jax import shard_map
-        except ImportError:  # jax<0.5: experimental spelling
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from m3_tpu.parallel.mesh import replicated_sharding, row_sharding
 
         row_sh = row_sharding(mesh)
@@ -460,13 +456,6 @@ def clear_plan_cache() -> None:
 # ---------------------------------------------------------------------------
 
 
-def _jax_ready() -> bool:
-    """Compile only when jax is importable WITHOUT risking a wedge —
-    the shared dispatch.jax_ready rung (jax already imported, or
-    M3_TPU_QUERY_COMPILE=1 explicitly accepts the import)."""
-    return dispatch.jax_ready("M3_TPU_QUERY_COMPILE")
-
-
 def _fallback(reason: str):
     """Counted, traced, never an error."""
     from m3_tpu.query import explain as explain_mod
@@ -520,7 +509,8 @@ def _group_ids(labels: list, grouping: tuple, without: bool):
 
 def try_execute(engine, expr: Expr, eval_ts: np.ndarray):
     """Compile-and-run `expr` when covered; None means "interpreter's
-    turn" (uncovered shape or jax unavailable), with the fallback counted.
+    turn" (uncovered shape, or a CPU host that serves it faster
+    natively), with the fallback counted.
 
     The decision is made BEFORE any storage work, so falling back never
     double-fetches or double-accounts query limits; past this point the
@@ -532,8 +522,6 @@ def try_execute(engine, expr: Expr, eval_ts: np.ndarray):
         if vspec is None:
             return _fallback("uncovered_plan_shape")
         return _try_execute_vecbin(engine, expr, vspec, eval_ts)
-    if not _jax_ready():
-        return _fallback("jax_not_initialized")
     if os.environ.get("M3_TPU_QUERY_COMPILE") != "1" \
             and _host_prefers_interpreter(spec):
         return _fallback("host_native_faster")
@@ -568,8 +556,6 @@ def _try_execute_vecbin(engine, expr, vspec: VecBinSpec, eval_ts):
     matching combines them element-wise in numpy — identical match-key,
     duplicate-series and result-label semantics, including the
     EvalErrors the interpreter raises for many-to-many/many-to-one."""
-    if not _jax_ready():
-        return _fallback("jax_not_initialized")
     if os.environ.get("M3_TPU_QUERY_COMPILE") != "1" \
             and (_host_prefers_interpreter(vspec.lhs)
                  or _host_prefers_interpreter(vspec.rhs)):

@@ -27,11 +27,14 @@ from m3_tpu.utils import dispatch
 NS = 1_000_000_000
 
 
-def _use_device(raws: "RaggedSeries", eval_ts: np.ndarray) -> bool:
-    from m3_tpu.ops import temporal
+# elementwise matrix math wins earlier than sort-based ops (kept here, not
+# in ops/temporal: the numpy interpreter must run in a process without jax)
+DEVICE_THRESHOLD = 16_384
 
+
+def _use_device(raws: "RaggedSeries", eval_ts: np.ndarray) -> bool:
     work = len(raws.values) + raws.n_series * len(eval_ts)
-    return dispatch.use_device(work, temporal.DEVICE_THRESHOLD)
+    return dispatch.use_device(work, DEVICE_THRESHOLD)
 
 
 class RaggedSeries:
@@ -208,13 +211,14 @@ def over_time(fn: str, raws: RaggedSeries, eval_ts: np.ndarray, range_ns: int):
         out = var if fn == "stdvar" else np.sqrt(var)
         return np.where(empty, np.nan, out)
     if fn in ("min", "max"):
-        from m3_tpu.ops import temporal
+        device = _use_device(raws, eval_ts)
+        if device:
+            from m3_tpu.ops import temporal
 
-        n = len(raws.values)
-        max_len = int((hi - lo).max()) if lo.size else 0
-        device = (_use_device(raws, eval_ts)
-                  and temporal.minmax_levels(max_len)
-                  * dispatch.next_pow2(n) <= temporal.MINMAX_SCRATCH_ELEMS)
+            max_len = int((hi - lo).max()) if lo.size else 0
+            device = (temporal.minmax_levels(max_len)
+                      * dispatch.next_pow2(len(raws.values))
+                      <= temporal.MINMAX_SCRATCH_ELEMS)
         dispatch.record("temporal.window_minmax", device)
         if device:
             return temporal.window_minmax(raws.values, lo, hi, fn == "min")

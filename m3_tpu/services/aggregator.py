@@ -23,6 +23,7 @@ from m3_tpu.metrics.aggregation import MetricType
 from m3_tpu.msg.consumer import Consumer
 from m3_tpu.msg.producer import Producer
 from m3_tpu.services.coordinator import ruleset_from_config
+from m3_tpu.utils import backend
 from m3_tpu.utils.config import load_config
 from m3_tpu.utils.instrument import Logger, default_registry
 
@@ -128,6 +129,7 @@ class AggregatorService:
         return len(metrics)
 
     def run(self) -> None:
+        backend.init(self.log)  # once, before anything listens
         ingest = self.config.get("ingest", {}) or {}
         self.consumer = Consumer(
             self._on_message,

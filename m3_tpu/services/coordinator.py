@@ -20,6 +20,7 @@ from m3_tpu.query.api import CoordinatorAPI
 from m3_tpu.query.graphite import CarbonIngester
 from m3_tpu.storage.database import Database
 from m3_tpu.storage.options import DatabaseOptions, NamespaceOptions, RetentionOptions
+from m3_tpu.utils import backend
 from m3_tpu.utils.config import load_config
 from m3_tpu.utils.instrument import Logger, default_registry
 
@@ -183,14 +184,6 @@ class CoordinatorService:
                                   limits=limits,
                                   query_compile=bool(
                                       query_cfg.get("compile", False)))
-        if self.api.query_compile:
-            # pay the jax import HERE, at service startup — the dispatch
-            # doctrine's blessed init point — never on a query thread
-            # (compiler._jax_ready refuses to be the first importer): a
-            # coordinator whose ingest path never touches jax would
-            # otherwise fall back forever on the feature the operator
-            # explicitly enabled
-            import jax  # noqa: F401
         self.api.writer = self.writer  # ingest fans out through downsampler
         # per-tenant admission control (utils/tenantlimits): quotas from
         # the config's `tenants:` section, cardinality ceilings read from
@@ -398,6 +391,10 @@ class CoordinatorService:
                           version=self._placement_version)
 
     def run(self) -> None:
+        # the backend is initialised here, once, before anything listens:
+        # flush encode, cold decode, compiled plans and the index program
+        # all choose device or host from it (utils/dispatch)
+        backend.init(self.log)
         if not self.db._open:
             self.db.open()  # bootstrap filesets + commitlog replay + WAL
             self.log.info("bootstrapped")
@@ -450,7 +447,8 @@ class CoordinatorService:
                     from m3_tpu.utils import faults
 
                     faults.escalate(e)
-                    self.log.info("tick error; continuing", error=str(e))
+                    self.log.error("tick error; continuing",
+                                   error=f"{type(e).__name__}: {e}")
         finally:
             self.shutdown()
 

@@ -21,7 +21,7 @@ from urllib.parse import parse_qs, urlparse
 from m3_tpu.services.coordinator import namespace_options
 from m3_tpu.storage.database import Database
 from m3_tpu.storage.options import DatabaseOptions
-from m3_tpu.utils import faults, trace
+from m3_tpu.utils import backend, faults, trace
 from m3_tpu.utils.config import load_config
 from m3_tpu.utils.instrument import Logger, default_registry
 
@@ -724,6 +724,7 @@ class DBNodeService:
         self._ns_registry_version = version
 
     def run(self) -> None:
+        backend.init(self.log)  # once, before anything listens
         self.db.open()
         self.log.info("bootstrapped")
         if self.kv is not None:
@@ -782,7 +783,8 @@ class DBNodeService:
                     # error must not kill the long-running node (but an
                     # armed SimulatedCrash must — the rig is watching)
                     faults.escalate(e)
-                    self.log.info("tick error; continuing", error=str(e))
+                    self.log.error("tick error; continuing",
+                                   error=f"{type(e).__name__}: {e}")
         finally:
             self.shutdown()
 

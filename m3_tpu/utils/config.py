@@ -39,6 +39,10 @@ def _parse_scalar(s: str) -> Any:
         return True
     if s in ("false", "False"):
         return False
+    if s == "[]":  # the empty flow collections, the only flow syntax
+        return []  # the subset reads (config/coordinator.yml: `rollup: []`)
+    if s == "{}":
+        return {}
     if s.startswith('"') and s.endswith('"') or s.startswith("'") and s.endswith("'"):
         return s[1:-1]
     try:

@@ -121,7 +121,7 @@ TIMERS = {
 #   compute_index_fallback {reason=...}        segments that took the
 #       counted scalar walk instead — reason is one of
 #       unpacked_segment / nested_boolean / trivial_query /
-#       jax_not_ready / small_work; the same split rides the
+#       small_work; the same split rides the
 #       ?explain=analyze `index` block per query
 # plus the dispatch-layer tallies index.postings[device|host] and
 # jit_postings_program[hit|miss] on /debug counters.

@@ -63,28 +63,20 @@ def rss_bytes() -> int:
 def record_process_gauges(registry: MetricsRegistry | None = None) -> None:
     """Compute-plane health gauges, refreshed each self-scrape tick:
     process RSS (from /proc/self/statm, getrusage fallback) and per-device
-    accelerator memory in use (jax memory_stats — only when a backend is
-    ALREADY initialized, same no-init rule as utils/dispatch: a scrape
-    must never be the thing that pays, or wedges on, PJRT init). CPU
-    backends report no memory_stats and are skipped."""
+    accelerator memory in use (jax memory_stats; the service initialised
+    the backend at start). CPU backends report no memory_stats and are
+    skipped."""
     registry = registry or default_registry()
     scope = registry.root_scope("process")
     rss = rss_bytes()
     if rss:
         scope.gauge("rss_bytes", float(rss))
-    import sys
+    import jax
 
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return
     try:
-        from jax._src import xla_bridge
-
-        if not xla_bridge._backends:  # not initialized: do not trigger it
-            return
         dev_scope = registry.root_scope("device")
         for d in jax.devices():
-            stats = d.memory_stats() if hasattr(d, "memory_stats") else None
+            stats = d.memory_stats()
             if not stats:
                 continue  # CPU devices report none
             in_use = stats.get("bytes_in_use")

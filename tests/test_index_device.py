@@ -239,8 +239,6 @@ class TestDeviceParity:
     virtual devices (pure boolean algebra: bit-identical on any mesh)."""
 
     def _device_ids(self, seg_, q, monkeypatch, shard):
-        import jax  # noqa: F401  - make jax_ready() true for this process
-
         monkeypatch.setenv("M3_TPU_DEVICE_OPS", "1")
         monkeypatch.setenv("M3_TPU_QUERY_SHARD", shard)
         ids, reason = device.match(seg_, q)

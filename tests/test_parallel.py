@@ -3,20 +3,10 @@
 import numpy as np
 import pytest
 
-jax = pytest.importorskip("jax")
+import jax
 
-# shard_map moved out of experimental in jax 0.5; collectives falls back
-# to the experimental import, so only a jax with NEITHER spelling skips
-# (the way test_properties degrades without hypothesis)
-if not hasattr(jax, "shard_map"):
-    try:
-        from jax.experimental.shard_map import shard_map as _sm  # noqa: F401
-    except ImportError:
-        pytest.skip("this jax has no shard_map (jax.* or experimental)",
-                    allow_module_level=True)
-
-from m3_tpu.parallel import collectives as C  # noqa: E402
-from m3_tpu.parallel.mesh import build_mesh  # noqa: E402
+from m3_tpu.parallel import collectives as C
+from m3_tpu.parallel.mesh import build_mesh
 
 
 @pytest.fixture(scope="module")

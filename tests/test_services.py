@@ -201,6 +201,34 @@ class TestConfigRegressions:
         assert doc["policies"] == ["10s:2d", "1m:30d"]
         assert doc["m"] == [{"k": "v"}]
 
+    def test_empty_flow_collections(self):
+        # `rollup: []` used to load as the string "[]" and crash the
+        # documented `coordinator -f config/coordinator.yml` start
+        assert parse_yaml("a: []\nb: {}\nc: x\n") == \
+            {"a": [], "b": {}, "c": "x"}
+
+    def test_shipped_configs_build_their_services(self, tmp_path,
+                                                  monkeypatch):
+        """Every config under config/ constructs the service it is for
+        (constructed, not run: nothing listens, nothing touches jax)."""
+        import os
+
+        from m3_tpu.utils.config import load_config
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        monkeypatch.setenv("M3_DATA_PATH", str(tmp_path / "data"))
+        monkeypatch.chdir(tmp_path)
+        svc = CoordinatorService(
+            load_config(os.path.join(repo, "config", "coordinator.yml")))
+        assert svc.downsampler is not None  # the default-rollup rule
+        svc.db.close()
+        node = DBNodeService(
+            load_config(os.path.join(repo, "config", "dbnode.yml")))
+        node.db.close()
+        agg = AggregatorService(
+            load_config(os.path.join(repo, "config", "aggregator.yml")))
+        agg.shutdown()
+
     def test_same_indent_list_under_key(self):
         doc = parse_yaml("namespaces:\n- name: default\n- name: agg\nk: 1\n")
         assert doc == {"namespaces": [{"name": "default"}, {"name": "agg"}],
