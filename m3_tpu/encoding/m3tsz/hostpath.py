@@ -233,22 +233,30 @@ def _decode_streams_device(streams: list[bytes], unit: TimeUnit,
         sum((len(s) + 7) // 8 for s in streams), int(words.size))
     sig = f"B{words.shape[0]}xW{words.shape[1]}xP{max_points}" + \
         ("|int" if int_optimized else "")
+    from m3_tpu.utils import trace
+
     if int_optimized:
         from m3_tpu.encoding.m3tsz import tpu_int
 
-        with dispatch.jit_tracker("m3tsz_decode", tpu_int.decode_int,
-                                  sig=sig):
-            dec = tpu_int.decode_int(words, unit, max_points=max_points)
-        vals = _np.asarray(dec.values, _np.float64)
-        vbits = vals.view(_np.uint64)
+        jitted = tpu_int.decode_int
     else:
-        with dispatch.jit_tracker("m3tsz_decode", m3tsz_tpu._decode_jit,
-                                  sig=sig):
-            dec = m3tsz_tpu.decode(words, unit, max_points=max_points)
-        vbits = _np.asarray(dec.value_bits, _np.uint64)
-    times = _np.asarray(dec.times, _np.int64)
-    err = _np.asarray(dec.error)
-    counts = _np.asarray(dec.n_points)
+        jitted = m3tsz_tpu._decode_jit
+    # one stage and one tracked block around the decoder call AND the
+    # reads that wait for it: dispatch, H2D, queue and device time
+    with trace.stage(trace.STAGE_DECODE_WAIT) as fr:
+        with dispatch.jit_tracker("m3tsz_decode", jitted,
+                                  sig=sig) as tracker:
+            if int_optimized:
+                dec = tpu_int.decode_int(words, unit, max_points=max_points)
+                vbits = _np.asarray(dec.values, _np.float64).view(_np.uint64)
+            else:
+                dec = m3tsz_tpu.decode(words, unit, max_points=max_points)
+                vbits = _np.asarray(dec.value_bits, _np.uint64)
+            times = _np.asarray(dec.times, _np.int64)
+            err = _np.asarray(dec.error)
+            counts = _np.asarray(dec.n_points)
+        if tracker.miss:
+            fr.name = trace.STAGE_DECODE_COMPILE
     dispatch.counters["m3tsz_decode_device_batch"] += 1
     out = []
     for b, stream in enumerate(streams):
@@ -275,8 +283,6 @@ def decode_streams_batch(streams: list[bytes | None], unit: TimeUnit,
     fast rungs reject (annotation/time-unit markers) degrade per stream,
     never the whole group.
     """
-    import time as _time
-
     from m3_tpu.utils import querystats, trace
 
     empty = (np.empty(0, np.int64), np.empty(0, np.uint64))
@@ -293,8 +299,7 @@ def decode_streams_batch(streams: list[bytes | None], unit: TimeUnit,
     rung = "scalar"
     use_device = forced == "device" or (not forced and _device_decode())
     use_native = forced == "native" or (not forced and not use_device)
-    with trace.span(trace.DECODE_BATCH, streams=len(subset)) as sp:
-        t0 = _time.perf_counter()
+    with trace.stage(trace.STAGE_DECODE_HOST, streams=len(subset)) as fr:
         if use_device:
             decoded = _decode_streams_device(subset, unit, int_optimized)
             rung = "device"
@@ -331,26 +336,24 @@ def decode_streams_batch(streams: list[bytes | None], unit: TimeUnit,
                 v = np.array([np.float64(d.value) for d in dps],
                              np.float64).view(np.uint64)
                 decoded.append((t, v))
-        dt = _time.perf_counter() - t0
-        # device-op profiling: which rung served this group (visible on
-        # /metrics per rung), how long it took, how many bytes it chewed —
-        # the per-query record gets the same attribution
         n_bytes = sum(len(s) for s in subset)
-        sc = _decode_scope(rung)
-        sc.observe("seconds", dt)
-        sc.counter("streams", len(subset))
-        sc.counter("bytes", n_bytes)
-        # batch-size DISTRIBUTION per rung (count-shaped bounds): whether
-        # batches are big enough to amortize a dispatch is the question
-        # the per-rung counters alone can't answer
-        from m3_tpu.utils.instrument import COUNT_BUCKETS
+        fr.tag(path=rung, bytes=n_bytes)
+    # device-op profiling: which rung served this group (visible on
+    # /metrics per rung), how long it took (the stage's whole time), how
+    # many bytes it chewed — the per-query record gets the same
+    # attribution
+    sc = _decode_scope(rung)
+    sc.observe("seconds", fr.wall_s)
+    sc.counter("streams", len(subset))
+    sc.counter("bytes", n_bytes)
+    # batch-size DISTRIBUTION per rung (count-shaped bounds): whether
+    # batches are big enough to amortize a dispatch is the question
+    # the per-rung counters alone can't answer
+    from m3_tpu.utils.instrument import COUNT_BUCKETS
 
-        sc.observe("batch_size", float(len(subset)), bounds=COUNT_BUCKETS)
-        querystats.record(blocks_read=1, bytes_decoded=n_bytes,
-                          decode_rung=rung)
-        if sp is not None:
-            sp.tags["path"] = rung
-            sp.tags["bytes"] = n_bytes
+    sc.observe("batch_size", float(len(subset)), bounds=COUNT_BUCKETS)
+    querystats.record(blocks_read=1, bytes_decoded=n_bytes,
+                      decode_rung=rung)
     for i, r in zip(todo, decoded):
         out[i] = r
     return out

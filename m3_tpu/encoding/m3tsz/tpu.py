@@ -246,8 +246,9 @@ def _encode_bits_jit(
         in32 = (dod_units >= -(1 << 31)) & (dod_units <= (1 << 31) - 1)
         overflow = overflow | jnp.any(valid & ~in32)
 
-    words = _pack_stream(ts_hi, ts_lo, ts_len, v_hi, v_lo, v_len,
-                         valid, start, capacity_words, impl)
+    with jax.named_scope("m3.encode.pack"):
+        words = _pack_stream(ts_hi, ts_lo, ts_len, v_hi, v_lo, v_len,
+                             valid, start, capacity_words, impl)
     return EncodedBlocks(words=words, bit_lengths=total_bits, overflow=overflow)
 
 
@@ -490,9 +491,10 @@ def _decode_jit(
     max_points: int,
     impl: str,
 ) -> DecodedBlocks:
-    if impl == "tree":
-        return _decode_shift(words, unit, max_points)
-    return _decode_gather(words, unit, max_points)
+    with jax.named_scope("m3.decode.scan"):
+        if impl == "tree":
+            return _decode_shift(words, unit, max_points)
+        return _decode_gather(words, unit, max_points)
 
 
 def _decode_gather(

@@ -151,7 +151,9 @@ def _program(n_pos: int, n_neg: int, conjunction: bool, mesh):
 
         words_sharding = NamedSharding(mesh, PartitionSpec(None, "series"))
 
-    def run(col, starts, lens, *, lb, npad):
+    # not `run`: the trace names a program after its function, and
+    # `jit_run` is the query plan's (query/compiler.py)
+    def postings_run(col, starts, lens, *, lb, npad):
         def member(starts_m, lens_m):
             # expand this matcher's CSR rows into flat column positions:
             # lane j of lb belongs to row rid[j] at row-local offset
@@ -175,15 +177,16 @@ def _program(n_pos: int, n_neg: int, conjunction: bool, mesh):
             # each device owns a contiguous slice of the doc-space words:
             # scatter+reduce stay device-local, the result mask replicates
             words = jax.lax.with_sharding_constraint(words, words_sharding)
-        if conjunction:
-            acc = bitmaps.and_reduce_words(words[:n_pos])
-        else:
-            acc = bitmaps.or_reduce_words(words[:n_pos])
-        if n_neg:
-            acc = acc & ~bitmaps.or_reduce_words(words[n_pos:])
+        with jax.named_scope("m3.postings.intersect"):
+            if conjunction:
+                acc = bitmaps.and_reduce_words(words[:n_pos])
+            else:
+                acc = bitmaps.or_reduce_words(words[:n_pos])
+            if n_neg:
+                acc = acc & ~bitmaps.or_reduce_words(words[n_pos:])
         return acc
 
-    return jax.jit(run, static_argnames=("lb", "npad"))
+    return jax.jit(postings_run, static_argnames=("lb", "npad"))
 
 
 def match(seg, query):
@@ -229,8 +232,6 @@ def match(seg, query):
         starts[m, : len(s)] = s
         lens[m, : len(ln)] = ln
 
-    import time
-
     from m3_tpu.utils.instrument import default_registry
 
     col = seg.device_postings(mesh)
@@ -246,21 +247,17 @@ def match(seg, query):
     sig = (f"P{n_pos}N{M - n_pos}{'&' if conjunction else '|'}"
            f"|K{kb}|L{lb}|D{npad}" + (f"|M{n_dev}" if mesh else ""))
     starts_d, lens_d = jnp.asarray(starts), jnp.asarray(lens)
-    t0 = time.perf_counter()
-    with dispatch.jit_tracker(
-            "postings_program", prog, sig=sig,
-            lower=lambda: prog.lower(col, starts_d, lens_d,
-                                     lb=lb, npad=npad)):
-        words = prog(col, starts_d, lens_d, lb=lb, npad=npad)
+    with dispatch.jit_tracker("postings_program", prog, sig=sig) as tracker:
+        w = np.asarray(prog(col, starts_d, lens_d, lb=lb, npad=npad))
     dispatch.record("index.postings", True)
     sc = default_registry().root_scope("compute").subscope("index")
     sc.counter("device")
-    # program wall time; on a shape-cache miss this includes the
-    # trace+compile (compute.jit{op=postings_program} splits that out)
-    sc.observe("postings_seconds", time.perf_counter() - t0)
+    # program wall time, the wait for the mask included; on a shape-cache
+    # miss this includes the trace+compile (compute.jit{op=
+    # postings_program} splits that out)
+    sc.observe("postings_seconds", tracker.seconds)
     querystats.record_index(postings_rows=sum(len(s) for s in sels))
 
-    w = np.asarray(words)
     bits = np.unpackbits(w.view(np.uint8), bitorder="little")
     ids = np.nonzero(bits)[0]
     return ids[ids < seg.n_docs].astype(np.uint32), None

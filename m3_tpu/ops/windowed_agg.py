@@ -194,13 +194,11 @@ def _aggregate_groups_device(elem_ids, window_ids, values, order_seq, times):
     # padding-waste ledger: real sample rows vs the pow2-padded batch
     compute_stats.record_waste("windowed_agg", "samples", n, N)
     kernel = _grouped_stats_jit()
-    with dispatch.jit_tracker(
-            "grouped_stats", kernel, sig=f"N{N}",
-            lower=lambda: kernel.lower(e_p, w_p, v_p, s_p, t_p)):
+    with dispatch.jit_tracker("grouped_stats", kernel, sig=f"N{N}"):
         out = kernel(e_p, w_p, v_p, s_p, t_p)
-    es, ws, new_group, count, s1, s2, gmin, gmax, last, vq = (
-        np.asarray(x) for x in out
-    )
+        es, ws, new_group, count, s1, s2, gmin, gmax, last, vq = (
+            np.asarray(x) for x in out
+        )
     group_start = np.nonzero(new_group)[0]
     n_groups_total = len(group_start)
     # pads share the (BIG, BIG) key: exactly one trailing sentinel group

@@ -172,6 +172,17 @@ def _wants_openmetrics(q, headers) -> bool:
     return fmt in ("openmetrics", "openmetrics-text")
 
 
+# path -> the `route` label of the stage metrics (utils/trace.py);
+# every other path is trace.ROUTE_OTHER
+_ROUTES = {
+    "/api/v1/query_range": "query_range",
+    "/api/v1/query": "query",
+    "/api/v1/prom/remote/write": "remote_write",
+    "/api/v1/prom/remote/read": "remote_read",
+}
+_QUERY_ROUTES = ("query_range", "query")
+
+
 def _render_metrics(q, headers):
     """(status, content_type, payload) for a /metrics scrape: OpenMetrics
     with exemplars when negotiated, strict Prometheus text otherwise."""
@@ -264,21 +275,31 @@ class CoordinatorAPI:
         nodes — follows it, and the response echoes the trace id in an
         `M3-Trace-Id` header so a slow query is one /debug/traces lookup
         away."""
+        import dataclasses
         import math
         import time as _time
 
-        from m3_tpu.utils import trace
+        from m3_tpu.utils import querystats, trace
 
         # one resource budget per request, enforced in the storage read
         # path (covers PromQL, Graphite render, and remote read alike)
         limits = getattr(self.db, "limits", None)
-        ctx = trace.start_request(headers)
+        route = _ROUTES.get(path, trace.ROUTE_OTHER)
+        ctx = dataclasses.replace(trace.start_request(headers), route=route)
+        # a query route's record lives as long as the request, so that
+        # /debug/slow_queries holds every stage down to `render`; the
+        # engine's own start() nests under it
+        stats = querystats.start(
+            query=(query.get("query") or [""])[0] if query else "",
+            namespace=self._tenant_of(query)) \
+            if route in _QUERY_ROUTES else None
         t0 = _time.perf_counter()
         try:
             if limits is not None:
                 limits.start_query()
             with trace.activate(ctx), \
-                    trace.span(trace.API_REQUEST, path=path, method=method), \
+                    trace.stage(trace.STAGE_REQUEST, path=path,
+                                method=method), \
                     self._scope.histogram("request_seconds"):
                 res = self._route(method, path, query, body, headers)
             status, ctype, payload, hdrs = res if len(res) == 4 \
@@ -310,6 +331,8 @@ class CoordinatorAPI:
         finally:
             if limits is not None:
                 limits.end_query()
+            if stats is not None:
+                querystats.finish(stats)
         if path.startswith("/api/v1/") or path == "/render":
             # bytes-on-wire ledger for the coordinator's egress (the
             # `response` flow of net_bytes_{sent,recv}): only query-serving
@@ -412,6 +435,14 @@ class CoordinatorAPI:
             from m3_tpu.utils import profiler
 
             status, payload, ctype = profiler.handle_debug_profile(
+                method, q, body)
+            return status, ctype, payload
+        if path == "/debug/profile/device":
+            # the service's device-trace session (utils/backend): the
+            # stage clock's spans as annotations beside the device planes
+            from m3_tpu.utils import backend
+
+            status, payload, ctype = backend.handle_debug_profile_device(
                 method, q, body)
             return status, ctype, payload
         if path == "/debug/compute":
@@ -878,6 +909,14 @@ class CoordinatorAPI:
 
     def _render(self, result, eval_ts, matrix: bool, engine=None,
                 explain_doc=None):
+        from m3_tpu.utils import trace
+
+        with trace.stage(trace.STAGE_RENDER):
+            return self._render_json(result, eval_ts, matrix, engine,
+                                     explain_doc)
+
+    def _render_json(self, result, eval_ts, matrix: bool, engine,
+                     explain_doc):
         ts_sec = eval_ts.astype(np.float64) / NS
         if isinstance(result, Scalar):
             if matrix:

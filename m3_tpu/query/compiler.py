@@ -74,7 +74,6 @@ import contextlib
 import functools
 import os
 import threading
-import time
 from collections import OrderedDict
 from dataclasses import dataclass
 
@@ -331,22 +330,26 @@ def _program(sig: tuple, mesh=None):
 
     def run(vs, adjs, ts, csums, bmat, lo, hi, eval_ts, range_ns, seg,
             phi, scalars, num_groups: int, mm_levels: int):
-        if mesh is None:
-            cur = _base_stage(vs[0], adjs[0], ts[0], csums[0], bmat,
-                              lo, hi, eval_ts, range_ns, mm_levels)
-        elif base in _MINMAX and mm_levels == 0:
-            cur = bmat  # host-computed base, already row-sharded
-        else:
-            def local(vs, adjs, ts, csums, lo, hi, eval_ts, range_ns):
-                return _base_stage(vs[0], adjs[0], ts[0], csums[0], None,
-                                   lo, hi, eval_ts, range_ns, mm_levels)
+        # the named scopes change operation metadata only: the phases'
+        # stable names in a device trace
+        with jax.named_scope("m3.plan.base"):
+            if mesh is None:
+                cur = _base_stage(vs[0], adjs[0], ts[0], csums[0], bmat,
+                                  lo, hi, eval_ts, range_ns, mm_levels)
+            elif base in _MINMAX and mm_levels == 0:
+                cur = bmat  # host-computed base, already row-sharded
+            else:
+                def local(vs, adjs, ts, csums, lo, hi, eval_ts, range_ns):
+                    return _base_stage(vs[0], adjs[0], ts[0], csums[0],
+                                       None, lo, hi, eval_ts, range_ns,
+                                       mm_levels)
 
-            cur = shard_map(
-                local, mesh=mesh,
-                in_specs=(P("series", None),) * 6 + (P(None), P()),
-                out_specs=P("series", None),
-            )(vs, adjs, ts, csums, lo, hi, eval_ts, range_ns)
-        cur = _constrain(cur, grouped=False)
+                cur = shard_map(
+                    local, mesh=mesh,
+                    in_specs=(P("series", None),) * 6 + (P(None), P()),
+                    out_specs=P("series", None),
+                )(vs, adjs, ts, csums, lo, hi, eval_ts, range_ns)
+            cur = _constrain(cur, grouped=False)
         si = 0
         grouped = False
         for st in stages:
@@ -368,12 +371,13 @@ def _program(sig: tuple, mesh=None):
                 cur = nxt
             else:
                 _, op = st
-                if op == "quantile":
-                    cur = windowed_agg.stage_grouped_quantile(
-                        cur, seg, num_groups, phi)
-                else:
-                    cur = windowed_agg.stage_grouped_reduce(
-                        op, cur, seg, num_groups)
+                with jax.named_scope("m3.plan.agg"):
+                    if op == "quantile":
+                        cur = windowed_agg.stage_grouped_quantile(
+                            cur, seg, num_groups, phi)
+                    else:
+                        cur = windowed_agg.stage_grouped_reduce(
+                            op, cur, seg, num_groups)
                 grouped = True
             cur = _constrain(cur, grouped)
         return cur
@@ -516,9 +520,12 @@ def try_execute(engine, expr: Expr, eval_ts: np.ndarray):
     double-fetches or double-accounts query limits; past this point the
     compiled path either returns a result or raises like the interpreter
     would (storage errors, limits)."""
-    spec = match(expr)
+    from m3_tpu.utils import trace
+
+    with trace.stage(trace.STAGE_PARSE_PLAN):
+        spec = match(expr)
+        vspec = match_vecbin(expr) if spec is None else None
     if spec is None:
-        vspec = match_vecbin(expr)
         if vspec is None:
             return _fallback("uncovered_plan_shape")
         return _try_execute_vecbin(engine, expr, vspec, eval_ts)
@@ -845,6 +852,7 @@ def _execute(engine, spec: PlanSpec, labels, raws, eval_ts, col):
     from m3_tpu.parallel import mesh as mesh_mod
     from m3_tpu.query.engine import Vector, _compact
     from m3_tpu.storage import hottier
+    from m3_tpu.utils import trace
     from m3_tpu.utils.instrument import default_registry
 
     T = len(eval_ts)
@@ -887,8 +895,9 @@ def _execute(engine, spec: PlanSpec, labels, raws, eval_ts, col):
         default_registry().root_scope("storage").subscope(
             "hot_tier").counter(hot_state)
     if entry is None:
-        entry = _prepare_slabs(engine, spec, labels, raws, shifted, T, S,
-                               agg, precision)
+        with trace.stage(trace.STAGE_SLAB_PREP):
+            entry = _prepare_slabs(engine, spec, labels, raws, shifted, T,
+                                   S, agg, precision)
         if hkey is not None:
             tier.put(hkey, entry, entry["nbytes"])
             default_registry().root_scope("storage").subscope(
@@ -920,21 +929,26 @@ def _execute(engine, spec: PlanSpec, labels, raws, eval_ts, col):
         ((n_dev, cap) if mesh is not None else ())
     key_str = f"{spec.sig_str}|S{Sp}|T{Tp}|G{Gp}" + \
         (f"|M{n_dev}x{cap}" if mesh is not None else "")
-    program = _program(sig, mesh)
+    with trace.stage(trace.STAGE_PARSE_PLAN):
+        program = _program(sig, mesh)
     if mesh is not None:
         dispatch.counters["query.compile[sharded]"] += 1
         default_registry().root_scope("compute").subscope(
             "mesh", devices=str(n_dev)).counter("dispatch")
-    t0 = time.perf_counter()
     prog_args = (vs, adjs, ts, csums, bmat, lo_p, hi_p,
                  eval_pad, np.int64(spec.range_ns), seg_pad,
                  np.float64(phi if phi is not None else 0.0), scalars)
-    tracker = dispatch.jit_tracker(
-        "query_plan", program, sig=key_str,
-        lower=lambda: program.lower(*prog_args, num_groups=Gp,
-                                    mm_levels=mm_levels))
-    with tracker:
-        out = program(*prog_args, num_groups=Gp, mm_levels=mm_levels)
+    # the tracker's block holds the enqueue and the read that waits for
+    # the program: compute_execute_seconds{op="query_plan"} times
+    # completion, one count per launch
+    with dispatch.jit_tracker("query_plan", program,
+                              sig=key_str) as tracker:
+        with trace.stage(trace.STAGE_PLAN_DISPATCH) as fr:
+            out = program(*prog_args, num_groups=Gp, mm_levels=mm_levels)
+            if tracker.missed():
+                fr.name = trace.STAGE_PLAN_COMPILE
+        with trace.stage(trace.STAGE_PLAN_WAIT):
+            out = np.asarray(out)
     hit = not tracker.miss
     _plan_cache_record(key, miss=tracker.miss)
     sc = default_registry().root_scope("compute").subscope(
@@ -943,9 +957,7 @@ def _execute(engine, spec: PlanSpec, labels, raws, eval_ts, col):
     if not hit:
         # trace+lower+compile dominates the first call of a new shape
         default_registry().root_scope("compute").subscope(
-            "query_plan").observe("plan_compile_seconds",
-                                  time.perf_counter() - t0)
-    out = np.asarray(out)
+            "query_plan").observe("plan_compile_seconds", tracker.seconds)
 
     if agg is not None:
         mat = out[:G, :T]
@@ -976,8 +988,7 @@ def _execute(engine, spec: PlanSpec, labels, raws, eval_ts, col):
         info = {"ran": True, "cache_key": key_str,
                 "cache": "hit" if hit else "miss"}
         # the ?explain=analyze device block: what this query cost on the
-        # compute plane — execute/compile wall, static FLOP/byte profile
-        # (captured once per compile), padding waste, mesh width
+        # compute plane — execute/compile wall, padding waste, mesh width
         padding = {"series": {"logical": S, "padded": Sp},
                    "time": {"logical": T, "padded": Tp}}
         if agg is not None:
@@ -989,9 +1000,6 @@ def _execute(engine, spec: PlanSpec, labels, raws, eval_ts, col):
                   "padding": padding,
                   "waste_ratio": round(1.0 - (S * T) / (Sp * Tp), 6),
                   "mesh_devices": n_dev}
-        prof = compute_stats.profile_for("query_plan", key_str)
-        if prof:
-            device.update(prof)
         info["device"] = device
         if hot_state is not None:
             # the ?explain=analyze hot_tier block: did warm device pages

@@ -159,8 +159,15 @@ class Engine:
         another Engine rebound it after this one was constructed)."""
         return getattr(self.db, "limits", None) or self.limits
 
+    @staticmethod
+    def _parse(q: str) -> Expr:
+        from m3_tpu.utils import trace
+
+        with trace.stage(trace.STAGE_PARSE_PLAN):
+            return promql.parse(q)
+
     def query_range(self, q: str, start_ns: int, end_ns: int, step_ns: int):
-        return self.query_range_expr(promql.parse(q), start_ns, end_ns,
+        return self.query_range_expr(self._parse(q), start_ns, end_ns,
                                      step_ns, query_text=q)
 
     def query_range_expr(self, expr: Expr, start_ns: int, end_ns: int,
@@ -170,50 +177,32 @@ class Engine:
         if step_ns <= 0:
             raise EvalError("step must be positive")
         eval_ts = np.arange(start_ns, end_ns + 1, step_ns, dtype=np.int64)
+        self._active_limits().check_steps(len(eval_ts))
+        return self._run_query(expr, eval_ts, query_text)
+
+    def query_instant(self, q: str, t_ns: int):
+        return self._run_query(self._parse(q),
+                               np.array([t_ns], dtype=np.int64), q)
+
+    def _run_query(self, expr: Expr, eval_ts: np.ndarray, query_text: str):
+        """One query over `eval_ts`: limits, the per-query record, the
+        `eval` stage (the span the engine's work hangs under), warnings."""
         limits = self._active_limits()
-        limits.check_steps(len(eval_ts))
         limits.start_query()
         from m3_tpu.utils import querystats, trace
 
         self._warn_tls.sink = sink = []
         st = querystats.start(query=query_text, namespace=self.namespace)
         try:
-            with trace.span(trace.ENGINE_QUERY, steps=len(eval_ts)) as sp:
-                if sp is not None:
-                    st.trace_id = sp.trace_id
-                with querystats.stage("eval"):
-                    _resolve_at_sentinels(expr, int(eval_ts[0]),
-                                          int(eval_ts[-1]))
-                    out = self._maybe_compiled(expr, eval_ts)
-                    if out is None:
-                        out = self._eval(expr, eval_ts)
-                    return out, eval_ts
-        finally:
-            querystats.finish(st)
-            self._warn_tls.last_stats = st
-            self._warn_tls.sink = None
-            self._warn_tls.last = sink
-            limits.end_query()
-
-    def query_instant(self, q: str, t_ns: int):
-        eval_ts = np.array([t_ns], dtype=np.int64)
-        limits = self._active_limits()
-        limits.start_query()
-        from m3_tpu.utils import querystats, trace
-
-        self._warn_tls.sink = sink = []
-        st = querystats.start(query=q, namespace=self.namespace)
-        try:
-            with trace.span(trace.ENGINE_QUERY, steps=1) as sp:
-                if sp is not None:
-                    st.trace_id = sp.trace_id
-                with querystats.stage("eval"):
-                    expr = promql.parse(q)
-                    _resolve_at_sentinels(expr, t_ns, t_ns)
-                    out = self._maybe_compiled(expr, eval_ts)
-                    if out is None:
-                        out = self._eval(expr, eval_ts)
-                    return out, eval_ts
+            with trace.stage(trace.STAGE_EVAL, steps=len(eval_ts)) as fr:
+                if fr.span is not None:
+                    st.trace_id = fr.span.trace_id
+                _resolve_at_sentinels(expr, int(eval_ts[0]),
+                                      int(eval_ts[-1]))
+                out = self._maybe_compiled(expr, eval_ts)
+                if out is None:
+                    out = self._eval(expr, eval_ts)
+                return out, eval_ts
         finally:
             querystats.finish(st)
             self._warn_tls.last_stats = st

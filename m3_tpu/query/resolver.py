@@ -151,7 +151,7 @@ def fetch_tagged_ragged(db, namespaces: list[str], index_query, t_min: int,
     docs in index order with empty series dropped (or appended at the
     end under keep_empty) — dropping/reordering empty rows never moves
     sample data, so the CSR arrays come through untouched."""
-    from m3_tpu.utils import querystats
+    from m3_tpu.utils import querystats, trace
 
     if len(namespaces) != 1:
         return None
@@ -161,14 +161,14 @@ def fetch_tagged_ragged(db, namespaces: list[str], index_query, t_min: int,
     # and this fast path would silently skip their remote legs
     if not getattr(ns, "supports_ragged_read", False):
         return None
-    with querystats.stage("query_ids"):
+    with trace.stage(trace.STAGE_QUERY_IDS):
         if limit is not None:
             docs = ns.query_ids(index_query, t_min, t_max, limit=limit)
         else:
             docs = ns.query_ids(index_query, t_min, t_max)
     querystats.record(series_matched=len(docs))
     ids = [d.series_id for d in docs]
-    with querystats.stage("read_many"):
+    with trace.stage(trace.STAGE_READ_MANY):
         if warnings is not None and getattr(ns, "supports_read_warnings",
                                             False):
             # cluster facade on the CSR path: its partial-read warnings
@@ -216,7 +216,7 @@ def fetch_tagged(db, namespaces: list[str], index_query, t_min: int,
     per-call thread-safe channel — never read back from shared facade
     state, which concurrent queries would cross-contaminate.
     """
-    from m3_tpu.utils import querystats
+    from m3_tpu.utils import querystats, trace
 
     by_id: dict[bytes, list] = {}  # id -> [doc, times, vbits]
     empties: dict[bytes, object] = {}  # matched but no samples anywhere
@@ -224,7 +224,7 @@ def fetch_tagged(db, namespaces: list[str], index_query, t_min: int,
         ns = db.namespaces[ns_name]
         kw = {"warnings": warnings} if warnings is not None and \
             getattr(ns, "supports_read_warnings", False) else {}
-        with querystats.stage("query_ids"):
+        with trace.stage(trace.STAGE_QUERY_IDS):
             if limit is not None:
                 docs = ns.query_ids(index_query, t_min, t_max, limit=limit,
                                     **kw)
@@ -232,7 +232,7 @@ def fetch_tagged(db, namespaces: list[str], index_query, t_min: int,
                 docs = ns.query_ids(index_query, t_min, t_max, **kw)
         querystats.record(series_matched=len(docs))
         ids = [d.series_id for d in docs]
-        with querystats.stage("read_many"):
+        with trace.stage(trace.STAGE_READ_MANY):
             results = ns.read_many(ids, t_min, t_max, **kw)
         for doc, (times, vbits) in zip(docs, results):
             if len(times) == 0:

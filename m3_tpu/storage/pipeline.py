@@ -383,27 +383,39 @@ def run_stages(items: list, produce, consume, depth: int | None = None,
     thread in submission order (thread-local leg: decode rungs,
     querystats, cache fills). With the hatch closed (or from a worker)
     it degrades to the exact serial interleaving ``consume(produce())``
-    — same work, same order, no threads."""
+    — same work, same order, no threads. Per-leg seconds come from the
+    legs' spans (utils/trace.py stage()); ``wall_s`` is this pass's own."""
+    from m3_tpu.utils import trace
+
     stats = StageStats(items=len(items))
     t0 = time.perf_counter()
+    pipelined = active() and len(items) > 1
+    tracer = trace.default_tracer()
+    ctx = tracer.current()
 
-    def timed_produce(item):
-        p0 = time.perf_counter()
-        payload = produce(item)
-        return item, payload, time.perf_counter() - p0
+    def produce_leg(item):
+        # the leg's seconds are its span's. On a pool worker the span is
+        # the stage `read_many.gather`, under the caller's re-activated
+        # context (so it hangs in the request's tree with the request's
+        # route); run inline it is the caller's own time, and its stage
+        # (read_many) keeps it as self-time
+        with tracer.activate(ctx), \
+                tracer.stage(trace.STAGE_GATHER, metered=pipelined) as fr:
+            payload = produce(item)
+        return item, payload, fr.wall_s
 
-    if active() and len(items) > 1:
+    if pipelined:
         ex = default_executor()
         results = ex.map_ordered(
-            [lambda it=it: timed_produce(it) for it in items],
+            [lambda it=it: produce_leg(it) for it in items],
             depth or prefetch_depth())
     else:
-        results = (timed_produce(it) for it in items)
+        results = (produce_leg(it) for it in items)
     for item, payload, p_dt in results:
         stats.add_stage(produce_stage, p_dt)
-        c0 = time.perf_counter()
-        consume(item, payload)
-        stats.add_stage(consume_stage, time.perf_counter() - c0)
+        with tracer.stage(trace.PIPELINE_CONSUME, metered=False) as fr:
+            consume(item, payload)
+        stats.add_stage(consume_stage, fr.wall_s)
     stats.wall_s = time.perf_counter() - t0
     if stats.items:
         _scope.subscope("stage", stage=produce_stage).observe(

@@ -17,11 +17,24 @@ and this module sets nothing. Where it is not, the cache lives at
 ``<checkout>/.jax_cache``, resolved from this package's own location —
 the path is part of the cache key, so it never carries a temp name, pid
 or time. No other place in the tree sets a cache.
+
+Device trace: only the process that holds the chip can trace it, so the
+profiler session belongs to the service. ``start_device_trace(dir)`` /
+``stop_device_trace()`` (``POST /debug/profile/device`` on the
+coordinator) run ``jax.profiler`` with the Python tracer off and the host
+tracer at level 1, which is annotations: while the session runs, the
+stage clock (utils/trace.py) enters a ``TraceAnnotation`` per sampled
+stage, so the request's stages land in the xplane beside the device
+planes, on the trace's own clock (m3_tpu/tools/trace_gaps.py reads
+them). Outside a session nothing is annotated and nothing is written.
 """
 
 from __future__ import annotations
 
+import json
 import os
+import threading
+import time
 
 _CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": "compile_cache[hit]",
@@ -92,3 +105,80 @@ def describe() -> dict:
         "devices": [{"id": int(d.id), "platform": str(d.platform),
                      "kind": str(d.device_kind)} for d in devices],
     }
+
+
+# -- the device-trace session ------------------------------------------------
+
+_trace_lock = threading.Lock()
+_trace_dir: str | None = None
+
+
+def start_device_trace(trace_dir: str) -> dict:
+    """Start a profiler session into `trace_dir` and switch the stage
+    clock's annotations on. One session at a time."""
+    global _trace_dir
+    import jax
+
+    from m3_tpu.utils import trace
+
+    with _trace_lock:
+        if _trace_dir is not None:
+            raise RuntimeError(f"a device trace runs into {_trace_dir}")
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        t0 = time.perf_counter()
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        _trace_dir = trace_dir
+        trace.default_tracer().annotate = jax.profiler.TraceAnnotation
+        return {"tracing": True, "dir": trace_dir,
+                "start_seconds": time.perf_counter() - t0}
+
+
+def stop_device_trace() -> dict:
+    """Switch the annotations off, stop the session and say where the
+    xplane is and how long the stop took."""
+    global _trace_dir
+    import jax
+
+    from m3_tpu.utils import trace
+
+    with _trace_lock:
+        if _trace_dir is None:
+            raise RuntimeError("no device trace runs")
+        trace.default_tracer().annotate = None
+        t0 = time.perf_counter()
+        try:
+            jax.profiler.stop_trace()
+        finally:
+            trace_dir, _trace_dir = _trace_dir, None
+        return {"tracing": False, "dir": trace_dir,
+                "stop_seconds": time.perf_counter() - t0}
+
+
+def handle_debug_profile_device(method: str, q: dict, body: bytes):
+    """``/debug/profile/device`` -> (status, payload, content_type), the
+    contract of profiler.handle_debug_profile. POST ``{"action":
+    "start", "dir": ...}`` or ``{"action": "stop"}``; GET says whether a
+    session runs."""
+    def answer(status: int, doc: dict):
+        return status, json.dumps(doc).encode(), "application/json"
+
+    if method == "GET":
+        return answer(200, {"tracing": _trace_dir is not None,
+                            "dir": _trace_dir})
+    if method != "POST":
+        return answer(405, {"error": "GET or POST"})
+    try:
+        doc = json.loads(body or b"{}")
+        action = doc.get("action")
+        if action == "start":
+            if not doc.get("dir"):
+                return answer(400, {"error": "start needs a dir"})
+            return answer(200, start_device_trace(str(doc["dir"])))
+        if action == "stop":
+            return answer(200, stop_device_trace())
+    except (ValueError, RuntimeError) as e:
+        return answer(409 if isinstance(e, RuntimeError) else 400,
+                      {"error": str(e)})
+    return answer(400, {"error": "action is start or stop"})

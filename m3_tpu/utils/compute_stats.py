@@ -1,5 +1,5 @@
-"""Device-compute observability plane: per-program execute telemetry,
-static XLA program profiles, and padding-waste accounting.
+"""Device-compute observability plane: per-program execute telemetry
+and padding-waste accounting.
 
 `dispatch.jit_tracker` answers ONE question per tracked call — did it
 hit the executable cache — and times the compile on a miss. Everything
@@ -14,12 +14,6 @@ tracker:
   compiler applies to plan-shape labels (sig cardinality is bounded by
   the half-octave bucket ladder, but the metrics registry must survive
   an adversarial shape storm anyway).
-- ``capture_profile`` stores the lowered program's ``cost_analysis()``
-  (FLOPs, bytes accessed) once per compile. Backends that expose
-  nothing degrade to a counted reason, never an exception.
-  ``memory_analysis`` needs a second AOT compile (jax's ``.compile()``
-  does not share the jit executable cache), so it is opt-in via
-  ``M3_TPU_COMPUTE_PROFILE_MEMORY=1``.
 - ``record_waste`` accumulates logical-vs-padded element counts at the
   half-octave/slab padding seams (query slabs, postings tensors, ragged
   encode, windowed agg); a snapshot hook publishes them as
@@ -126,8 +120,9 @@ def _row(op: str, sig: str) -> dict:
 
 
 def record_execute(op: str, sig: str, seconds: float) -> None:
-    """One tracked cache-HIT call: the wrapped wall time is device
-    dispatch + execution (trace/compile excluded by definition)."""
+    """One tracked cache-HIT call: the wrapped wall time is dispatch,
+    execution and the wait for the result (the tracker's block holds the
+    read that waits; trace/compile excluded by definition)."""
     if not _armed:
         return
     with _lock:
@@ -166,80 +161,6 @@ def record_evictions(op: str, n: int) -> None:
 
 
 _evictions: dict = {}
-
-
-# ---------------------------------------------------------------------------
-# static program profiles (cost/memory analysis, captured once per compile)
-# ---------------------------------------------------------------------------
-
-# degrade reasons are a closed set so the counter label stays bounded
-_DEGRADE_REASONS = ("lower_failed", "cost_unavailable", "cost_failed",
-                    "memory_unavailable", "profile_failed")
-_degrades: dict = {}
-
-
-def _degrade(reason: str) -> None:
-    if reason not in _DEGRADE_REASONS:
-        reason = "profile_failed"
-    _scope("profile", reason=reason).counter("degraded")
-    with _lock:
-        _degrades[reason] = _degrades.get(reason, 0) + 1
-
-
-def capture_profile(op: str, sig: str, lower) -> None:
-    """Attach the lowered program's static cost profile to (op, sig).
-
-    ``lower`` is a zero-arg callable returning a ``jax.stages.Lowered``
-    (the call site closes over the program + its args). Called only
-    from a tracked miss; every step degrades to a counted reason rather
-    than raising — telemetry must never fail a query.
-    """
-    if not _armed:
-        return
-    profile: dict = {}
-    try:
-        try:
-            lowered = lower()
-        except Exception:  # noqa: BLE001 - counted, never fatal
-            _degrade("lower_failed")
-            return
-        cost_failed = False
-        try:
-            cost = lowered.cost_analysis()
-        except Exception:  # noqa: BLE001
-            _degrade("cost_failed")
-            cost, cost_failed = None, True
-        if isinstance(cost, dict) and ("flops" in cost
-                                       or "bytes accessed" in cost):
-            if "flops" in cost:
-                profile["flops"] = float(cost["flops"])
-            if "bytes accessed" in cost:
-                profile["bytes_accessed"] = float(cost["bytes accessed"])
-        elif not cost_failed:
-            _degrade("cost_unavailable")
-        if os.environ.get("M3_TPU_COMPUTE_PROFILE_MEMORY") == "1":
-            # pays a SECOND XLA compile (AOT .compile() does not share
-            # the jit executable cache) — operator opt-in only
-            try:
-                mem = lowered.compile().memory_analysis()
-                profile["temp_bytes"] = float(mem.temp_size_in_bytes)
-                profile["output_bytes"] = float(mem.output_size_in_bytes)
-                profile["argument_bytes"] = float(mem.argument_size_in_bytes)
-            except Exception:  # noqa: BLE001
-                _degrade("memory_unavailable")
-    except Exception:  # noqa: BLE001 - belt over braces: never fatal
-        _degrade("profile_failed")
-        return
-    if profile:
-        with _lock:
-            _row(op, sig).setdefault("profile", {}).update(profile)
-
-
-def profile_for(op: str, sig: str) -> dict | None:
-    """The stored static profile for (op, sig), if one was captured."""
-    with _lock:
-        row = _programs.get((op, sig))
-        return dict(row["profile"]) if row and "profile" in row else None
 
 
 # ---------------------------------------------------------------------------
@@ -378,11 +299,10 @@ def _plan_cache_stats() -> dict | None:
 def debug_payload(top_n: int = 20) -> dict:
     """The /debug/compute JSON body: top-N programs by device time,
     plan-cache occupancy, jit evictions, padding waste, device-resident
-    cache bytes, per-device memory, profile degrades."""
+    cache bytes, per-device memory."""
     with _lock:
         rows = [dict(r) for r in _programs.values()]
         evict = dict(_evictions)
-        degr = dict(_degrades)
         waste = {f"{site}/{axis}": {
             "logical": acc[0], "padded": acc[1],
             "waste_ratio": round(1.0 - acc[0] / acc[1], 6) if acc[1] else 0.0,
@@ -397,7 +317,6 @@ def debug_payload(top_n: int = 20) -> dict:
         "device_caches": _device_cache_stats(),
         "backend": backend.describe(),
         "device_memory": device_memory(),
-        "profile_degrades": degr,
     }
 
 
@@ -417,13 +336,12 @@ def handle_debug_compute(method: str, q: dict, body: bytes):
 
 
 def reset() -> None:
-    """Test hook: drop every accumulator (table, waste, evictions,
-    degrades, sig labels) — NOT the registered cache providers."""
+    """Test hook: drop every accumulator (table, waste, evictions, sig
+    labels) — NOT the registered cache providers."""
     global _armed
     with _lock:
         _programs.clear()
         _waste.clear()
         _evictions.clear()
-        _degrades.clear()
         _sig_labels_seen.clear()
     _armed = os.environ.get("M3_TPU_COMPUTE_STATS", "1") != "0"

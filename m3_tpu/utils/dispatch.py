@@ -133,30 +133,32 @@ class jit_tracker:
     """`with jit_tracker("m3tsz_decode", jitted_fn, sig="..."): ...` —
     records compute.jit_calls{op,result=hit|miss}; on a miss, the
     trace+compile wall time into compute.jit_compile_seconds{op}; on a
-    hit (with a ``sig``), the execute wall into
-    compute.execute_seconds{op,sig} and the per-program ledger
-    (utils/compute_stats). The jitted function's private executable
-    cache (`_cache_size`) is the ground truth; entries that vanished
-    since the last tracked call bump compute_jit_evictions{op}.
+    hit (with a ``sig``), the wall into compute.execute_seconds{op,sig}
+    and the per-program ledger (utils/compute_stats). The block holds
+    the call AND the read that waits for its result (``np.asarray``,
+    ``block_until_ready``): JAX returns at the enqueue, so a block
+    without the wait times the dispatch, not the program. The jitted
+    function's private executable cache (`_cache_size`) is the ground
+    truth; entries that vanished since the last tracked call bump
+    compute_jit_evictions{op}."""
 
-    ``lower`` (zero-arg callable returning a ``jax.stages.Lowered``,
-    closing over the call's args) lets a miss capture the program's
-    static cost profile once per compile."""
-
-    def __init__(self, op: str, jitted_fn, sig: str | None = None,
-                 lower=None):
+    def __init__(self, op: str, jitted_fn, sig: str | None = None):
         self.op = op
         self.sig = sig
-        self._lower = lower
         self._fn = jitted_fn
         self._size_fn = jitted_fn._cache_size
         # ground-truth compile outcome of the wrapped call, readable after
         # the with-block (the whole-query compiler keys its plan-cache
         # hit/miss accounting off this rather than guessing)
         self.miss = False
-        # wrapped-call wall time, readable after the with-block (the
+        # wrapped-block wall time, readable after the with-block (the
         # explain `device` block attributes it per query)
         self.seconds = 0.0
+
+    def missed(self) -> bool:
+        """Inside the block, after the call has returned: whether it
+        compiled (a stage that dispatched a miss closes as *.compile)."""
+        return self._size_fn() > self._before
 
     def __enter__(self):
         import time
@@ -186,13 +188,10 @@ class jit_tracker:
         from m3_tpu.utils import compute_stats
 
         if miss:
-            # the whole call IS the compile on a miss (execution time is
+            # the whole block IS the compile on a miss (execution time is
             # noise next to trace+lower+compile)
             sc.observe("compile_seconds", dt)
             compute_stats.record_compile(self.op, self.sig or "default", dt)
-            if self._lower is not None:
-                compute_stats.capture_profile(
-                    self.op, self.sig or "default", self._lower)
         else:
             compute_stats.record_execute(self.op, self.sig or "default", dt)
         return False

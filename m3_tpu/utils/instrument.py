@@ -441,6 +441,18 @@ class MetricsRegistry:
     def root_scope(self, prefix: str = "") -> Scope:
         return Scope(self, prefix)
 
+    def record_many(self, observations, increments) -> None:
+        """Histogram observations [(name, tags, value)] and counter
+        increments [(name, tags, delta)] under ONE acquisition of the
+        lock: the stage clock (utils/trace.py) publishes a request's
+        stages together when its outermost span closes, not two
+        acquisitions a stage from inside the request."""
+        with self._lock:
+            for name, tags, value in observations:
+                self.histograms[(name, tags)].observe_locked(value)
+            for name, tags, delta in increments:
+                self.counters[(name, tags)].value += delta
+
     def merge_histogram(self, name: str, tags: tuple, bounds: tuple,
                         counts_delta, sum_delta: float) -> None:
         """Fold externally-accumulated histogram DELTAS into this

@@ -29,10 +29,12 @@ HISTOGRAMS = {
     #                             also compute.execute{op,sig} — the
     #                             compute_execute_seconds exposition
     #                             family: wall time of one tracked
-    #                             cache-HIT program call (dispatch +
-    #                             device execution; sig is the
-    #                             shape-bucket signature, <=64 distinct
-    #                             labels then "other")
+    #                             cache-HIT program call (dispatch,
+    #                             device execution and the wait for
+    #                             the result; sig is the shape-bucket
+    #                             signature, <=64 distinct labels then
+    #                             "other"); and query.stage{route,
+    #                             stage}, the stage clock's self-time
     "batch_size",               # decode.batch per-rung batch size
     "compile_seconds",          # compute.jit trace+compile on cache miss
     "plan_compile_seconds",     # compute.query_plan whole-plan compile
@@ -175,11 +177,20 @@ TIMERS = {
 #       bf16_bytes, ...) from registered device-resident cache
 #       providers: the hot tier (storage/hottier) and the per-segment
 #       postings columns (index/packed)
-#   compute_profile_degraded {reason=...}      counter: static program
-#       profile capture (lowered cost_analysis / memory_analysis)
-#       unavailable on this backend — counted, never fatal; reason is
-#       one of lower_failed / cost_failed / cost_unavailable /
-#       memory_unavailable / profile_failed
+#
+# The stage clock of the served query path (utils/trace.py stage()),
+# query.stage scope, fed for every request whatever the trace sampling
+# (a thread's stages are published together, in one acquisition of the
+# registry's lock, when its outermost span closes):
+#   query_stage_seconds {route,stage}          histogram: a stage's wall
+#       SELF-time (own minus what the stages beneath it covered on its
+#       thread), one observation per span; route is query_range / query /
+#       remote_write / remote_read / other (query/api.py _ROUTES), stage
+#       a trace.STAGE_* name. Per route the request thread's stages sum
+#       to the root stage `request`'s whole duration
+#   query_stage_cpu_seconds {route,stage}      counter: the same spans'
+#       thread CPU self-time (time.thread_time_ns); wall minus CPU is
+#       time the thread waited (GIL, locks, the device)
 #
 # Tier-resolution read routing (query/resolver.resolve_read), query.tier
 # scope with a {tier=...} label (raw / stitched / pinned_raw /

@@ -8,8 +8,10 @@ threading a parameter through a dozen signatures:
 
 - the engine opens/finishes the record (query text, namespace, trace id,
   total duration) and exposes it per thread as `Engine.last_stats`;
-- the resolver records series matched and per-stage durations
-  (query_ids / read_many);
+- the resolver records series matched; per-stage durations are written
+  by the stage clock (utils/trace.py ``stage()``: a metered stage adds
+  its whole wall time to ``stages`` under its name on close; this module
+  keeps no clock of its own for them);
 - the block cache records hits/misses, the decode ladder records which
   rung served each (shard, block, volume) group and the bytes decoded.
 
@@ -53,7 +55,9 @@ class QueryStats:
     cache_misses: int = 0
     # decode rung -> groups served (device / native / scalar / cache)
     decode_rungs: dict = field(default_factory=dict)
-    # stage name -> seconds (query_ids, read_many, eval)
+    # stage name -> INCLUSIVE wall seconds, written by trace.stage() on
+    # close (query_ids, read_many, eval and the other trace.STAGE_*
+    # names; /metrics holds the same stages' SELF-time)
     stages: dict = field(default_factory=dict)
     # remote leg -> (calls, seconds, rows): one entry per storage node /
     # fanout zone this query touched (the cross-node half of EXPLAIN
@@ -206,13 +210,17 @@ def current() -> QueryStats | None:
 def start(query: str = "", namespace: str = "",
           clock=None) -> QueryStats:
     """Open a record for this thread's query. Nested engines (subqueries,
-    front-ends compiling through the same engine) keep the OUTER record:
-    the inner call gets the same object back with a depth mark, and only
-    the matching outermost `finish` closes it. `clock` (seconds, default
-    perf_counter) is injectable so admission tests run on virtual time."""
+    front-ends compiling through the same engine, the engine under the
+    HTTP handler's request-long record) keep the OUTER record: the inner
+    call gets the same object back with a depth mark (and names the
+    query where the outer could not), and only the matching outermost
+    `finish` closes it. `clock` (seconds, default perf_counter) is
+    injectable so admission tests run on virtual time."""
     cur = getattr(_tls, "current", None)
     if cur is not None:
         cur._depth = getattr(cur, "_depth", 0) + 1  # type: ignore[attr-defined]
+        cur.query = cur.query or query
+        cur.namespace = cur.namespace or namespace
         return cur
     st = QueryStats(query=query, namespace=namespace,
                     start_unix_ns=time.time_ns())
@@ -226,18 +234,18 @@ def start(query: str = "", namespace: str = "",
 def finish(st: QueryStats) -> None:
     """Close the record, stamp duration, admit to the ring when it clears
     the threshold bar (env floor raised to the live p99 once the adaptive
-    source arms). A nested finish (depth > 0) only pops one level — the
-    outer query keeps accruing; object identity alone can't tell owner
-    from nested caller since start() hands the same record back."""
+    source arms). A nested finish (depth > 0) only pops one level and
+    stamps the duration so far — the outer query keeps accruing; object
+    identity alone can't tell owner from nested caller since start()
+    hands the same record back."""
     if getattr(_tls, "current", None) is not st:
         return
+    st.duration_s = st._clock() - st._t0  # type: ignore[attr-defined]
     depth = getattr(st, "_depth", 0)
     if depth > 0:
         st._depth = depth - 1  # type: ignore[attr-defined]
         return
     _tls.current = None
-    clock = getattr(st, "_clock", time.perf_counter)
-    st.duration_s = clock() - getattr(st, "_t0", clock())
     if st.duration_s >= threshold_s():
         with _ring_lock:
             _ring.append(st)
@@ -367,20 +375,6 @@ def merge_storage(doc: dict | None) -> None:
                         float(pipe.get("wall_s", 0.0)),
                         {k: float(v)
                          for k, v in (pipe.get("stages") or {}).items()})
-
-
-@contextmanager
-def stage(name: str):
-    """Time a named stage of the active query (no-op outside one)."""
-    st = getattr(_tls, "current", None)
-    if st is None:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        st.stages[name] = st.stages.get(name, 0.0) + time.perf_counter() - t0
 
 
 def slow_queries(limit: int = 50) -> list[dict]:

@@ -22,7 +22,7 @@ into ``429`` + ``Retry-After`` (client/breaker.py treats that as
 backpressure, never as a breaker failure). Every decision point emits
 per-tenant allow/shed counters into the metrics registry and the shed
 path carries the ``tenant.admission.shed`` tracepoint — enforced
-statically by tools/check_observability.py invariant 5.
+statically by m3lint's inv-admission-counted (``python -m tools.m3lint``).
 
 Limits are runtime-updatable through the cluster KV (``m3_tpu.tenants``
 key, same watch discipline as cluster/runtime.py) so an operator can

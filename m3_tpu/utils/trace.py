@@ -25,33 +25,52 @@ lock-free ``itertools.count`` (atomic under CPython), replacing the old
 documented-racy ``_counter % sample_every`` increment.
 
 ``M3_TPU_TRACE_SAMPLE`` overrides the default tracer's sampling: ``0``
-disables tracing, ``N`` samples one root trace in N.
+disables tracing, ``N`` samples one root trace in N. Sampling governs the
+ring only.
+
+The stage clock. ``stage()`` opens a span that keeps time whatever the
+sampling decision: wall (``perf_counter_ns``) and thread CPU
+(``thread_time_ns``), and through the thread's span stack the part of
+both that the stages beneath it covered. On close a metered stage
+records its wall SELF-time (own minus covered) for
+``query_stage_seconds{route,stage}`` and its CPU self-time for
+``query_stage_cpu_seconds{route,stage}`` (published together when the
+thread's outermost span closes) and writes its whole wall time to the
+active ``QueryStats.stages``: the one clock the query path's
+layer boundaries share (/metrics, the request's span tree,
+/debug/slow_queries). ``route`` rides the SpanContext, set where the
+HTTP handler routes. Plain spans between two stages are transparent to
+that sum. While a device-trace session runs (utils/backend
+``start_device_trace``), a sampled stage also enters a
+``jax.profiler.TraceAnnotation`` of its name, so it lands in the xplane
+on the device trace's clock.
 """
 
 from __future__ import annotations
 
 import itertools
 import os
+import random
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
-# tracepoint name constants (the tracepoint.go role). The observability
-# check (tools/check_observability.py) asserts these values stay unique.
+from m3_tpu.utils import querystats
+
+# tracepoint name constants (the tracepoint.go role). m3lint's
+# inv-tracepoint-unique (python -m tools.m3lint) asserts these values
+# stay unique.
 DB_WRITE = "storage.db.write"
 DB_WRITE_BATCH = "storage.db.write_batch"
 DB_QUERY = "storage.db.query"
 INDEX_QUERY = "index.query"
 SHARD_FLUSH = "storage.shard.flush"
-ENGINE_QUERY = "query.engine.query_range"
 SESSION_FETCH = "client.session.fetch_many"
 AGG_FLUSH = "aggregator.flush"
 READ_MANY = "storage.ns.read_many"
-DECODE_BATCH = "storage.decode.batch"
 DBNODE_HANDLE = "dbnode.handle"
-API_REQUEST = "query.api.request"
 FANOUT_READ = "query.fanout.read_many"
 MSG_SEND = "msg.producer.send"
 MSG_RECV = "msg.consumer.handle"
@@ -64,6 +83,34 @@ QUERY_COMPILE_FALLBACK = "query.compile.fallback"
 WATCHDOG_STALL = "watchdog.stall"
 PLACEMENT_SYNC_DEFER = "placement.sync.defer"
 WIRE_FALLBACK = "wire.fallback"
+PIPELINE_CONSUME = "storage.pipeline.consume"
+
+# stage names of the served query path: one per layer boundary (PERF.md
+# section 3), the `stage` label of query_stage_seconds and the keys of
+# QueryStats.stages. A stage whose jit_tracker reports a miss closes as
+# the *.compile name instead.
+STAGE_REQUEST = "request"
+STAGE_PARSE_PLAN = "parse_plan"
+STAGE_QUERY_IDS = "query_ids"
+STAGE_READ_MANY = "read_many"
+STAGE_GATHER = "read_many.gather"
+STAGE_DECODE_HOST = "decode.host"
+STAGE_DECODE_WAIT = "decode.device_wait"
+STAGE_DECODE_COMPILE = "decode.compile"
+STAGE_SLAB_PREP = "slab_prep"
+STAGE_PLAN_DISPATCH = "plan.dispatch"
+STAGE_PLAN_WAIT = "plan.device_wait"
+STAGE_PLAN_COMPILE = "plan.compile"
+STAGE_EVAL = "eval"
+STAGE_RENDER = "render"
+
+# the `route` label's values (query/api.py route_of); a stage opened
+# outside any routed request reports as ROUTE_OTHER
+ROUTE_OTHER = "other"
+
+# a thread whose outermost span stays open publishes its closed stages
+# at this many (a request closes some twenty)
+_PENDING_CAP = 512
 
 _ZERO_SPAN_ID = "0" * 16
 # placeholder trace id carried by a negative head decision's context —
@@ -79,18 +126,29 @@ class SpanContext:
     trace_id: str  # 32 hex chars (16 bytes)
     span_id: str   # 16 hex chars (8 bytes); "" = decision-only context
     sampled: bool = True
+    # the HTTP route this request came in by (stage metrics' label);
+    # process-local: never on the wire, "" past a remote hop
+    route: str = ""
 
     def to_traceparent(self) -> str:
         return (f"00-{self.trace_id}-{self.span_id or _ZERO_SPAN_ID}-"
                 f"{'01' if self.sampled else '00'}")
 
 
+# ids come from a process-local generator seeded from the OS, not from
+# os.urandom per id: that is a system call which gives the GIL away, and
+# a request opens some forty spans (uniqueness is what an id needs here)
+_ids = random.Random(os.urandom(16))
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=lambda: _ids.seed(os.urandom(16)))
+
+
 def new_trace_id() -> str:
-    return os.urandom(16).hex()
+    return f"{_ids.getrandbits(128):032x}"
 
 
 def new_span_id() -> str:
-    return os.urandom(8).hex()
+    return f"{_ids.getrandbits(64):016x}"
 
 
 def parse_traceparent(value: str | None) -> SpanContext | None:
@@ -128,9 +186,15 @@ class Span:
     # ring admission order, monotonic per process — the exporter's drain
     # cursor (utils/export.py) ships each recorded span exactly once
     seq: int = 0
+    # stage spans only (cpu_ns < 0 marks a plain span): wall self-time
+    # (own minus what the stages beneath covered), thread CPU time and
+    # its self part
+    self_ns: int = 0
+    cpu_ns: int = -1
+    cpu_self_ns: int = 0
 
     def to_dict(self) -> dict:
-        return {
+        out = {
             "name": self.name,
             "start_unix_ns": self.start_ns,
             "duration_us": round(self.duration_ns / 1000, 1),
@@ -140,6 +204,39 @@ class Span:
             "parent_span_id": self.parent_span_id,
             **({"tags": self.tags} if self.tags else {}),
         }
+        if self.cpu_ns >= 0:
+            out["self_us"] = round(self.self_ns / 1000, 1)
+            out["cpu_us"] = round(self.cpu_ns / 1000, 1)
+            out["cpu_self_us"] = round(self.cpu_self_ns / 1000, 1)
+        return out
+
+
+class StageFrame:
+    """One open span on its thread's stack, and the handle ``stage()``
+    yields: ``name`` may be changed until the close takes it (a compile
+    miss renames its stage), ``tag()`` reaches the ring span when the
+    request is sampled, ``wall_ns``/``cpu_ns`` are readable after the
+    close. ``covered_*`` is what the metered stages beneath have
+    covered of this frame's time, on this thread."""
+
+    __slots__ = ("name", "span", "covered_ns", "covered_cpu_ns",
+                 "wall_ns", "cpu_ns")
+
+    def __init__(self, name: str, span: "Span | None" = None):
+        self.name = name
+        self.span = span
+        self.covered_ns = 0
+        self.covered_cpu_ns = 0
+        self.wall_ns = 0
+        self.cpu_ns = 0
+
+    def tag(self, **tags) -> None:
+        if self.span is not None:
+            self.span.tags.update(tags)
+
+    @property
+    def wall_s(self) -> float:
+        return self.wall_ns / 1e9
 
 
 class Tracer:
@@ -168,12 +265,38 @@ class Tracer:
         # ring admission counter (under _lock): export_since cursors
         self._last_seq = 0
         self.enabled = True
+        # jax.profiler.TraceAnnotation while a device-trace session runs
+        # (utils/backend start_device_trace), else None: one attribute
+        # check per stage
+        self.annotate = None
 
     def _stack(self) -> list:
         st = getattr(self._tl, "stack", None)
         if st is None:
             st = self._tl.stack = []
         return st
+
+    def _pending(self) -> list:
+        """This thread's closed metered stages, (the series' tags, wall
+        self seconds, CPU self seconds), not yet on the registry."""
+        pending = getattr(self._tl, "pending", None)
+        if pending is None:
+            pending = self._tl.pending = []
+        return pending
+
+    def _publish(self) -> None:
+        """The thread's outermost span has closed: its stages reach the
+        two families in one acquisition of the registry's lock. From
+        inside the request they cost two acquisitions a stage of a lock
+        every metric of the process shares, which on the chip cost
+        `double-groupby-1` 5% of its latency (PERF.md section 6)."""
+        pending = getattr(self._tl, "pending", None)
+        if not pending:
+            return
+        _instrument.default_registry().record_many(
+            [("query.stage.seconds", tags, w) for tags, w, _c in pending],
+            [("query.stage.cpu_seconds", tags, c) for tags, _w, c in pending])
+        pending.clear()
 
     # -- context plumbing --
 
@@ -228,52 +351,137 @@ class Tracer:
 
     # -- spans --
 
-    @contextmanager
-    def span(self, name: str, **tags):
-        if not self.enabled:
-            yield None
-            return
+    def _begin(self, name: str, tags: dict):
+        """The ring half of opening a span: (Span or None, the context
+        to put back on close, whether a context was installed). A hit
+        with no active context is a trace root and draws the head
+        decision; a NEGATIVE one still installs a not-sampled context
+        for the span's extent — descendant tracepoints must follow this
+        root's decision, not draw their own (which would record orphan
+        bottom-half trees)."""
         tl = self._tl
         ctx = getattr(tl, "ctx", None)
+        if not self.enabled:
+            return None, ctx, False
         if ctx is None:
-            # trace root: head decision. A NEGATIVE decision still installs
-            # a not-sampled context for the span's extent — descendant
-            # tracepoints must follow this root's decision, not draw their
-            # own (which would record orphan bottom-half trees)
             if next(self._count) % self.sample_every:
                 tl.ctx = SpanContext(_UNSAMPLED_TRACE_ID, "", False)
-                try:
-                    yield None
-                finally:
-                    tl.ctx = None
-                return
-            trace_id = new_trace_id()
-            parent_sid: str | None = None
+                return None, None, True
+            trace_id, parent_sid, route = new_trace_id(), None, ""
         elif not ctx.sampled:
-            yield None
-            return
+            return None, ctx, False
         else:
-            trace_id = ctx.trace_id
-            parent_sid = ctx.span_id or None
+            trace_id, parent_sid, route = \
+                ctx.trace_id, ctx.span_id or None, ctx.route
         sid = new_span_id()
         stack = self._stack()
-        parent_name = stack[-1] if stack else None
-        sp = Span(name, time.time_ns(), parent=parent_name, tags=dict(tags),
+        sp = Span(name, time.time_ns(),
+                  parent=stack[-1].name if stack else None, tags=tags,
                   trace_id=trace_id, span_id=sid, parent_span_id=parent_sid)
-        stack.append(name)
-        prev_ctx = ctx
-        tl.ctx = SpanContext(trace_id, sid, True)
+        tl.ctx = SpanContext(trace_id, sid, True, route)
+        return sp, ctx, True
+
+    def _admit(self, sp: Span) -> None:
+        with self._lock:
+            self._last_seq += 1
+            sp.seq = self._last_seq
+            self._spans.append(sp)
+
+    @contextmanager
+    def span(self, name: str, **tags):
+        """A plain tracepoint: recorded when the request is sampled, a
+        no-op otherwise. Between two stages it is transparent to the
+        stage clock: what the stages beneath it covered passes through
+        to the frame above."""
+        sp, prev_ctx, installed = self._begin(name, tags)
+        if sp is None:
+            try:
+                yield None
+            finally:
+                if installed:
+                    self._tl.ctx = prev_ctx
+            return
+        stack = self._stack()
+        frame = StageFrame(name, sp)
+        stack.append(frame)
         t0 = time.perf_counter_ns()
         try:
             yield sp
         finally:
             sp.duration_ns = time.perf_counter_ns() - t0
             stack.pop()
-            tl.ctx = prev_ctx
-            with self._lock:
-                self._last_seq += 1
-                sp.seq = self._last_seq
-                self._spans.append(sp)
+            if stack:
+                stack[-1].covered_ns += frame.covered_ns
+                stack[-1].covered_cpu_ns += frame.covered_cpu_ns
+            self._tl.ctx = prev_ctx
+            self._admit(sp)
+            if not stack:
+                self._publish()
+
+    @contextmanager
+    def stage(self, name: str, metered: bool = True, **tags):
+        """A span that keeps time for EVERY request, sampled or not, with
+        tracing enabled or not: wall and thread CPU, and what the stages
+        beneath covered of each. Yields its StageFrame. A metered stage
+        feeds query_stage_seconds / query_stage_cpu_seconds with its
+        self-time and QueryStats.stages with its whole wall time on
+        close; an unmetered one (a pipeline leg run inline) only keeps
+        its own wall time for the caller (no CPU clock) and passes what
+        was covered beneath it upward. The span enters the ring when the
+        request is sampled."""
+        tl = self._tl
+        route = getattr(getattr(tl, "ctx", None), "route", "") or ROUTE_OTHER
+        sp, prev_ctx, installed = self._begin(name, tags)
+        stack = self._stack()
+        frame = StageFrame(name, sp)
+        stack.append(frame)
+        ann = None
+        if sp is not None and self.annotate is not None:
+            ann = self.annotate(name)
+            ann.__enter__()
+        # the wall pair encloses the CPU pair, so CPU <= wall per stage;
+        # an unmetered stage feeds no CPU counter and reads no CPU clock
+        t0 = time.perf_counter_ns()
+        c0 = time.thread_time_ns() if metered else 0
+        try:
+            yield frame
+        finally:
+            cpu = frame.cpu_ns = time.thread_time_ns() - c0 if metered else 0
+            wall = frame.wall_ns = time.perf_counter_ns() - t0
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            stack.pop()
+            parent = stack[-1] if stack else None
+            if metered:
+                if parent is not None:
+                    parent.covered_ns += wall
+                    parent.covered_cpu_ns += cpu
+                self_ns = max(0, wall - frame.covered_ns)
+                cpu_self_ns = max(0, cpu - frame.covered_cpu_ns)
+                pending = self._pending()
+                pending.append(
+                    ((("route", route), ("stage", frame.name)),
+                     self_ns / 1e9, cpu_self_ns / 1e9))
+                if len(pending) >= _PENDING_CAP:
+                    self._publish()
+                st = querystats.current()
+                if st is not None:
+                    st.stages[frame.name] = \
+                        st.stages.get(frame.name, 0.0) + wall / 1e9
+            elif parent is not None:
+                parent.covered_ns += frame.covered_ns
+                parent.covered_cpu_ns += frame.covered_cpu_ns
+            if installed:
+                tl.ctx = prev_ctx
+            if sp is not None:
+                sp.name = frame.name
+                sp.duration_ns = wall
+                if metered:
+                    sp.self_ns, sp.cpu_ns, sp.cpu_self_ns = \
+                        self_ns, cpu, cpu_self_ns
+                self._admit(sp)
+            if not stack:
+                self._publish()
 
     # -- ring access --
 
@@ -363,6 +571,12 @@ def default_tracer() -> Tracer:
 def span(name: str, **tags):
     """Open a span on the process tracer: `with trace.span(trace.DB_WRITE):`"""
     return _default.span(name, **tags)
+
+
+def stage(name: str, metered: bool = True, **tags):
+    """Open a stage of the served query path on the process tracer:
+    `with trace.stage(trace.STAGE_RENDER):`"""
+    return _default.stage(name, metered, **tags)
 
 
 def current() -> SpanContext | None:

@@ -36,9 +36,7 @@ def eval_inline_tracked(sig, padded):
 
 def eval_named_tracker(sig, padded):
     prog = _program(sig)
-    tracker = dispatch.jit_tracker(
-        "fixture_op", prog, sig=str(sig),
-        lower=lambda: prog.lower(padded))
+    tracker = dispatch.jit_tracker("fixture_op", prog, sig=str(sig))
     with tracker:                # blessed: tracker bound to a Name
         out = prog(padded)
     return out, tracker.seconds

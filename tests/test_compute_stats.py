@@ -6,8 +6,7 @@ wall time in the per-program ledger and the compute_execute_seconds
 histogram (fake clock — no tolerance); evictions are counted from the
 executable-cache ground truth (a clear-then-retrace is a miss plus an
 eviction, never a hit); sig labels and the program table are bounded
-with an ``other`` overflow; static profile capture degrades to counted
-reasons, never an exception; the /debug/compute surface answers on all
+with an ``other`` overflow; the /debug/compute surface answers on all
 four services (fault-exempt on dbnode, like /debug/profile) and NEVER
 initializes a jax backend; the ?explain=analyze ``device`` block is
 present and consistent at 1 and 8 virtual mesh devices; and the whole
@@ -175,52 +174,6 @@ class TestCardinalityBounds:
 
 
 # ---------------------------------------------------------------------------
-# static profile capture: counted degrade, never fatal
-# ---------------------------------------------------------------------------
-
-class _FakeLowered:
-    def __init__(self, cost):
-        self._cost = cost
-
-    def cost_analysis(self):
-        if isinstance(self._cost, Exception):
-            raise self._cost
-        return self._cost
-
-
-class TestProfileCapture:
-    def test_cost_profile_stored(self):
-        compute_stats.capture_profile(
-            "p", "s", lambda: _FakeLowered({"flops": 3.0,
-                                            "bytes accessed": 12.0}))
-        assert compute_stats.profile_for("p", "s") == {
-            "flops": 3.0, "bytes_accessed": 12.0}
-        assert compute_stats.debug_payload()["profile_degrades"] == {}
-
-    def test_lower_failure_counted(self):
-        def boom():
-            raise RuntimeError("no backend")
-
-        compute_stats.capture_profile("p", "s", boom)
-        assert compute_stats.profile_for("p", "s") is None
-        assert compute_stats.debug_payload()["profile_degrades"] == {
-            "lower_failed": 1}
-
-    def test_cost_unavailable_counted(self):
-        # a CPU/backends without cost info: empty analysis, counted once
-        compute_stats.capture_profile("p", "s", lambda: _FakeLowered({}))
-        assert compute_stats.debug_payload()["profile_degrades"] == {
-            "cost_unavailable": 1}
-
-    def test_cost_raise_counts_once_not_twice(self):
-        compute_stats.capture_profile(
-            "p", "s", lambda: _FakeLowered(RuntimeError("unimplemented")))
-        # cost_failed only — NOT also cost_unavailable
-        assert compute_stats.debug_payload()["profile_degrades"] == {
-            "cost_failed": 1}
-
-
-# ---------------------------------------------------------------------------
 # padding-waste ledger + gauges
 # ---------------------------------------------------------------------------
 
@@ -337,7 +290,7 @@ class TestDebugComputeSurface:
         assert [r["op"] for r in doc["programs"]] == ["hot"]
         assert set(doc) >= {"armed", "programs", "plan_cache",
                             "jit_evictions", "waste", "device_caches",
-                            "device_memory", "profile_degrades"}
+                            "device_memory"}
         status, _p, _ct = compute_stats.handle_debug_compute(
             "POST", {}, b"{}")
         assert status == 405
@@ -468,8 +421,7 @@ class TestExplainDeviceBlock:
         db.open(START)
         rng = np.random.default_rng(11)
         # 23 series: a distinct Sp shape bucket from the other test
-        # files, so THIS file's warm run pays the miss that captures the
-        # static profile
+        # files, so THIS file's warm run pays the miss
         for i in range(23):
             tags = [(b"host", b"h%02d" % (i % 5)), (b"i", b"%02d" % i)]
             t = START
@@ -495,7 +447,7 @@ class TestExplainDeviceBlock:
     def test_device_block_single_device(self, engine, monkeypatch):
         monkeypatch.setenv("M3_TPU_QUERY_COMPILE", "1")
         monkeypatch.setenv("M3_TPU_QUERY_SHARD", "0")
-        self._run(engine, collect=False)  # warm: miss + profile capture
+        self._run(engine, collect=False)  # warm: the miss
         _v, doc = self._run(engine, collect=True)
         assert doc["compiled"]["ran"] is True
         dev = doc["compiled"]["device"]
@@ -508,9 +460,6 @@ class TestExplainDeviceBlock:
         assert pad["series"]["padded"] >= 23
         assert pad["time"]["padded"] >= pad["time"]["logical"]
         assert 0.0 <= dev["waste_ratio"] < 1.0
-        # CPU cost_analysis works without compiling: the static profile
-        # captured on the warm run's miss rides every later explain
-        assert dev["flops"] > 0 and dev["bytes_accessed"] > 0
         # the same program ranks in the /debug/compute table
         ops = {r["op"] for r in
                compute_stats.debug_payload()["programs"]}

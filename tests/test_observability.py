@@ -424,9 +424,9 @@ class TestTwoNodeFanoutTrace:
         spans = doc["spans"]
         assert spans and all(s["trace_id"] == trace_id for s in spans)
         names = [s["name"] for s in spans]
-        for expected in (trace.API_REQUEST, trace.ENGINE_QUERY,
+        for expected in (trace.STAGE_REQUEST, trace.STAGE_EVAL,
                          trace.SESSION_FETCH, trace.DBNODE_HANDLE,
-                         trace.READ_MANY, trace.DECODE_BATCH):
+                         trace.READ_MANY, trace.STAGE_DECODE_HOST):
             assert expected in names, f"missing {expected} in {names}"
         # one batched /read_batch per node -> two dbnode read spans, each
         # parented by the coordinator's session fetch span
@@ -439,7 +439,7 @@ class TestTwoNodeFanoutTrace:
             assert s["parent_span_id"] == fetch[0]["span_id"]
         # ONE stitched tree: every span hangs off the single request root
         tree = doc["tree"]
-        assert len(tree) == 1 and tree[0]["name"] == trace.API_REQUEST
+        assert len(tree) == 1 and tree[0]["name"] == trace.STAGE_REQUEST
 
         def count(node):
             return 1 + sum(count(c) for c in node["children"])
@@ -715,7 +715,8 @@ class TestExplainAnalyzeFanout(TestTwoNodeFanoutTrace):
             timeout=10).read())
         assert doc["count"] > 0
         names = [s["name"] for s in doc["spans"]]
-        assert trace.API_REQUEST in names and trace.DECODE_BATCH in names
+        assert trace.STAGE_REQUEST in names
+        assert trace.STAGE_DECODE_HOST in names
         assert len(doc["tree"]) == 1
 
 

@@ -62,11 +62,14 @@ class TestEndToEndSpans:
             doc = json.loads(urllib.request.urlopen(
                 f"http://127.0.0.1:{port}/debug/traces", timeout=10).read())
             names = [s["name"] for s in doc["spans"]]
-            assert trace.ENGINE_QUERY in names
+            assert trace.STAGE_EVAL in names
             assert trace.INDEX_QUERY in names
-            # index query nests under the engine span
+            # index query nests under the engine's eval stage, inside
+            # the resolver's query_ids stage
             idx = next(s for s in doc["spans"] if s["name"] == trace.INDEX_QUERY)
-            assert idx["parent"] == trace.ENGINE_QUERY
+            assert idx["parent"] == trace.STAGE_QUERY_IDS
+            by_id = {s["span_id"]: s for s in doc["spans"]}
+            assert by_id[idx["parent_span_id"]]["parent"] == trace.STAGE_EVAL
         finally:
             api.shutdown()
             db.close()
