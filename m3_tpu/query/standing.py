@@ -283,8 +283,7 @@ class StandingEvaluator:
             docs = ns.query_ids(matchers_to_query(sel.matchers), t_lo, t_hi)
             ids = [d.series_id for d in docs]
             if ids:
-                shards.update(
-                    int(s) for s in ns.shard_set.lookup_many(ids))
+                shards.update(ns.shards_of(ids))
         state.shards = shards
 
     def _run(self, rule, state, ns, start_pt: int, end_pt: int,
@@ -352,7 +351,7 @@ class StandingEvaluator:
         from m3_tpu.utils.ident import tags_to_id
 
         ids = list({tags_to_id(name, tags) for name, tags, _t, _v in entries})
-        for sid in {int(s) for s in ns.shard_set.lookup_many(ids)}:
+        for sid in set(ns.shards_of(ids)):
             shard = ns.shards.get(sid)
             if shard is not None:
                 self._last_shard_versions[sid] = shard.data_version

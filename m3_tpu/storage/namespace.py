@@ -12,7 +12,7 @@ from m3_tpu.index.index import NamespaceIndex
 from m3_tpu.index.query import Query
 from m3_tpu.storage.options import DatabaseOptions, NamespaceOptions
 from m3_tpu.storage.shard import Shard
-from m3_tpu.storage.sharding import ShardSet
+from m3_tpu.storage.sharding import ShardRoutes, ShardSet
 
 
 class Namespace:
@@ -63,6 +63,10 @@ class Namespace:
         self.ns_uid = next(self._UID)
         # bumped by every shard add/remove (see data_version)
         self._placement_epoch = 0
+        # series -> shard, hashed once per id (see shards_of); expire
+        # rotates it each time the retention cutoff moves on a block
+        self._routes = ShardRoutes()
+        self._routes_cutoff = None
 
     _UID = itertools.count()
 
@@ -135,19 +139,24 @@ class Namespace:
         if self.index is not None:
             self.index.insert(series_id, tags, t_ns)
 
+    def shards_of(self, series_ids: list[bytes]) -> list[int]:
+        """The shard of each id, == shard_set.lookup_many(series_ids):
+        a probe of the remembered routes, with one murmur3 pass over
+        the ids not routed before. Ownership is the caller's test, on
+        every call: the routes do not move with a placement change."""
+        return self._routes.lookup_many(self.shard_set, series_ids)
+
     def route_many(self, series_ids: list[bytes]
                    ) -> tuple[dict[int, "object"], dict[int, str]]:
-        """Vectorized series->shard routing for a batch: one murmur3 pass
-        (ShardSet.lookup_many), then one row-index gather per distinct
-        shard — no per-row python loop. Returns ({owned shard id: row
+        """Series->shard routing for a batch (shards_of), then one
+        row-index gather per distinct shard. Returns ({owned shard id: row
         index ndarray}, {row index: error} for rows landing on unowned
         shards — sparse, so the clean path allocates nothing per row).
         Split from write_many so Database.write_batch can validate
         ownership BEFORE logging, the per-point write order."""
         import numpy as np
 
-        shards_arr = np.asarray(self.shard_set.lookup_many(series_ids),
-                                np.int64)
+        shards_arr = np.asarray(self.shards_of(series_ids), np.int64)
         by_shard: dict[int, object] = {}
         errors: dict[int, str] = {}
         for s in np.unique(shards_arr).tolist():
@@ -165,13 +174,13 @@ class Namespace:
                    routed: tuple | None = None,
                    only_rows: list | None = None) -> list[str | None]:
         """Storage-side batched writes (the write half of read_many's
-        contract): rows route in one vectorized murmur3 pass
-        (ShardSet.lookup_many — pass `routed` to reuse a route_many
-        result), each owned shard takes its rows through ONE buffer lock
-        per (shard, window) group (Shard.write_many), and the reverse
-        index sees one pre-filtered insert_many pass. Rows landing on
-        unowned shards degrade per entry — the batch never fails
-        wholesale. Returns per-row error strings (None = written).
+        contract): rows route in one pass (route_many — pass `routed`
+        to reuse its result), each owned shard takes its rows through
+        ONE buffer lock per (shard, window) group (Shard.write_many),
+        and the reverse index sees one pre-filtered insert_many pass.
+        Rows landing on unowned shards degrade per entry — the batch
+        never fails wholesale. Returns per-row error strings (None =
+        written).
 
         ``only_rows`` (with ``routed``) restricts the pass to those row
         indices — the pipelined write path's per-WAL-chunk call shape:
@@ -274,7 +283,7 @@ class Namespace:
         from m3_tpu.storage import pipeline
 
         by_shard: dict[int, list[int]] = {}
-        for i, shard_id in enumerate(self.shard_set.lookup_many(series_ids)):
+        for i, shard_id in enumerate(self.shards_of(series_ids)):
             if shard_id not in self.shards:
                 raise KeyError(f"shard {shard_id} not owned by this node")
             by_shard.setdefault(shard_id, []).append(i)
@@ -410,6 +419,13 @@ class Namespace:
         return n
 
     def expire(self, now_ns: int) -> int:
+        r = self.opts.retention
+        cutoff = r.block_start(now_ns - r.retention_ns)
+        if cutoff != self._routes_cutoff:
+            # a block period has passed: forget the routes of series
+            # not touched since the one before it did
+            self._routes.rotate()
+            self._routes_cutoff = cutoff
         return sum(s.expire(now_ns) for s in self.shards.values())
 
     def _spanned_index_starts(self, data_block_start: int) -> range:

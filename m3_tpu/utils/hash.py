@@ -26,8 +26,13 @@ def murmur3_32_batch(ids: list[bytes], seed: int = 0):
     with per-row active masks (rows shorter than the current block keep
     their prior h). Arithmetic is uint64 masked back to 32 bits after
     every op so the wraparound semantics match the scalar path exactly.
-    Worth it from a few hundred ids (read_many's series->shard routing
-    hashes 10k+ ids per call)."""
+    Faster than the scalar loop from about 64 ids, but not cheap: it
+    allocates n x max_len x 8 bytes and makes ~20 numpy calls per
+    4-byte block (on a sandbox CPU 2.9 s for 120,000 171-byte ids;
+    2,000 such ids 9 ms alone, 104 ms under eight threads). Run on
+    every read it was two fifths of a v5e host's on-CPU samples
+    (PERF.md, PR 26), so routing remembers its answers
+    (storage/sharding.py ShardRoutes) and only unseen ids come here."""
     import numpy as np
 
     n = len(ids)

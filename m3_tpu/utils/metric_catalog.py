@@ -116,6 +116,12 @@ TIMERS = {
 #       (compiled path; the same outcome rides the ?explain=analyze
 #       hot_tier block)
 #
+# Series -> shard routing (storage/sharding.py ShardRoutes):
+#   storage_shard_route_hit / storage_shard_route_miss   series ids
+#       routed from a namespace's remembered routes / hashed with
+#       murmur3 (first sight, or not routed for two block periods);
+#       added to once per batched read, write or standing-rule probe
+#
 # Device-compiled inverted index (ROADMAP #4), compute.index scope:
 #   compute_index_device                       segments whose boolean
 #       postings algebra ran as ONE fused ragged program

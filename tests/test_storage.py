@@ -425,3 +425,159 @@ class TestBatchedShardRouting:
         # small batches ride the scalar path; same answers
         assert ss.lookup_many(ids[:3]) == [ss.lookup(s) for s in ids[:3]]
         assert ss.lookup_many([]) == []
+
+
+def _random_ids(seed: int, n: int) -> list[bytes]:
+    rng = np.random.default_rng(seed)
+    return [bytes(rng.integers(0, 256, int(k)).astype(np.uint8))
+            for k in rng.integers(0, 401, n)]
+
+
+def _route_counts() -> tuple[float, float]:
+    from m3_tpu.utils.instrument import default_registry
+
+    c = default_registry().counters
+    return (c[("storage.shard_route.hit", ())].value,
+            c[("storage.shard_route.miss", ())].value)
+
+
+class TestRememberedShardRouting:
+    """PR 27: a namespace hashes a series id to its shard once and
+    probes a two-generation map after that (storage/sharding.py
+    ShardRoutes); the answers are ShardSet.lookup's, bit for bit."""
+
+    # under and over sharding._BATCH_MIN (64), and nothing at all
+    @pytest.mark.parametrize("n", [0, 1, 63, 64, 65, 700])
+    def test_routes_match_scalar_lookup(self, n):
+        from m3_tpu.storage.sharding import ShardRoutes, ShardSet
+
+        ss = ShardSet(16)
+        ids = _random_ids(27 + n, n)
+        ids += ids[: n // 3]  # repeats inside one batch
+        want = [ss.lookup(s) for s in ids]
+        routes = ShardRoutes()
+        h0, m0 = _route_counts()
+        assert routes.lookup_many(ss, ids) == want  # hashed
+        h1, m1 = _route_counts()
+        assert routes.lookup_many(ss, ids) == want  # remembered
+        h2, m2 = _route_counts()
+        assert len(routes) == len(set(ids))
+        # once per call, the call's totals: every id is a hit or a miss
+        assert (h1 - h0) + (m1 - m0) == len(ids)
+        # a repeat of an unseen id is not hashed twice
+        assert m1 - m0 == len(set(ids))
+        assert (h2 - h1, m2 - m1) == (len(ids), 0)
+        # another (seed, n_shards) is another mapping, not a stale hit
+        other = ShardSet(16, seed=7)
+        assert routes.lookup_many(other, ids) == [other.lookup(s)
+                                                  for s in ids]
+
+    def test_routes_survive_assign_shards(self, tmp_path):
+        db = make_db(tmp_path)
+        ns = db.namespaces["default"]
+        ids = [b"cpu_usage_user|hostname=host_%d" % i for i in range(200)]
+        want = [ns.shard_set.lookup(s) for s in ids]
+        assert ns.shards_of(ids) == want
+        old_set = ns.shard_set
+        db.assign_shards({0, 1}, START)
+        assert ns.shard_set is not old_set
+        assert ns.shards_of(ids) == want  # routing does not move
+        _, m0 = _route_counts()
+        kept = [s for s, sh in zip(ids, want) if sh in (0, 1)]
+        gone = [s for s, sh in zip(ids, want) if sh in (2, 3)]
+        assert len(ns.read_many(kept, START, START + HOUR)) == len(kept)
+        assert _route_counts()[1] == m0  # ... and is not hashed again
+        # ownership is tested on every call: a shard handed away is
+        # refused on the next read, and per row on the next write
+        with pytest.raises(KeyError, match="not owned by this node"):
+            ns.read_many(kept[:2] + gone[:1], START, START + HOUR)
+        by_shard, errors = ns.route_many(kept[:2] + gone[:2])
+        assert sorted(np.concatenate(list(by_shard.values())).tolist()) \
+            == [0, 1]
+        assert set(errors) == {2, 3}
+        assert all("not owned by this node" in e for e in errors.values())
+        res = db.write_batch("default", [
+            (b"m", [(b"k", b"%d" % i)], START + 10**9, 1.0)
+            for i in range(40)])
+        sids = [tags_to_id(b"m", [(b"k", b"%d" % i)]) for i in range(40)]
+        for sid, err in zip(sids, res):
+            if ns.shard_set.lookup(sid) in (0, 1):
+                assert err is None
+            else:
+                assert "not owned by this node" in err
+        # taken back: served again from the same remembered routes
+        db.assign_shards({0, 1, 2, 3}, START)
+        assert len(ns.read_many(ids, START, START + HOUR)) == len(ids)
+        db.close()
+
+    def test_routes_bounded_by_two_rotations(self, tmp_path):
+        db = make_db(tmp_path)
+        ns = db.namespaces["default"]
+        block = ns.opts.retention.block_size_ns
+        idle = [b"idle-%d" % i for i in range(100)]
+        busy = [b"busy-%d" % i for i in range(100)]
+        want = [ns.shard_set.lookup(s) for s in busy]
+        ns.expire(START)
+        ns.shards_of(idle + busy)
+        assert len(ns._routes) == 200
+        ns.expire(START + block // 2)  # same cutoff: no rotation
+        ns.expire(START + block)       # first rotation
+        assert idle[0] in ns._routes and busy[0] in ns._routes
+        _, m0 = _route_counts()
+        assert ns.shards_of(busy) == want  # touched in between: kept
+        assert _route_counts()[1] == m0
+        ns.expire(START + 2 * block)   # second rotation
+        assert len(ns._routes) == 100
+        assert all(s not in ns._routes for s in idle)
+        assert all(s in ns._routes for s in busy)
+        assert ns.shards_of(busy) == want
+        assert _route_counts()[1] == m0
+        # a churned-out id costs one hash when it comes back
+        assert ns.shards_of(idle[:5]) == [ns.shard_set.lookup(s)
+                                          for s in idle[:5]]
+        assert _route_counts()[1] == m0 + 5
+        # the tick is what drives it
+        db.tick(START + 3 * block)
+        db.tick(START + 4 * block)
+        assert len(ns._routes) == 0
+        db.close()
+
+    def test_routes_under_threads_and_rotation(self):
+        import threading
+
+        from m3_tpu.storage.sharding import ShardRoutes, ShardSet
+
+        ss = ShardSet(8)
+        ids = [b"cpu_usage_user|arch=x64|hostname=host_%d|team=SF" % i
+               for i in range(2000)]
+        want = [ss.lookup(s) for s in ids]
+        routes = ShardRoutes()
+        stop = threading.Event()
+        wrong: list = []
+
+        def rotate():
+            while not stop.is_set():
+                routes.rotate()
+
+        def route(k):
+            for r in range(30):
+                part = ids[(k * 97 + r * 131) % 1000:]
+                if routes.lookup_many(ss, part) != want[-len(part):]:
+                    wrong.append((k, r))
+
+        h0, m0 = _route_counts()
+        rotator = threading.Thread(target=rotate)
+        workers = [threading.Thread(target=route, args=(k,))
+                   for k in range(8)]
+        rotator.start()
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join()
+        stop.set()
+        rotator.join()
+        assert wrong == []
+        h1, m1 = _route_counts()
+        routed = sum(2000 - (k * 97 + r * 131) % 1000
+                     for k in range(8) for r in range(30))
+        assert (h1 - h0) + (m1 - m0) == routed
