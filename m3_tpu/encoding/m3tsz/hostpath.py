@@ -203,15 +203,39 @@ def _forced_batch_path() -> str:
     return os.environ.get("M3_TPU_DECODE_BATCH_PATH", "")
 
 
+# most bytes the padded output of ONE decoder launch may take (rows x
+# points x 17 B: an int64 time, a uint64 value and a valid flag a point).
+# A read's whole miss set is one launch up to here, and beyond it is cut
+# into launches of the largest row bucket that fits: a 20,000-series read
+# of hour-long streams is 7 launches of 107 MB, not one of 700 MB.
+_LAUNCH_OUT_BYTES = 128 << 20
+
+
+def _max_points(maxlen: int) -> int:
+    """Point capacity for streams of at most `maxlen` bytes: a datapoint
+    costs >= 2 bits, so the longest stream bounds the points."""
+    return dispatch.next_pow2(maxlen * 4 + 16)
+
+
+def _launch_rows(maxlen: int) -> int:
+    """Most streams one launch takes when the longest has `maxlen`
+    bytes: the largest half-octave row bucket (so padding adds nothing)
+    whose output stays within _LAUNCH_OUT_BYTES, and at least one."""
+    cap = max(1, _LAUNCH_OUT_BYTES // (_max_points(maxlen) * 17))
+    p = 1 << (cap.bit_length() - 1)
+    return p + p // 2 if p + p // 2 <= cap else p
+
+
 def _decode_streams_device(streams: list[bytes], unit: TimeUnit,
                            int_optimized: bool):
-    """One vmapped XLA decode over the whole group. Streams whose rows come
+    """One vmapped XLA decode over the whole launch. Streams whose rows come
     back flagged (annotation/time-unit markers the kernels don't decode)
     fall back to the scalar decoder individually. Every axis is padded
     to a shape bucket (half-octave rows, power-of-two words and points)
-    so repeated groups share compiled kernels: each (shard, block) group
-    has its own row count, and an unbucketed row axis compiled the scan
-    once per group."""
+    so repeated launches share compiled kernels: each read has its own
+    miss count, and an unbucketed row axis compiled the scan once per
+    count."""
+    import jax
     import numpy as _np
 
     from m3_tpu.encoding.m3tsz import tpu as m3tsz_tpu
@@ -225,8 +249,7 @@ def _decode_streams_device(streams: list[bytes], unit: TimeUnit,
     words = m3tsz_tpu.bytes_to_words(
         streams + [b""] * (n_rows - len(streams)),
         dispatch.next_pow2((maxlen + 7) // 8))
-    # a datapoint costs >= 2 bits, so the longest stream bounds the points
-    max_points = dispatch.next_pow2(maxlen * 4 + 16)
+    max_points = _max_points(maxlen)
     # padding-waste ledger: real stream words vs the pow2 word rectangle
     compute_stats.record_waste(
         "decode_batch", "words",
@@ -242,19 +265,22 @@ def _decode_streams_device(streams: list[bytes], unit: TimeUnit,
     else:
         jitted = m3tsz_tpu._decode_jit
     # one stage and one tracked block around the decoder call AND the
-    # reads that wait for it: dispatch, H2D, queue and device time
+    # one transfer that waits for it: dispatch, H2D, queue, device time
+    # and D2H of the four results together
     with trace.stage(trace.STAGE_DECODE_WAIT) as fr:
         with dispatch.jit_tracker("m3tsz_decode", jitted,
                                   sig=sig) as tracker:
             if int_optimized:
                 dec = tpu_int.decode_int(words, unit, max_points=max_points)
-                vbits = _np.asarray(dec.values, _np.float64).view(_np.uint64)
             else:
                 dec = m3tsz_tpu.decode(words, unit, max_points=max_points)
-                vbits = _np.asarray(dec.value_bits, _np.uint64)
-            times = _np.asarray(dec.times, _np.int64)
-            err = _np.asarray(dec.error)
-            counts = _np.asarray(dec.n_points)
+            vals, times, err, counts = jax.device_get(
+                (dec.values if int_optimized else dec.value_bits,
+                 dec.times, dec.error, dec.n_points))
+            vbits = _np.asarray(
+                vals, _np.float64 if int_optimized else _np.uint64
+            ).view(_np.uint64)
+            times = _np.asarray(times, _np.int64)
         if tracker.miss:
             fr.name = trace.STAGE_DECODE_COMPILE
     dispatch.counters["m3tsz_decode_device_batch"] += 1
@@ -269,21 +295,24 @@ def _decode_streams_device(streams: list[bytes], unit: TimeUnit,
 
 
 def decode_streams_batch(streams: list[bytes | None], unit: TimeUnit,
-                         int_optimized: bool
+                         int_optimized: bool, groups: int = 1
                          ) -> list[tuple[np.ndarray, np.ndarray]]:
-    """Decode MANY streams of one (shard, block, volume) group in a single
-    batched dispatch — the read-path dual of encode_blocks. Returns
-    [(times int64, value_bits uint64)] aligned to the input; empty/None
-    streams decode to empty arrays.
+    """Decode MANY streams in a single batched dispatch — the read-path
+    dual of encode_blocks. The unit of a call is everything one read
+    missed in the block cache, across its ``groups`` (shard, block,
+    volume) groups (Shard.decode_misses); it becomes one launch, or
+    ceil(streams / _launch_rows) where the padded output of one would
+    pass _LAUNCH_OUT_BYTES. Returns [(times int64, value_bits uint64)]
+    aligned to the input; empty/None streams decode to empty arrays.
 
     Ladder (same platform dispatch as the flush encode): the vmapped XLA
     kernels when an accelerator is live/forced (float AND int-optimized —
     the batch surface removes the int-opt scalar cliff), else the native
     v2 batch decoder (float-mode only), else a scalar loop. Streams the
     fast rungs reject (annotation/time-unit markers) degrade per stream,
-    never the whole group.
+    never the whole launch.
     """
-    from m3_tpu.utils import querystats, trace
+    from m3_tpu.utils import querystats
 
     empty = (np.empty(0, np.int64), np.empty(0, np.uint64))
     out: list = [empty] * len(streams)
@@ -291,8 +320,27 @@ def decode_streams_batch(streams: list[bytes | None], unit: TimeUnit,
     if not todo:
         return out
     subset = [streams[i] for i in todo]
-    # one counter bump per GROUP: tests assert read_many issues at most one
-    # batched dispatch per (shard, block, volume) group
+    rows = _launch_rows(max(map(len, subset)))
+    decoded: list = []
+    for lo in range(0, len(subset), rows):
+        decoded += _decode_launch(subset[lo : lo + rows], unit,
+                                  int_optimized)
+    querystats.record(blocks_read=groups)
+    for i, r in zip(todo, decoded):
+        out[i] = r
+    return out
+
+
+def _decode_launch(subset: list[bytes], unit: TimeUnit,
+                   int_optimized: bool
+                   ) -> list[tuple[np.ndarray, np.ndarray]]:
+    """One rung of the ladder over non-empty streams, with its stage,
+    its per-rung metrics and its line of the query record."""
+    from m3_tpu.utils import querystats, trace
+
+    empty = (np.empty(0, np.int64), np.empty(0, np.uint64))
+    # one counter bump per LAUNCH: tests bound the batched dispatches a
+    # read_many issues from above
     dispatch.counters["m3tsz_decode_batch_groups"] += 1
     forced = _forced_batch_path()
     decoded = None
@@ -338,7 +386,7 @@ def decode_streams_batch(streams: list[bytes | None], unit: TimeUnit,
                 decoded.append((t, v))
         n_bytes = sum(len(s) for s in subset)
         fr.tag(path=rung, bytes=n_bytes)
-    # device-op profiling: which rung served this group (visible on
+    # device-op profiling: which rung served this launch (visible on
     # /metrics per rung), how long it took (the stage's whole time), how
     # many bytes it chewed — the per-query record gets the same
     # attribution
@@ -352,11 +400,8 @@ def decode_streams_batch(streams: list[bytes | None], unit: TimeUnit,
     from m3_tpu.utils.instrument import COUNT_BUCKETS
 
     sc.observe("batch_size", float(len(subset)), bounds=COUNT_BUCKETS)
-    querystats.record(blocks_read=1, bytes_decoded=n_bytes,
-                      decode_rung=rung)
-    for i, r in zip(todo, decoded):
-        out[i] = r
-    return out
+    querystats.record(bytes_decoded=n_bytes, decode_rung=rung)
+    return decoded
 
 
 _decode_scopes: dict = {}

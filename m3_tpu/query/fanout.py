@@ -130,9 +130,9 @@ class FanoutNamespace:
     def read_many(self, series_ids: list[bytes], start_ns: int, end_ns: int,
                   warnings: list | None = None):
         """One BATCHED read per zone: the local leg is the namespace's
-        fused fetch+decode batch (one dispatch per (shard, block, volume)
-        group) and each remote leg is one read_many RPC, so a fan-out over
-        N series costs one batched request per node, not N.
+        batched read (one fetch per (shard, block, volume) group, one
+        decode dispatch) and each remote leg is one read_many RPC, so a
+        fan-out over N series costs one batched request per node, not N.
 
         Partial-result contract (non-strict): a zone failing closed yields
         the surviving zones' merge plus one ReadWarning per skipped zone

@@ -203,10 +203,10 @@ def fetch_tagged(db, namespaces: list[str], index_query, t_min: int,
     overlap region is served by the finer tier alone, the reference's
     completeness preference).
 
-    Each tier's read is ONE batched read_many — storage fuses it into one
-    fetch+decode dispatch per (shard, block, volume) group (or one RPC per
-    node on cluster facades), so a 10k-series PromQL fetch costs a handful
-    of decode dispatches, not 10k.
+    Each tier's read is ONE batched read_many — storage makes it one
+    fetch per (shard, block, volume) group and one decode dispatch (or one
+    RPC per node on cluster facades), so a 10k-series PromQL fetch costs
+    a handful of decode dispatches, not 10k.
 
     ``warnings`` (out-param) accumulates the ReadWarnings degraded
     cluster facades recorded for these reads — the engine carries them to

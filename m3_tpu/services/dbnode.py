@@ -173,9 +173,9 @@ class NodeAPI:
                 from m3_tpu.utils import querystats, wire
 
                 doc = json.loads(body)
-                # one batched storage read for the whole request: a single
-                # fused fetch+decode dispatch per (shard, block, volume)
-                # group instead of one decode per series. The storage
+                # one batched storage read for the whole request: a fetch
+                # per (shard, block, volume) group and a single decode
+                # dispatch instead of one decode per series. The storage
                 # counters the read accrues (blocks/bytes/cache/rungs)
                 # ride the response envelope back to the coordinator's
                 # QueryStats record — in cluster mode they live HERE, and

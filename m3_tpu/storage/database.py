@@ -690,8 +690,8 @@ class Database:
 
         ns = self.namespaces[namespace]
         docs = ns.query_ids(matchers_to_query(list(matchers)), start_ns, end_ns, limit)
-        # one batched read for the whole match set: a single fused
-        # fetch+decode dispatch per (shard, block, volume) group
+        # one batched read for the whole match set: a fetch per (shard,
+        # block, volume) group and a single decode dispatch
         results = ns.read_many([d.series_id for d in docs], start_ns, end_ns)
         out = []
         for doc, (times, vbits) in zip(docs, results):
@@ -711,8 +711,8 @@ class Database:
 
     def read_batch(self, namespace: str, series_ids: list[bytes],
                    start_ns: int, end_ns: int) -> list[list[Datapoint]]:
-        """Batched node-API reads (the read_batch RPC shape): one fused
-        fetch+decode per (shard, block, volume) group server-side, so a
+        """Batched node-API reads (the read_batch RPC shape): one fetch
+        per (shard, block, volume) group and one decode server-side, so a
         Session wired to in-process databases batches like the HTTP path."""
         ns = self.namespaces[namespace]
         results = ns.read_many(series_ids, start_ns, end_ns)

@@ -11,7 +11,7 @@ import itertools
 from m3_tpu.index.index import NamespaceIndex
 from m3_tpu.index.query import Query
 from m3_tpu.storage.options import DatabaseOptions, NamespaceOptions
-from m3_tpu.storage.shard import Shard
+from m3_tpu.storage.shard import Shard, run_read_groups
 from m3_tpu.storage.sharding import ShardRoutes, ShardSet
 
 
@@ -235,14 +235,16 @@ class Namespace:
         """Batch-read surface shared with the cluster facade (which turns
         it into one request per storage node).
 
-        First-class batched operation: series group by owning shard and
-        each shard fuses fetch+decode into one dispatch per (block,
-        volume) group (Shard.read_many) — cache hits never enter the
+        First-class batched operation: series group by owning shard,
+        each shard fetches once per (block, volume) group, and what the
+        groups missed in the block cache decodes in ONE dispatch for the
+        whole call (shard.decode_misses) — cache hits never enter the
         batch. Limits accounting stays EXACT: one add_datapoints per
         series, same as the per-series path; with a datapoint limit
-        configured, shard batches are chunked so the limit still bounds
-        decode WORK (an over-limit query aborts after at most one chunk
-        of extra decode, not after materializing the whole match set)."""
+        configured, shard batches are chunked (one decode a chunk) so
+        the limit still bounds decode WORK (an over-limit query aborts
+        after at most one chunk of extra decode, not after materializing
+        the whole match set)."""
         from m3_tpu.utils import trace
         from m3_tpu.utils.instrument import default_registry
 
@@ -295,7 +297,7 @@ class Namespace:
         if pipeline.active() and chunk >= len(series_ids):
             # pipelined dataflow (no datapoint-limit chunking): ONE
             # flattened schedule of per-(shard, block) gather legs
-            # across every shard, overlapping the caller's decode rung
+            # across every shard, then one decode of what they missed
             return self._read_many_pipelined(series_ids, by_shard,
                                              start_ns, end_ns, out,
                                              want_ragged=want_ragged)
@@ -313,52 +315,38 @@ class Namespace:
 
     def _read_many_pipelined(self, series_ids, by_shard, start_ns, end_ns,
                              out, want_ragged: bool = False):
-        """Per-(shard, block) groups through the executor seam: group
-        N+1's fileset gather runs on the pool while group N decodes on
-        this thread, and a shard's series FINALIZE (buffer merge +
-        limits accounting, the partial columns downstream host prep
-        consumes) as soon as its last group decodes — while later
-        shards' gathers are still in flight. Results are identical to
-        the serial path: groups run in the same nested order, decode
-        stays one dispatch per group, and per-series parts keep the
-        filesets-then-buffer order merge_dedup resolves last-write-wins.
+        """Per-(shard, block) groups through the executor seam
+        (shard.run_read_groups): the fileset gathers of every shard run
+        on the pool while this thread lands the cache hits of those that
+        have arrived; when the last has, ONE batched decode takes what
+        all groups missed, and then the shards' series FINALIZE (buffer
+        merge + limits accounting, the partial columns downstream host
+        prep consumes). Results are identical to the serial path: the
+        groups' hits and decoded misses land in the same nested order,
+        and per-series parts keep the filesets-then-buffer order
+        merge_dedup resolves last-write-wins.
         """
         from m3_tpu.ops import ragged
-        from m3_tpu.storage import pagepool, pipeline
-        from m3_tpu.utils import querystats
+        from m3_tpu.storage import pagepool
 
         # paged: batched ragged finalize per shard; fragments of the
-        # namespace-level ragged combine (one merged per-shard CSR each,
-        # landed by the finalize callback mid-flight) are only tracked
-        # when the caller asked for the CSR back
+        # namespace-level ragged combine (one merged per-shard CSR each)
+        # are only tracked when the caller asked for the CSR back
         paged = pagepool.active()
         frags: list | None = [] if (paged and want_ragged) else None
         groups = []
-        last_group_of: dict[int, object] = {}
+        plans = []
         for shard_id, idxs in by_shard.items():
             shard = self.shards[shard_id]
             sids = [series_ids[i] for i in idxs]
             parts: list[list] = [[] for _ in idxs]
-            plan = (shard, idxs, sids, parts)
-            shard_groups = shard.plan_read_groups(sids, start_ns, end_ns,
-                                                  parts)
-            groups.extend(shard_groups)
-            if shard_groups:
-                last_group_of[id(shard_groups[-1])] = plan
-            else:
-                self._finalize_shard_read(plan, start_ns, end_ns, out,
-                                          paged, frags)
-
-        def consume(g, payload):
-            g.consume(payload)
-            plan = last_group_of.get(id(g))
-            if plan is not None:  # this shard's partial columns are
-                # complete: hand them downstream now, mid-pipeline
-                self._finalize_shard_read(plan, start_ns, end_ns, out,
-                                          paged, frags)
-
-        stats = pipeline.run_stages(groups, lambda g: g.gather(), consume)
-        querystats.record_pipeline(stats.items, stats.wall_s, stats.stages)
+            plans.append((shard, idxs, sids, parts))
+            groups.extend(shard.plan_read_groups(sids, start_ns, end_ns,
+                                                 parts))
+        run_read_groups(groups)
+        for plan in plans:
+            self._finalize_shard_read(plan, start_ns, end_ns, out, paged,
+                                      frags)
         if want_ragged and frags is not None:
             # pure O(N) scatter: each fragment is already merged and
             # filtered, and every row lives in exactly one fragment —

@@ -2,15 +2,17 @@
 
 The T3 pattern (PAPERS.md "Transparent Tracking & Triggering for
 Fine-grained Overlap of Compute & Collectives") applied to the storage
-engine's host dataflow: instead of `fetch whole group -> decode whole
+engine's host dataflow: instead of `fetch whole group -> consume whole
 group -> next group`, a small fixed worker pool runs the NEXT group's
-fetch/RPC leg while the CALLER decodes the current one, with a bounded
+fetch/RPC leg while the CALLER consumes the current one, with a bounded
 prefetch depth so memory stays flat. The same executor serves both hot
 paths:
 
   read side   `Shard`/`Namespace.read_many` push per-(shard, block)
               gather legs through ``run_stages`` so group N+1's fileset
-              gather overlaps group N's decode rung, and
+              gather overlaps the caller landing group N's cache hits;
+              what the groups missed decodes in ONE batch when the last
+              gather has landed (`shard.run_read_groups`), and
               `Session.fetch_many` / the coordinator fanout put every
               node/zone RPC in flight at once instead of draining them
               serially.
@@ -256,7 +258,7 @@ class PipelineExecutor:
     def map_ordered(self, fns: list, depth: int):
         """Yield fn() results in input order with up to ``depth`` calls
         in flight ahead of the consumer — the bounded-depth prefetch the
-        read path overlaps gather and decode through. Falls back to a
+        read path overlaps its gathers through. Falls back to a
         plain inline loop from worker context (no nested waits)."""
         if in_worker() or len(fns) <= 1:
             for fn in fns:

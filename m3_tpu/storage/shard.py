@@ -24,11 +24,11 @@ class _FilesetReadGroup:
     ``gather()`` is the worker-safe leg: cache probe + columnar stream
     gather off the immutable reader — nothing thread-local, nothing
     mutated outside the lock-guarded BlockCache. ``consume()`` runs on
-    the calling thread in submission order: querystats accounting, the
-    ONE batched decode dispatch per group (the dispatch-economy
-    contract), the cache fill, and the per-series parts append — every
-    thread-local seam (query record, decode-rung counters, trace spans)
-    stays on the query's own thread."""
+    the calling thread in submission order: querystats accounting and
+    the per-series parts append of the cache hits; what the cache
+    missed it hands back for the read's ONE batched decode
+    (``decode_misses``) — every thread-local seam (query record,
+    decode-rung counters, trace spans) stays on the query's own thread."""
 
     __slots__ = ("shard", "block_start", "reader", "series_ids", "parts")
 
@@ -64,12 +64,12 @@ class _FilesetReadGroup:
             [self.series_ids[i] for i in miss_idx]) if miss_idx else [])
         return keys, cached, miss_idx, streams
 
-    def consume(self, payload) -> None:
-        from m3_tpu.encoding.m3tsz import hostpath
+    def consume(self, payload) -> tuple:
+        """Account the probe, land the hits, and return the group's
+        ``decode_misses`` entry."""
         from m3_tpu.utils import querystats
 
         keys, cached, miss_idx, streams = payload
-        shard = self.shard
         parts = self.parts
         querystats.record(
             cache_hits=len(self.series_ids) - len(miss_idx),
@@ -78,16 +78,64 @@ class _FilesetReadGroup:
             for i, hit in enumerate(cached):
                 if hit is not None and len(hit[0]):
                     parts[i].append(hit)
-        if not miss_idx:
-            return
-        decoded = hostpath.decode_streams_batch(
-            streams, shard.opts.write_time_unit, shard.opts.int_optimized)
-        if keys is not None:  # negative results cached too
-            shard.cache.put_many(
-                [(keys[i], r) for i, r in zip(miss_idx, decoded)])
-        for i, (ct, cv) in zip(miss_idx, decoded):
-            if len(ct):
-                parts[i].append((ct, cv))
+        return keys, miss_idx, streams, parts
+
+
+def decode_misses(pending: list[tuple], opts: NamespaceOptions,
+                  cache) -> None:
+    """The decode leg of a batched read: ONE ``decode_streams_batch``
+    call over the streams every group of ``pending`` missed in the block
+    cache (the time unit and the int mode are the namespace's, so groups
+    of different shards and blocks share a call), scattered back in
+    group order: one cache fill (negative results too), then the
+    per-series parts append. An entry is ``(keys, miss_idx, streams,
+    parts)``: the group's cache keys (None: nothing is cached), the rows
+    it missed, their streams, and the parts lists its rows append to."""
+    from m3_tpu.encoding.m3tsz import hostpath
+
+    pending = [p for p in pending if p[1]]
+    if not pending:
+        return
+    decoded = hostpath.decode_streams_batch(
+        [s for p in pending for s in p[2]], opts.write_time_unit,
+        opts.int_optimized, groups=sum(1 for p in pending if any(p[2])))
+    fills = []
+    lo = 0
+    for keys, miss_idx, _, parts in pending:
+        for i, r in zip(miss_idx, decoded[lo : lo + len(miss_idx)]):
+            if keys is not None:
+                fills.append((keys[i], r))
+            if len(r[0]):
+                parts[i].append(r)
+        lo += len(miss_idx)
+    if fills:
+        cache.put_many(fills)
+
+
+def run_read_groups(groups: "list[_FilesetReadGroup]") -> None:
+    """Drive a read's groups through the executor seam: gathers on the
+    pool in submission order, each group's hits landed on this thread as
+    its gather arrives, and when the last has landed one decode over
+    what all of them missed. Every group's parts are complete on
+    return."""
+    from m3_tpu.storage import pipeline
+    from m3_tpu.utils import querystats
+
+    if not groups:
+        return
+    pending: list[tuple] = []
+
+    def consume(g, payload):
+        pending.append(g.consume(payload))
+        if g is groups[-1]:
+            # one namespace, so one time unit, int mode and block cache
+            decode_misses(pending, g.shard.opts, g.shard.cache)
+
+    stats = pipeline.run_stages(groups, lambda g: g.gather(), consume)
+    # overlap accounting reaches ?explain=analyze from every entry (the
+    # namespace's flattened schedule, its limit-chunked loop and direct
+    # shard callers)
+    querystats.record_pipeline(stats.items, stats.wall_s, stats.stages)
 
 
 class Shard:
@@ -252,34 +300,28 @@ class Shard:
 
     def read_many(self, series_ids: list[bytes], start_ns: int, end_ns: int
                   ) -> list[tuple[np.ndarray, np.ndarray]]:
-        """Batched read: ONE fused fetch+decode dispatch per (block,
-        volume) group instead of one per series. Cache hits are served
-        without entering the batch; the whole group's misses fill the
-        decoded-block LRU in one pass. Identical results to per-series
-        read() — parts accumulate in the same (filesets-then-buffer) order
-        so last-write-wins resolution is unchanged.
+        """Batched read: one fetch per (block, volume) group and ONE
+        decode dispatch for the call instead of one per series. Cache
+        hits are served without entering the batch; the misses of every
+        group fill the decoded-block LRU in one pass. Identical results
+        to per-series read() — parts accumulate in the same
+        (filesets-then-buffer) order so last-write-wins resolution is
+        unchanged.
 
         Default path is the PIPELINED dataflow (storage/pipeline.py):
         per-(block, volume) gather legs run on the executor pool up to
-        depth-N ahead of the caller's decode rung, and the gather itself
-        is the reader's cached columnar row index instead of a per-query
-        merge-join walk. ``M3_TPU_PIPELINE=0`` pins this serial body —
-        the seed behavior, kept verbatim for bisection."""
+        depth-N ahead of the caller landing their cache hits, the
+        decode follows the last gather (run_read_groups), and the gather
+        itself is the reader's cached columnar row index instead of a
+        per-query merge-join walk. ``M3_TPU_PIPELINE=0`` pins the serial
+        body — the seed behavior for bisection: one fetch and one decode
+        per group."""
         from m3_tpu.storage import pipeline
 
         if pipeline.active():
-            from m3_tpu.utils import querystats
-
             parts: list[list] = [[] for _ in series_ids]
-            groups = self.plan_read_groups(series_ids, start_ns, end_ns,
-                                           parts)
-            stats = pipeline.run_stages(
-                groups, lambda g: g.gather(), lambda g, p: g.consume(p))
-            # overlap accounting reaches ?explain=analyze from THIS
-            # entry too (the namespace's limit-chunked loop and direct
-            # shard callers), not just the flattened namespace schedule
-            querystats.record_pipeline(stats.items, stats.wall_s,
-                                       stats.stages)
+            run_read_groups(
+                self.plan_read_groups(series_ids, start_ns, end_ns, parts))
             from m3_tpu.storage import pagepool
 
             if pagepool.active():
@@ -356,8 +398,6 @@ class Shard:
 
     def _read_many_serial(self, series_ids: list[bytes], start_ns: int,
                           end_ns: int) -> list[tuple[np.ndarray, np.ndarray]]:
-        from m3_tpu.encoding.m3tsz import hostpath
-
         n = len(series_ids)
         parts: list[list] = [[] for _ in range(n)]
         # snapshot: the tick thread swaps fileset volumes concurrently
@@ -385,14 +425,9 @@ class Shard:
             # batched fetch: one merge-join walk of the volume's index for
             # the whole miss set, then one batched decode of its streams
             streams = reader.read_many([series_ids[i] for i in miss_idx])
-            decoded = hostpath.decode_streams_batch(
-                streams, self.opts.write_time_unit, self.opts.int_optimized)
-            if self.cache is not None:  # negative results cached too
-                self.cache.put_many(
-                    [(keys[i], r) for i, r in zip(miss_idx, decoded)])
-            for i, (ct, cv) in zip(miss_idx, decoded):
-                if len(ct):
-                    parts[i].append((ct, cv))
+            decode_misses(
+                [(keys if self.cache is not None else None, miss_idx,
+                  streams, parts)], self.opts, self.cache)
         out = []
         for i, sid in enumerate(series_ids):
             bt, bv = self.buffer.read(sid, start_ns, end_ns)

@@ -1,10 +1,12 @@
-"""Batched multi-series read path: fetch+decode fused into ONE columnar
-dispatch per (shard, block, volume) group.
+"""Batched multi-series read path: one columnar fetch per (shard, block,
+volume) group and ONE decode dispatch per read (per chunk and group on
+the serial hatch).
 
-Pins the three claims of the batched surface:
+Pins the claims of the batched surface:
   - dispatch economy: read_many over >=10k cold-cache series issues at
     most one batched decode per (shard, block, volume) group (counted via
-    utils/dispatch counters), never one per series;
+    utils/dispatch counters), never one per series; on the pipelined
+    path exactly one a read, cut only by the bound on a launch's output;
   - parity: batched results are identical (times AND value bits) to the
     per-series read() path on every ladder rung (native batch, vmapped
     XLA kernel, scalar loop), including int-optimized and NaN-staleness
@@ -28,7 +30,7 @@ from m3_tpu.storage.options import (
     NamespaceOptions,
     RetentionOptions,
 )
-from m3_tpu.utils import dispatch
+from m3_tpu.utils import dispatch, querystats
 from m3_tpu.utils.xtime import TimeUnit
 
 NS = 10**9
@@ -41,9 +43,11 @@ PER_STREAM_COUNTERS = ("m3tsz_decode_native", "m3tsz_decode_scalar")
 
 
 def build_db(tmp_path, n_series, n_blocks=2, n_shards=4, points=6,
-             int_optimized=False, cache_entries=0):
+             int_optimized=False, cache_entries=0, overrides=None):
     """A database whose fileset volumes are written directly (one batched
-    encode per (shard, block)) — fast enough to set up 10k+ series."""
+    encode per (shard, block)) — fast enough to set up 10k+ series.
+    ``overrides`` maps (series id, block number) to the stream to store
+    in place of the encoded one."""
     db = Database(
         str(tmp_path / "db"),
         DatabaseOptions(n_shards=n_shards, block_cache_entries=cache_entries),
@@ -77,7 +81,8 @@ def build_db(tmp_path, n_series, n_blocks=2, n_shards=4, points=6,
             writer = FilesetWriter(db.fs_root, "default", shard_id, bs,
                                    BLOCK, 0)
             for sid, stream in zip(sids, streams):
-                writer.write_series(sid, b"", stream)
+                writer.write_series(
+                    sid, b"", (overrides or {}).get((sid, b), stream))
             writer.close()
     db.open(START + n_blocks * BLOCK)
     return db, ns, ids
@@ -244,6 +249,246 @@ class TestForcedPathParity:
         ref = hostpath.decode_stream(marker, TimeUnit.SECOND, False)
         np.testing.assert_array_equal(got[1][0], ref[0])
         np.testing.assert_array_equal(got[1][1], ref[1])
+
+
+def _read_with_stats(ns, ids, n_blocks):
+    """(answers, query record) of one read_many over every block."""
+    st = querystats.start(query="batched-read-test")
+    try:
+        return ns.read_many(ids, START, START + n_blocks * BLOCK), st
+    finally:
+        querystats.finish(st)
+
+
+def _assert_same_answers(got, want):
+    assert len(got) == len(want)
+    for (gt, gv), (wt, wv) in zip(got, want):
+        np.testing.assert_array_equal(gt, wt)
+        np.testing.assert_array_equal(gv, wv)
+
+
+def _marker_stream():
+    """A stream with a time-unit-change marker: the batch rungs reject
+    it, the scalar decoder reads it."""
+    enc = Encoder(START, int_optimized=False,
+                  default_time_unit=TimeUnit.SECOND)
+    enc.encode(START + NS, 1.0, TimeUnit.SECOND)
+    enc.encode(START + NS + 10**6, 2.0, TimeUnit.MILLISECOND)
+    return enc.stream()
+
+
+# (shards, blocks): one block over many shards is the dashboard's read,
+# several blocks a long range's
+SHAPES = [(8, 1), (4, 3)]
+
+
+class TestOneLaunchPerRead:
+    """The unit of a decoder launch is everything a read_many missed in
+    the block cache, across its (shard, block) groups: one launch a
+    read, cut only by the bound on one launch's padded output, with the
+    answers, cache fill and query record of the serial hatch."""
+
+    N = 96
+
+    @pytest.mark.parametrize("n_shards,n_blocks", SHAPES)
+    def test_one_device_launch_with_misses_in_every_group(
+            self, tmp_path, monkeypatch, n_shards, n_blocks):
+        monkeypatch.setenv("M3_TPU_DECODE_BATCH_PATH", "device")
+        db, ns, ids = build_db(tmp_path, self.N, n_blocks=n_blocks,
+                               n_shards=n_shards, cache_entries=10_000)
+        try:
+            assert len(ns.shards) == n_shards
+            for read_ids in (ids[::3], ids):  # cold, then partly warm
+                before = dict(dispatch.counters)
+                res, st = _read_with_stats(ns, read_ids, n_blocks)
+                assert _deltas(before, ("m3tsz_decode_device_batch",
+                                        "m3tsz_decode_batch_groups")) == {
+                    "m3tsz_decode_device_batch": 1,
+                    "m3tsz_decode_batch_groups": 1}
+                # misses in every group, and every group still counted
+                assert st.blocks_read == n_shards * n_blocks
+                assert st.decode_rungs == {"device": 1}
+                assert all(len(t) == 6 * n_blocks for t, _ in res)
+        finally:
+            db.close()
+
+    @pytest.mark.parametrize("path", ["device", "native"])
+    def test_launches_above_the_bound(self, tmp_path, monkeypatch, path):
+        """ceil(miss rows / rows of the bound) launches, each a full row
+        bucket but the last; answers unchanged."""
+        monkeypatch.setenv("M3_TPU_DECODE_BATCH_PATH", path)
+        db, ns, ids = build_db(tmp_path, self.N, n_blocks=3, n_shards=4,
+                               cache_entries=0)
+        try:
+            want = ns.read_many(ids, START, START + 3 * BLOCK)
+            maxlen = max(len(s) for sh in ns.shards.values()
+                         for r in sh._filesets.values()
+                         for s in r.read_many(ids) if s)
+            # room for 100 rows: the largest half-octave bucket is 96
+            monkeypatch.setattr(
+                hostpath, "_LAUNCH_OUT_BYTES",
+                100 * 17 * hostpath._max_points(maxlen))
+            assert hostpath._launch_rows(maxlen) == 96
+            before = dict(dispatch.counters)
+            got, st = _read_with_stats(ns, ids, 3)
+            launches = -(-3 * self.N // 96)
+            assert launches == 3
+            assert _deltas(before, ("m3tsz_decode_batch_groups",)) == {
+                "m3tsz_decode_batch_groups": launches}
+            if path == "device":
+                assert _deltas(before, ("m3tsz_decode_device_batch",)) \
+                    == {"m3tsz_decode_device_batch": launches}
+            assert st.decode_rungs == {path: launches}
+            assert st.blocks_read == 4 * 3
+            _assert_same_answers(got, want)
+        finally:
+            db.close()
+
+    @pytest.mark.parametrize("maxlen,rows", [
+        (1, 196608), (430, 3072), (600, 1536), (1 << 22, 1)])
+    def test_launch_rows_fit_the_bound(self, maxlen, rows):
+        """The cut is a half-octave bucket (padding adds no rows) within
+        the bound; a 2,500-row group of hour-long streams still fits."""
+        assert hostpath._launch_rows(maxlen) == rows
+        assert dispatch.next_bucket(rows) == rows
+        out = rows * hostpath._max_points(maxlen) * 17
+        assert out <= hostpath._LAUNCH_OUT_BYTES or rows == 1
+
+    @pytest.mark.parametrize("path", ["device", "native", "scalar"])
+    @pytest.mark.parametrize("n_shards,n_blocks", SHAPES)
+    def test_parity_with_serial_hatch(self, tmp_path, monkeypatch, path,
+                                      n_shards, n_blocks):
+        """Answers, cache contents and the query record's blocks_read /
+        cache_hits / cache_misses equal M3_TPU_PIPELINE=0's, cold and
+        partly warm, on every rung."""
+        monkeypatch.setenv("M3_TPU_DECODE_BATCH_PATH", path)
+        db, ns, ids = build_db(tmp_path, self.N, n_blocks=n_blocks,
+                               n_shards=n_shards, cache_entries=10_000)
+        cache = db.block_cache
+
+        def run(pipeline_env):
+            monkeypatch.setenv("M3_TPU_PIPELINE", pipeline_env)
+            cache._entries.clear()
+            out = []
+            for read_ids in (ids[::3], ids):
+                res, st = _read_with_stats(ns, read_ids, n_blocks)
+                out.append((res, st.blocks_read, st.cache_hits,
+                            st.cache_misses, dict(cache._entries)))
+            return out
+
+        try:
+            for serial, piped in zip(run("0"), run("1")):
+                _assert_same_answers(piped[0], serial[0])
+                assert piped[1:4] == serial[1:4]
+                assert piped[4].keys() == serial[4].keys()
+                for key, (t, v) in serial[4].items():
+                    np.testing.assert_array_equal(piped[4][key][0], t)
+                    np.testing.assert_array_equal(piped[4][key][1], v)
+            assert len(cache) == self.N * n_blocks
+        finally:
+            db.close()
+
+    @pytest.mark.parametrize("path", ["device", "native"])
+    def test_marker_stream_in_one_group_degrades_alone(
+            self, tmp_path, monkeypatch, path):
+        """One marker-bearing stream among the misses of 8 groups: it
+        alone reaches the scalar decoder, the launch stays one."""
+        monkeypatch.setenv("M3_TPU_DECODE_BATCH_PATH", path)
+        victim = b"series-%06d" % 5
+        db, ns, ids = build_db(
+            tmp_path, self.N, n_blocks=1, n_shards=8, cache_entries=0,
+            overrides={(victim, 0): _marker_stream()})
+        try:
+            before = dict(dispatch.counters)
+            got = ns.read_many(ids, START, START + BLOCK)
+            d = _deltas(before, ("m3tsz_decode_batch_groups",
+                                 "m3tsz_decode_device_batch",
+                                 "m3tsz_decode_scalar"))
+            assert d["m3tsz_decode_batch_groups"] == 1
+            assert d["m3tsz_decode_device_batch"] == (path == "device")
+            assert d["m3tsz_decode_scalar"] == 1
+            np.testing.assert_array_equal(
+                got[5][0], [START + NS, START + NS + 10**6])
+            np.testing.assert_array_equal(
+                got[5][1].view(np.float64), [1.0, 2.0])
+            _assert_same_answers(
+                got, [ns.read(sid, START, START + BLOCK) for sid in ids])
+        finally:
+            db.close()
+
+    def test_duplicate_ids(self, tmp_path, monkeypatch):
+        monkeypatch.setenv("M3_TPU_DECODE_BATCH_PATH", "device")
+        db, ns, ids = build_db(tmp_path, 40, n_blocks=2, n_shards=8,
+                               cache_entries=10_000)
+        try:
+            asked = ids + ids[:7] + [ids[3]]
+            before = dict(dispatch.counters)
+            got = ns.read_many(asked, START, START + 2 * BLOCK)
+            assert _deltas(before, ("m3tsz_decode_device_batch",)) == {
+                "m3tsz_decode_device_batch": 1}
+            _assert_same_answers(
+                got, [ns.read(sid, START, START + 2 * BLOCK)
+                      for sid in asked])
+        finally:
+            db.close()
+
+    def test_group_whose_every_series_hits_the_cache(self, tmp_path):
+        """A fully cached group adds its hits and no rows to the launch,
+        and is not among the blocks read."""
+        db, ns, ids = build_db(tmp_path, self.N, n_blocks=1, n_shards=8,
+                               cache_entries=10_000)
+        try:
+            warm_shard = ns.shard_set.lookup(ids[0])
+            warm = [sid for sid in ids
+                    if ns.shard_set.lookup(sid) == warm_shard]
+            ns.read_many(warm, START, START + BLOCK)
+            before = dict(dispatch.counters)
+            got, st = _read_with_stats(ns, ids, 1)
+            assert _deltas(before, ("m3tsz_decode_batch_groups",)) == {
+                "m3tsz_decode_batch_groups": 1}
+            assert st.blocks_read == 7
+            assert st.cache_hits == len(warm)
+            assert st.cache_misses == self.N - len(warm)
+            _assert_same_answers(
+                got, [ns.read(sid, START, START + BLOCK) for sid in ids])
+        finally:
+            db.close()
+
+    @pytest.mark.parametrize("path", ["device", "scalar"])
+    def test_int_optimized_namespace(self, tmp_path, monkeypatch, path):
+        monkeypatch.setenv("M3_TPU_DECODE_BATCH_PATH", path)
+        db, ns, ids = build_db(tmp_path, 48, n_blocks=2, n_shards=8,
+                               int_optimized=True, cache_entries=0)
+        try:
+            before = dict(dispatch.counters)
+            got, st = _read_with_stats(ns, ids, 2)
+            assert _deltas(before, ("m3tsz_decode_batch_groups",)) == {
+                "m3tsz_decode_batch_groups": 1}
+            assert st.decode_rungs == {path: 1}
+            monkeypatch.delenv("M3_TPU_DECODE_BATCH_PATH")
+            _assert_same_answers(
+                got, [ns.read(sid, START, START + 2 * BLOCK)
+                      for sid in ids])
+        finally:
+            db.close()
+
+    def test_shard_read_many_is_one_launch_across_blocks(self, tmp_path):
+        """Direct shard callers (and the namespace's limit-chunked loop)
+        decode once a call, not once a block."""
+        db, ns, ids = build_db(tmp_path, 64, n_blocks=3, n_shards=2,
+                               cache_entries=0)
+        try:
+            shard = ns.shards[0]
+            sids = [s for s in ids if ns.shard_set.lookup(s) == 0]
+            before = dict(dispatch.counters)
+            got = shard.read_many(sids, START, START + 3 * BLOCK)
+            assert _deltas(before, ("m3tsz_decode_batch_groups",)) == {
+                "m3tsz_decode_batch_groups": 1}
+            _assert_same_answers(
+                got, [shard.read(s, START, START + 3 * BLOCK)
+                      for s in sids])
+        finally:
+            db.close()
 
 
 class TestBatchedVsBufferMerge:

@@ -256,6 +256,9 @@ class TestDeviceCaches:
         docs = [Document(i, b"s-%04d" % i,
                          [(b"host", b"h%d" % (i % 3))]) for i in range(64)]
         seg = packed.build(docs)
+        # segments that earlier tests of this worker left as garbage
+        # release their share now, not at the collect below
+        gc.collect()
         before = dict(packed._dev_cols)
         col = seg.device_postings()
         nbytes = int(col.nbytes)

@@ -225,8 +225,9 @@ class TestPipelinedReads:
             db.close()
 
     def test_dispatch_economy_preserved(self, tmp_path):
-        """One batched decode per (shard, block) group, cache hits never
-        re-enter the batch — the PR-1 contracts, pipeline armed."""
+        """No more batched decodes than (shard, block) groups (one a
+        read since PR 31), cache hits never re-enter the batch — the
+        PR-1 contracts, pipeline armed."""
         from m3_tpu.utils import dispatch
 
         db, ns, ids = build_multiblock_db(tmp_path, n_series=300,
@@ -243,6 +244,94 @@ class TestPipelinedReads:
             for (t1, v1), (t2, v2) in zip(first, second):
                 np.testing.assert_array_equal(t1, t2)
                 np.testing.assert_array_equal(v1, v2)
+        finally:
+            db.close()
+
+    @pytest.mark.parametrize("n_shards,n_blocks", [(8, 1), (4, 3)])
+    def test_one_decode_per_read(self, tmp_path, n_shards, n_blocks):
+        """The read's whole miss set is one batched decode, whatever its
+        (shard, block) groups; the query record still counts the groups
+        and the decode still sits in the pipeline's decode leg."""
+        from m3_tpu.utils import dispatch
+
+        db, ns, ids = build_multiblock_db(
+            tmp_path, n_series=128, n_blocks=n_blocks, n_shards=n_shards,
+            cache_entries=10_000)
+        try:
+            before = dispatch.counters["m3tsz_decode_batch_groups"]
+            st = querystats.start(query="pipeline-test")
+            res = ns.read_many(ids, START, START + n_blocks * BLOCK)
+            querystats.finish(st)
+            assert dispatch.counters["m3tsz_decode_batch_groups"] \
+                - before == 1
+            assert st.blocks_read == n_shards * n_blocks
+            assert st.pipeline_groups == n_shards * n_blocks
+            assert sum(st.decode_rungs.values()) == 1
+            assert st.cache_misses == 128 * n_blocks
+            assert st.pipeline_stage_s["decode"] > 0
+            assert all(len(t) == 6 * n_blocks for t, _ in res)
+        finally:
+            db.close()
+
+    def test_decode_follows_the_last_gather(self, tmp_path, monkeypatch):
+        """No stream decodes before every group's gather has landed, and
+        every shard finalizes after the decode."""
+        from m3_tpu.encoding.m3tsz import hostpath
+        from m3_tpu.storage.namespace import Namespace
+        from m3_tpu.storage.shard import _FilesetReadGroup
+
+        db, ns, ids = build_multiblock_db(tmp_path, n_series=64,
+                                          n_blocks=3)
+        events = []
+        gather = _FilesetReadGroup.gather
+        decode = hostpath.decode_streams_batch
+        finalize = Namespace._finalize_shard_read
+
+        def spy(name, fn):
+            def wrapped(*a, **kw):
+                out = fn(*a, **kw)
+                events.append(name)
+                return out
+            return wrapped
+
+        monkeypatch.setattr(_FilesetReadGroup, "gather",
+                            spy("gather", gather))
+        monkeypatch.setattr(hostpath, "decode_streams_batch",
+                            spy("decode", decode))
+        monkeypatch.setattr(Namespace, "_finalize_shard_read",
+                            spy("finalize", finalize))
+        try:
+            ns.read_many(ids, START, START + 3 * BLOCK)
+            assert events == ["gather"] * 12 + ["decode"] + ["finalize"] * 4
+        finally:
+            db.close()
+
+    @pytest.mark.parametrize("path", ["device", "native", "scalar"])
+    def test_record_and_ragged_parity_with_serial_hatch(
+            self, tmp_path, monkeypatch, path):
+        """read_many_ragged and the query record (blocks, bytes, cache
+        counts, the rung) equal the serial hatch's on every rung."""
+        monkeypatch.setenv("M3_TPU_DECODE_BATCH_PATH", path)
+        db, ns, ids = build_multiblock_db(tmp_path, n_series=96,
+                                          n_blocks=3, n_shards=8,
+                                          cache_entries=10_000)
+
+        def run(pipeline_env):
+            monkeypatch.setenv("M3_TPU_PIPELINE", pipeline_env)
+            db.block_cache._entries.clear()
+            st = querystats.start(query="pipeline-test")
+            t, v, offs = ns.read_many_ragged(ids, START, START + 3 * BLOCK)
+            querystats.finish(st)
+            return (t, v, offs), (st.blocks_read, st.bytes_decoded,
+                                  st.cache_hits, st.cache_misses,
+                                  set(st.decode_rungs))
+
+        try:
+            (serial, s_rec), (piped, p_rec) = run("0"), run("1")
+            for a, b in zip(serial, piped):
+                np.testing.assert_array_equal(a, b)
+            assert p_rec == s_rec
+            assert p_rec[0] == 8 * 3 and p_rec[4] == {path}
         finally:
             db.close()
 
