@@ -131,9 +131,9 @@ def encode_blocks_ragged(times, vbits, offsets, starts,
     a window where one series wrote 10k points and a million wrote one no
     longer materializes a million 10k-wide padded lanes.  Streams are
     byte-identical to encode_blocks over the fully-padded window (the
-    encoder reads exactly n_points lanes per row; the pad rule matches
-    seal's monotone-tail rule), pinned by the seeded parity sweep in
-    tests/test_paged_memory.py.  Zero-length rows return b"".
+    encoder reads exactly n_points lanes per row), pinned by the seeded
+    parity sweep in tests/test_paged_memory.py.  Zero-length rows return
+    b"".
 
     ``waste_site`` names the padding-waste ledger row: the ingest seal
     keeps the default, while the binary wire codec (utils/wire) passes

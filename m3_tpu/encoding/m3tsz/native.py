@@ -73,12 +73,6 @@ def load():
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int32,
         ]
-        lib.m3tsz_bench_roundtrip.restype = ctypes.c_int64
-        lib.m3tsz_bench_roundtrip.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32,
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
-        ]
         lib.m3tsz_encode_batch.restype = ctypes.c_int64
         lib.m3tsz_encode_batch.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32,
@@ -148,38 +142,6 @@ def decode_series(stream: bytes, unit: TimeUnit = TimeUnit.SECOND,
     if n < 0:
         raise ValueError("native decode failed (corrupt or host-path stream)")
     return times[:n].copy(), vbits[:n].view(np.float64).copy()
-
-
-def bench_roundtrip(times: np.ndarray, values: np.ndarray, start: int,
-                    unit: TimeUnit = TimeUnit.SECOND) -> float:
-    """Datapoints/sec for a [B, T] encode+decode round trip executed
-    entirely in native code (one FFI call: the honest CPU baseline).
-
-    Measures the FROZEN v1 scalar codec — the stand-in for the reference's
-    single-core Go hot loop. The serving path uses the v2 batch codec
-    (encode_batch/decode_batch/bench_roundtrip_batch below)."""
-    import time as _time
-
-    lib = load()
-    if lib is None:
-        raise RuntimeError("native codec unavailable")
-    B, T = times.shape
-    times = np.ascontiguousarray(times, dtype=np.int64)
-    vbits = np.ascontiguousarray(values, dtype=np.float64).view(np.uint64)
-    cap = 8 + (T * 146 + 11) // 8 + 16
-    scratch = np.zeros(cap, dtype=np.uint8)
-    out_t = np.empty(T, dtype=np.int64)
-    out_v = np.empty(T, dtype=np.uint64)
-    t0 = _time.perf_counter()
-    n = lib.m3tsz_bench_roundtrip(
-        times.ctypes.data, vbits.ctypes.data, B, T,
-        start, unit_value_ns(unit), _default_bits(unit),
-        scratch.ctypes.data, cap, out_t.ctypes.data, out_v.ctypes.data,
-    )
-    dt = _time.perf_counter() - t0
-    if n < 0:
-        raise ValueError("native bench roundtrip failed")
-    return n / dt
 
 
 def default_threads() -> int:

@@ -260,14 +260,11 @@ def _resolve_impl(impl: str | None = None) -> str:
     cache: the log-tree/shifting-buffer u32 kernels ('tree') avoid the
     scatter/gather + u64-emulation costs that dominate on TPU (both
     seen on the v5e, PR 21); CPU XLA lowers the original scatter/gather
-    design ('scatter') several times faster. Overridable via
-    M3_CODEC_IMPL=tree|scatter."""
-    import os
-
-    impl = impl or os.environ.get("M3_CODEC_IMPL")
-    if impl is not None and impl not in ("tree", "scatter"):
-        raise ValueError(f"unknown codec impl {impl!r}: want 'tree' or 'scatter'")
+    design ('scatter') several times faster."""
     if impl is not None:
+        if impl not in ("tree", "scatter"):
+            raise ValueError(
+                f"unknown codec impl {impl!r}: want 'tree' or 'scatter'")
         return impl
     return "scatter" if jax.default_backend() == "cpu" else "tree"
 

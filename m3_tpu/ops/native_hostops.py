@@ -2,7 +2,7 @@
 
 Serving-path kernels (grouped aggregation, extrapolated rate) used by
 ``ops.windowed_agg`` / ``query.windows`` when no accelerator is live, plus
-the reference-cost-model scalar baselines ``bench_all`` measures against.
+per-sample scalar loops kept as references for the tests.
 Built on demand with g++ like the native m3tsz codec; every caller falls
 back to the numpy host path when no compiler is available.
 """
@@ -145,8 +145,8 @@ def rate_csr(times, values, offsets, eval_ts, range_ns: int,
 
 
 def agg_baseline_scalar(ids: list[bytes], window_ids, values) -> tuple[float, int]:
-    """Run the per-sample reference-shape baseline loop once (one FFI call);
-    returns (checksum of window sums, n samples). Caller times it."""
+    """Reference for the tests: the per-sample loop (one FFI call);
+    returns (checksum of window sums, n samples)."""
     lib = load()
     if lib is None:
         raise RuntimeError("native hostops unavailable")
@@ -165,8 +165,8 @@ def agg_baseline_scalar(ids: list[bytes], window_ids, values) -> tuple[float, in
 
 def rate_baseline_scalar(times, values, offsets, eval_ts, range_ns: int,
                          is_counter: bool, is_rate: bool):
-    """Run the per-(series, step) window-rescan baseline once; returns the
-    [S, K] matrix. Caller times it."""
+    """Reference for the tests: the per-(series, step) window rescan;
+    returns the [S, K] matrix."""
     lib = load()
     if lib is None:
         raise RuntimeError("native hostops unavailable")

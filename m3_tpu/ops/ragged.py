@@ -152,8 +152,7 @@ def assemble_rows(parts_rows: list[list[tuple[np.ndarray, np.ndarray]]],
 def pairs_to_csr(pairs: list[tuple[np.ndarray, np.ndarray]]):
     """(times, vbits, offsets) from per-row (times, vbits) pairs — the
     compatibility ramp for callers that still produce per-series arrays
-    (datapoint-limit chunked reads, cluster facades, the M3_TPU_PAGED=0
-    seed path)."""
+    (datapoint-limit chunked reads, cluster facades)."""
     R = len(pairs)
     offsets = np.empty(R + 1, np.int64)
     offsets[0] = 0

@@ -481,8 +481,8 @@ def _host_prefers_interpreter(spec: PlanSpec) -> bool:
     """The per-PLAN rung of the XLA/native/scalar dispatch ladder: on a
     CPU-only backend, extrapolated-rate bases are served faster by the
     interpreter's native columnar kernel (ops.native_hostops.rate_csr —
-    a pointer-walk the XLA lowering can't match on host; measured ~2.4x
-    in bench #9's development), so a config-enabled engine declines them
+    a pointer-walk the XLA lowering can't match on host; profiled ~2.4x
+    on a CPU host), so a config-enabled engine declines them
     unless an accelerator is live. M3_TPU_QUERY_COMPILE=1 (the explicit
     hatch) overrides — tests and accelerator-bound benches force the
     fused program."""

@@ -15,14 +15,11 @@ multiple encoders at flush.
 from __future__ import annotations
 
 import threading
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import numpy as np
 
 from m3_tpu.storage import pagepool
-
-_GROW = 1024
-
 
 def merge_dedup(times: np.ndarray, vbits: np.ndarray,
                 start_ns: int | None = None, end_ns: int | None = None):
@@ -51,68 +48,20 @@ def merge_dedup(times: np.ndarray, vbits: np.ndarray,
     return times, vbits
 
 
-class _ColumnLog:
-    """Growable (series_idx, time, value_bits) append log."""
-
-    __slots__ = ("sidx", "times", "vbits", "n")
-
-    def __init__(self) -> None:
-        self.sidx = np.empty(_GROW, dtype=np.int32)
-        self.times = np.empty(_GROW, dtype=np.int64)
-        self.vbits = np.empty(_GROW, dtype=np.uint64)
-        self.n = 0
-
-    def append(self, sidx: int, t_ns: int, vbits: int) -> None:
-        if self.n == len(self.sidx):
-            cap = len(self.sidx) * 2
-            self.sidx = np.resize(self.sidx, cap)
-            self.times = np.resize(self.times, cap)
-            self.vbits = np.resize(self.vbits, cap)
-        self.sidx[self.n] = sidx
-        self.times[self.n] = t_ns
-        self.vbits[self.n] = vbits
-        self.n += 1
-
-    def extend(self, sidx: np.ndarray, t_ns: np.ndarray,
-               vbits: np.ndarray) -> None:
-        """Bulk append: one capacity check + three slice-assigns for the
-        whole batch (write_many's per-window store), vs one append per
-        row. Row order is preserved, so seal's last-write-wins dedup
-        resolves batched and per-point writes identically."""
-        m = len(sidx)
-        need = self.n + m
-        if need > len(self.sidx):
-            cap = len(self.sidx)
-            while cap < need:
-                cap *= 2
-            self.sidx = np.resize(self.sidx, cap)
-            self.times = np.resize(self.times, cap)
-            self.vbits = np.resize(self.vbits, cap)
-        self.sidx[self.n : need] = sidx
-        self.times[self.n : need] = t_ns
-        self.vbits[self.n : need] = vbits
-        self.n = need
-
-    def view(self):
-        return self.sidx[: self.n], self.times[: self.n], self.vbits[: self.n]
-
-    def release(self) -> None:
-        """No-op twin of PagedColumnLog.release (grow-arrays just die)."""
-
-
 @dataclass
 class RaggedSealedWindow:
     """One block window sealed to the ragged (offsets, lengths) layout:
     sorted by (series, time), deduped last-write-wins, NO rectangular
     padding — the CSR the length-bucketed ragged encode consumes
-    (hostpath.encode_blocks_ragged) and the paged-memory twin of
-    SealedWindow (ROADMAP #3)."""
+    (hostpath.encode_blocks_ragged)."""
 
     block_start: int
     series_indices: np.ndarray  # [B] int32 buffer-level series indices
     times: np.ndarray           # [N] int64
     value_bits: np.ndarray      # [N] uint64
     offsets: np.ndarray         # [B+1] int64 row boundaries
+    # raw log rows this seal covered: drop_window_prefix(bs, raw_count)
+    # removes exactly these, preserving concurrent appends after the seal
     raw_count: int = 0
 
     @property
@@ -124,25 +73,6 @@ class RaggedSealedWindow:
         return np.diff(self.offsets).astype(np.int32)
 
 
-@dataclass
-class SealedWindow:
-    """One block window grouped into a padded (series x point) batch."""
-
-    block_start: int
-    series_indices: np.ndarray  # [B] int32 buffer-level series indices
-    times: np.ndarray  # [B, T] int64 (padded)
-    value_bits: np.ndarray  # [B, T] uint64 (padded)
-    n_points: np.ndarray  # [B] int32
-    starts: np.ndarray = field(default=None)  # [B] int64, all == block_start
-    # raw log rows this seal covered: drop_window_prefix(bs, raw_count)
-    # removes exactly these, preserving concurrent appends after the seal
-    raw_count: int = 0
-
-    @property
-    def n_series(self) -> int:
-        return len(self.series_indices)
-
-
 class ShardBuffer:
     """Per-shard buffer: series registry + one column log per block window."""
 
@@ -151,21 +81,13 @@ class ShardBuffer:
         self._series: dict[bytes, int] = {}
         self.series_ids: list[bytes] = []
         self.series_tags: list[bytes] = []  # encoded tag blobs
-        self._logs: dict[int, _ColumnLog] = {}
+        self._logs: dict[int, pagepool.PagedColumnLog] = {}
         # paged columnar memory (ROADMAP #3): window logs draw fixed-size
-        # pages from a shared pool instead of doubling grow-arrays; the
-        # M3_TPU_PAGED=0 hatch (read once, at buffer construction) pins
-        # the seed _ColumnLog bodies for bisection
-        self._paged = pagepool.active()
-        self._pool = (pagepool.monitor_pool(pagepool.PagePool())
-                      if self._paged else None)
+        # pages from one pool shared by the shard's windows
+        self._pool = pagepool.monitor_pool(pagepool.PagePool())
         # one lock per shard buffer (the reference's per-shard lock):
         # HTTP handler threads write while the tick thread seals/expires
         self._lock = threading.RLock()
-
-    def _new_log(self):
-        return (pagepool.PagedColumnLog(self._pool) if self._paged
-                else _ColumnLog())
 
     # -- write path --
 
@@ -186,14 +108,14 @@ class ShardBuffer:
             bs = t_ns - (t_ns % self._block_size_ns)
             log = self._logs.get(bs)
             if log is None:
-                log = self._logs[bs] = self._new_log()
+                log = self._logs[bs] = pagepool.PagedColumnLog(self._pool)
             log.append(idx, t_ns, vbits)
             return idx
 
     def write_many(self, series_ids: list[bytes], times: np.ndarray,
                    vbits: np.ndarray, tags_list: list[bytes]) -> None:
         """Bulk write under ONE lock acquisition: resolve (registering)
-        every series index, then ONE _ColumnLog.extend per block window
+        every series index, then ONE log extend per block window
         in the batch — numpy slice-assign, not N appends. Equivalent to
         calling write() per row; rows keep arrival order per window so
         seal-time conflict resolution is unchanged."""
@@ -213,7 +135,7 @@ class ShardBuffer:
                 sel = bs == w
                 log = self._logs.get(int(w))
                 if log is None:
-                    log = self._logs[int(w)] = self._new_log()
+                    log = self._logs[int(w)] = pagepool.PagedColumnLog(self._pool)
                 log.extend(idxs[sel], times[sel], vbits[sel])
 
     # -- read path --
@@ -296,18 +218,24 @@ class ShardBuffer:
         log = self._logs.get(block_start)
         return log.n if log else 0
 
-    def _seal_sorted(self, block_start: int, drop: bool):
-        """Locked extract + the ONE sort/dedup definition both seal
-        layouts share: stable (series, time) sort, same-timestamp dedupe
-        keeping the LAST append.  Returns (sidx, times, vbits,
-        raw_count, fill_ratio) or None for an absent/empty window."""
+    def seal_csr(self, block_start: int,
+                 drop: bool = True) -> RaggedSealedWindow | None:
+        """Seal one block window to the RAGGED layout: stable sort by
+        (series, time), same-timestamp dedupe keeping the LAST append,
+        and the output stays a CSR — no rectangular scatter, no padding,
+        so a window where one series wrote 10k points and a million wrote
+        one costs O(samples), not O(series x 10k).  The length-bucketed
+        ragged encode (hostpath.encode_blocks_ragged) consumes this
+        directly."""
+        from m3_tpu.utils.instrument import default_registry
+
         with self._lock:
             log = self._logs.get(block_start)
             if log is None or log.n == 0:
                 return None
             raw_count = log.n
             sidx, times, vbits = (a.copy() for a in log.view())
-            fill = log.fill_ratio() if hasattr(log, "fill_ratio") else 1.0
+            fill = log.fill_ratio()
             if drop:
                 del self._logs[block_start]
                 log.release()
@@ -317,59 +245,7 @@ class ShardBuffer:
         if len(sidx) > 1:
             same = (sidx[1:] == sidx[:-1]) & (times[1:] == times[:-1])
             keep[:-1] = ~same
-        return sidx[keep], times[keep], vbits[keep], raw_count, fill
-
-    def seal(self, block_start: int, drop: bool = True) -> SealedWindow | None:
-        """Group one block window into a padded batch for device encode.
-
-        Stable-sorts by (series, time), dedupes last-write-wins, pads to the
-        max points of any series in the window.
-        """
-        ext = self._seal_sorted(block_start, drop)
-        if ext is None:
-            return None
-        sidx, times, vbits, raw_count, _fill = ext
-
-        uniq, counts = np.unique(sidx, return_counts=True)
-        B, T = len(uniq), int(counts.max())
-        out_t = np.zeros((B, T), np.int64)
-        out_v = np.zeros((B, T), np.uint64)
-        row = np.repeat(np.arange(B), counts)
-        col = np.arange(len(sidx)) - np.repeat(
-            np.concatenate([[0], np.cumsum(counts)[:-1]]), counts
-        )
-        out_t[row, col] = times
-        out_v[row, col] = vbits
-        # pad timestamps past n_points monotonically so the encoder's
-        # masked lanes still see sane deltas
-        pad_mask = np.arange(T)[None, :] >= counts[:, None]
-        out_t = np.where(pad_mask, out_t.max(axis=1, keepdims=True), out_t)
-        return SealedWindow(
-            block_start=block_start,
-            series_indices=uniq.astype(np.int32),
-            times=out_t,
-            value_bits=out_v,
-            n_points=counts.astype(np.int32),
-            starts=np.full(B, block_start, dtype=np.int64),
-            raw_count=raw_count,
-        )
-
-    def seal_csr(self, block_start: int,
-                 drop: bool = True) -> RaggedSealedWindow | None:
-        """Seal one block window to the RAGGED layout: same stable sort
-        by (series, time) + last-write-wins dedup as seal(), but the
-        output stays a CSR — no rectangular scatter, no padding, so a
-        window where one series wrote 10k points and a million wrote one
-        costs O(samples), not O(series x 10k).  The length-bucketed
-        ragged encode (hostpath.encode_blocks_ragged) consumes this
-        directly and produces byte-identical streams to the padded
-        path."""
-        from m3_tpu.utils.instrument import default_registry
-
-        ext = self._seal_sorted(block_start, drop)
-        if ext is None:
-            return None
-        sidx, times, vbits, raw_count, fill = ext
+        sidx, times, vbits = sidx[keep], times[keep], vbits[keep]
         # page-occupancy telemetry: how much of the window's page
         # allocation held real rows at seal time (padding-waste measure)
         default_registry().root_scope("storage").subscope(
@@ -405,24 +281,9 @@ class ShardBuffer:
                 del self._logs[block_start]
                 log.release()
                 return
-            if hasattr(log, "drop_prefix"):
-                # paged log: advance the head, free covered pages — no
-                # suffix copy under the shard lock
-                log.drop_prefix(n)
-                return
-            # bulk copy the surviving suffix: this runs under the shard
-            # lock, so a per-row python loop would stall every writer
-            rest = _ColumnLog()
-            m = log.n - n
-            cap = max(_GROW, m)
-            rest.sidx = np.empty(cap, dtype=np.int32)
-            rest.times = np.empty(cap, dtype=np.int64)
-            rest.vbits = np.empty(cap, dtype=np.uint64)
-            rest.sidx[:m] = log.sidx[n:log.n]
-            rest.times[:m] = log.times[n:log.n]
-            rest.vbits[:m] = log.vbits[n:log.n]
-            rest.n = m
-            self._logs[block_start] = rest
+            # advance the head, free covered pages — no suffix copy under
+            # the shard lock
+            log.drop_prefix(n)
 
     def expire_before(self, cutoff_block_start: int) -> int:
         with self._lock:

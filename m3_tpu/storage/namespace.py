@@ -263,9 +263,9 @@ class Namespace:
         into `RaggedSeries`, which is exactly what the whole-query
         compiler's `_slab_cuts`/`_fill_slabs` slab prep consumes.  Same
         results, limits accounting and warnings contract as read_many
-        (per-row slices are element-identical); paths the paged finalize
-        doesn't cover (M3_TPU_PAGED=0, datapoint-limit chunking, serial
-        hatch) assemble the CSR from the per-series views in one pass."""
+        (per-row slices are element-identical); paths the batched finalize
+        doesn't cover (datapoint-limit chunking, serial hatch) assemble
+        the CSR from the per-series views in one pass."""
         from m3_tpu.ops import ragged
         from m3_tpu.utils import trace
         from m3_tpu.utils.instrument import default_registry
@@ -327,13 +327,11 @@ class Namespace:
         merge_dedup resolves last-write-wins.
         """
         from m3_tpu.ops import ragged
-        from m3_tpu.storage import pagepool
 
-        # paged: batched ragged finalize per shard; fragments of the
-        # namespace-level ragged combine (one merged per-shard CSR each)
-        # are only tracked when the caller asked for the CSR back
-        paged = pagepool.active()
-        frags: list | None = [] if (paged and want_ragged) else None
+        # fragments of the namespace-level ragged combine (one merged
+        # per-shard CSR each) are only tracked when the caller asked for
+        # the CSR back
+        frags: list | None = [] if want_ragged else None
         groups = []
         plans = []
         for shard_id, idxs in by_shard.items():
@@ -345,9 +343,8 @@ class Namespace:
                                                  parts))
         run_read_groups(groups)
         for plan in plans:
-            self._finalize_shard_read(plan, start_ns, end_ns, out, paged,
-                                      frags)
-        if want_ragged and frags is not None:
+            self._finalize_shard_read(plan, start_ns, end_ns, out, frags)
+        if frags is not None:
             # pure O(N) scatter: each fragment is already merged and
             # filtered, and every row lives in exactly one fragment —
             # the combine just lands rows at their query-order positions
@@ -355,31 +352,22 @@ class Namespace:
         return out
 
     def _finalize_shard_read(self, plan, start_ns, end_ns, out,
-                             paged: bool = False,
                              frags: list | None = None) -> None:
+        """Batched ragged finalize (ROADMAP #3): ONE merge pass over the
+        shard's series; out[] carries zero-copy row slices of the shard
+        CSR."""
+        import numpy as np
+
         shard, idxs, sids, parts = plan
         limits = self.limits
-        if paged:
-            # batched ragged finalize (ROADMAP #3): ONE merge pass over
-            # the shard's series instead of per-series concatenates;
-            # out[] carries zero-copy row slices of the shard CSR
-            import numpy as np
-
-            t, v, offs = shard.finish_read_many(sids, parts, start_ns,
-                                                end_ns)
-            for j, i in enumerate(idxs):
-                a, b = int(offs[j]), int(offs[j + 1])
-                if limits is not None:
-                    limits.add_datapoints(b - a)
-                out[i] = (t[a:b], v[a:b])
-            if frags is not None:
-                frags.append((np.asarray(idxs, np.int64), t, v, offs))
-            return
-        for i, sid, pl in zip(idxs, sids, parts):
-            times, vbits = shard.finish_read(sid, pl, start_ns, end_ns)
+        t, v, offs = shard.finish_read_many(sids, parts, start_ns, end_ns)
+        for j, i in enumerate(idxs):
+            a, b = int(offs[j]), int(offs[j + 1])
             if limits is not None:
-                limits.add_datapoints(len(times))
-            out[i] = (times, vbits)
+                limits.add_datapoints(b - a)
+            out[i] = (t[a:b], v[a:b])
+        if frags is not None:
+            frags.append((np.asarray(idxs, np.int64), t, v, offs))
 
     def flush(self, now_ns: int) -> int:
         """WARM flush: first volume for aged-out buffered windows."""

@@ -1,19 +1,18 @@
 """Paged columnar memory for the ingest buffer (ROADMAP #3).
 
-The seed `_ColumnLog` keeps one grow-array triple per block window:
-growth doubles (up to 2x overshoot per window), a window drop frees
-nothing until the arrays die, and `drop_window_prefix` COPIES the whole
-surviving suffix under the shard lock at every flush.  Following
-PAPERS.md "Ragged Paged Attention" (fixed pages, ragged index vectors),
-this module replaces the grow-arrays with a shared pool of FIXED-SIZE
-columnar pages:
+One grow-array triple per block window would double as it grows (up to
+2x overshoot per window), free nothing at a window drop until the arrays
+die, and make `drop_window_prefix` COPY the whole surviving suffix under
+the shard lock at every flush.  Following PAPERS.md "Ragged Paged
+Attention" (fixed pages, ragged index vectors), the buffer's window logs
+draw from a shared pool of FIXED-SIZE columnar pages:
 
 - ``PagePool`` hands out pages cut from arena slabs (slabs are never
   resized, so page views stay stable); freed pages go to a free list
   and are reused before the arena grows; a free list deeper than
   ``max_free_pages`` releases whole all-free slabs back to the OS —
   counted as evictions on the saturation plane.
-- ``PagedColumnLog`` is the `_ColumnLog` twin backed by a page list +
+- ``PagedColumnLog`` is a window's append log over a page list +
   a head offset: appends fill the tail page, bulk appends fill pages
   slab-assign by slab-assign, and ``drop_prefix`` just advances the
   head and frees fully-covered pages — O(pages freed), no copy under
@@ -24,15 +23,10 @@ Saturation-plane discipline (m3lint ``inv-pagepool-gauge``): every
 same scope — pools feed the aggregate ``queue_*{queue=page_pool}``
 gauges refreshed by the PR-11 snapshot hook, so occupancy and eviction
 are dashboards, not mysteries.
-
-``M3_TPU_PAGED=0`` pins the seed grow-array `_ColumnLog` and the seed
-per-series finalize bodies everywhere (bisection hatch, the
-``M3_TPU_PIPELINE=0`` discipline).
 """
 
 from __future__ import annotations
 
-import os
 import threading
 import weakref
 
@@ -43,12 +37,6 @@ from m3_tpu.utils.instrument import monitor_queue, register_snapshot_hook
 PAGE_ROWS = 1024          # rows per page (sidx i32 + times i64 + vbits u64)
 _SLAB_PAGES = 64          # pages allocated per arena slab
 _BYTES_PER_ROW = 4 + 8 + 8
-
-
-def active() -> bool:
-    """The M3_TPU_PAGED hatch: unset/1 = paged columnar memory + ragged
-    finalize, 0 = the seed grow-array/per-series-concatenate bodies."""
-    return os.environ.get("M3_TPU_PAGED", "1") != "0"
 
 
 class _Slab:
@@ -141,8 +129,9 @@ class PagePool:
 
 
 class PagedColumnLog:
-    """`_ColumnLog` twin over pool pages: logical row i lives at
-    physical offset head+i of the page list."""
+    """Growable (series_idx, time, value_bits) append log over pool
+    pages: logical row i lives at physical offset head+i of the page
+    list."""
 
     __slots__ = ("pool", "pages", "head", "n", "_view_cache")
 
@@ -170,8 +159,8 @@ class PagedColumnLog:
     def extend(self, sidx: np.ndarray, t_ns: np.ndarray,
                vbits: np.ndarray) -> None:
         """Bulk append filling pages slab-assign by slab-assign; row
-        order is preserved so seal-time last-write-wins conflict
-        resolution is unchanged (the `_ColumnLog.extend` contract)."""
+        order is preserved, so seal-time last-write-wins resolves
+        batched and per-point writes identically."""
         m = len(sidx)
         end = self._phys_end()
         need_pages = -(-(end + m) // PAGE_ROWS)
@@ -217,8 +206,8 @@ class PagedColumnLog:
 
     def drop_prefix(self, k: int) -> None:
         """Drop the first k logical rows by advancing the head and
-        freeing fully-covered pages — O(pages freed), vs the seed
-        path's full suffix copy under the shard lock."""
+        freeing fully-covered pages — O(pages freed), no suffix
+        copy under the shard lock."""
         k = min(k, self.n)
         self.head += k
         self.n -= k

@@ -322,15 +322,10 @@ class Shard:
             parts: list[list] = [[] for _ in series_ids]
             run_read_groups(
                 self.plan_read_groups(series_ids, start_ns, end_ns, parts))
-            from m3_tpu.storage import pagepool
-
-            if pagepool.active():
-                t, v, offs = self.finish_read_many(series_ids, parts,
-                                                   start_ns, end_ns)
-                return [(t[offs[i]:offs[i + 1]], v[offs[i]:offs[i + 1]])
-                        for i in range(len(series_ids))]
-            return [self.finish_read(sid, pl, start_ns, end_ns)
-                    for sid, pl in zip(series_ids, parts)]
+            t, v, offs = self.finish_read_many(series_ids, parts,
+                                               start_ns, end_ns)
+            return [(t[offs[i]:offs[i + 1]], v[offs[i]:offs[i + 1]])
+                    for i in range(len(series_ids))]
         return self._read_many_serial(series_ids, start_ns, end_ns)
 
     def plan_read_groups(self, series_ids: list[bytes], start_ns: int,
@@ -364,7 +359,7 @@ class Shard:
 
         if len(set(series_ids)) != len(series_ids):
             # duplicate ids: the CSR position map is one row per id —
-            # take the per-series seed finalize (correctness over speed
+            # take the per-series finalize (correctness over speed
             # on a shape no production caller emits)
             pairs = [self.finish_read(sid, list(pl), start_ns, end_ns)
                      for sid, pl in zip(series_ids, parts)]
@@ -463,40 +458,20 @@ class Shard:
 
         faults.check("shard.snapshot", shard=self.shard_id,
                      block_start=block_start)
-        from m3_tpu.storage import pagepool
-
-        if pagepool.active():
-            # ragged seal + length-bucketed encode: no [B, max_T]
-            # rectangle for the snapshot either (byte-identical streams)
-            sealed = self.buffer.seal_csr(block_start, drop=False)
-            if sealed is None:
-                return False
-            ids = [self.buffer.series_ids[i] for i in sealed.series_indices]
-            tags = [self.buffer.series_tags[i]
-                    for i in sealed.series_indices]
-            try:
-                streams = hostpath.encode_blocks_ragged(
-                    sealed.times, sealed.value_bits, sealed.offsets,
-                    np.full(sealed.n_series, block_start, np.int64),
-                    self.opts.write_time_unit, self.opts.int_optimized,
-                )
-            except OverflowError:
-                return False
-        else:
-            sealed = self.buffer.seal(block_start, drop=False)
-            if sealed is None:
-                return False
-            ids = [self.buffer.series_ids[i] for i in sealed.series_indices]
-            tags = [self.buffer.series_tags[i]
-                    for i in sealed.series_indices]
-            try:
-                streams = hostpath.encode_blocks(
-                    sealed.times, sealed.value_bits, sealed.starts,
-                    sealed.n_points, self.opts.write_time_unit,
-                    self.opts.int_optimized,
-                )
-            except OverflowError:
-                return False
+        # ragged seal + length-bucketed encode: no [B, max_T] rectangle
+        sealed = self.buffer.seal_csr(block_start, drop=False)
+        if sealed is None:
+            return False
+        ids = [self.buffer.series_ids[i] for i in sealed.series_indices]
+        tags = [self.buffer.series_tags[i] for i in sealed.series_indices]
+        try:
+            streams = hostpath.encode_blocks_ragged(
+                sealed.times, sealed.value_bits, sealed.offsets,
+                np.full(sealed.n_series, block_start, np.int64),
+                self.opts.write_time_unit, self.opts.int_optimized,
+            )
+        except OverflowError:
+            return False
         writer = FilesetWriter(
             snapshot_root, self.namespace, self.shard_id, block_start,
             self.opts.retention.block_size_ns, snapshot_id,
@@ -600,7 +575,11 @@ class Shard:
                 return self._flush_locked(block_start)
 
     def _flush_locked(self, block_start: int) -> bool:
+        """Ragged seal (no [B, max_T] scatter), per-series merge against
+        the previous volume on CSR slices, length-bucketed ragged encode,
+        then the durability tail."""
         from m3_tpu.encoding.m3tsz import hostpath
+        from m3_tpu.ops import ragged
 
         # the kill-mid-flush seam: a crash anywhere before the checkpoint
         # lands must leave the buffer window intact (seal below never
@@ -608,116 +587,10 @@ class Shard:
         faults.check("shard.flush", shard=self.shard_id,
                      block_start=block_start)
         self._drain_retired()
-        from m3_tpu.storage import pagepool
-
-        if pagepool.active():
-            return self._flush_ragged(block_start)
 
         # Seal WITHOUT dropping: the buffer window is the only copy until the
         # fileset volume is durably on disk; a failed flush must leave it
         # intact (and with it the retired-commitlog coverage check).
-        sealed = self.buffer.seal(block_start, drop=False)
-        if sealed is None:
-            return False
-
-        ids = [self.buffer.series_ids[i] for i in sealed.series_indices]
-        tags = [self.buffer.series_tags[i] for i in sealed.series_indices]
-        times = sealed.times
-        vbits = sealed.value_bits
-        n_points = sealed.n_points
-
-        prev = self._filesets.get(block_start)
-        volume = 0
-        extra: list[tuple[bytes, bytes, bytes]] = []  # untouched old series
-        if prev is not None:
-            volume = prev.volume + 1
-            merged_t, merged_v, merged_n = [], [], []
-            new_ids = {sid: k for k, sid in enumerate(ids)}
-            for i in range(prev.n_series):
-                sid, stags, stream = prev.read_at(i)
-                if sid not in new_ids:
-                    extra.append((sid, stags, stream))
-                    continue
-                k = new_ids[sid]
-                old_t, old_v = hostpath.decode_stream(
-                    stream, self.opts.write_time_unit,
-                    self.opts.int_optimized,
-                )
-                nt, nv = merge_dedup(
-                    np.concatenate([old_t, times[k, : n_points[k]]]),
-                    np.concatenate([old_v, vbits[k, : n_points[k]]]),
-                )
-                merged_t.append(nt)
-                merged_v.append(nv)
-                merged_n.append(k)
-            if merged_n:
-                width = max(times.shape[1], max(len(t) for t in merged_t))
-                if width > times.shape[1]:
-                    pad = width - times.shape[1]
-                    times = np.pad(times, ((0, 0), (0, pad)), constant_values=block_start)
-                    vbits = np.pad(vbits, ((0, 0), (0, pad)))
-                for k, nt, nv in zip(merged_n, merged_t, merged_v):
-                    times[k, : len(nt)] = nt
-                    vbits[k, : len(nv)] = nv
-                    times[k, len(nt):] = nt[-1]
-                    n_points[k] = len(nt)
-
-        try:
-            streams = hostpath.encode_blocks(
-                times, vbits, sealed.starts, n_points,
-                self.opts.write_time_unit, self.opts.int_optimized,
-            )
-        except OverflowError:
-            raise RuntimeError(
-                f"flush encode overflow: shard={self.shard_id} bs={block_start}"
-            )
-
-        self._write_volume_and_swap(ids, tags, streams, extra,
-                                    block_start, volume, prev,
-                                    sealed.raw_count)
-        return True
-
-    def _write_volume_and_swap(self, ids, tags, streams, extra,
-                               block_start: int, volume: int, prev,
-                               raw_count: int) -> None:
-        """The flush DURABILITY TAIL shared by the padded and ragged
-        bodies (which only differ in how they seal/merge/encode): paced
-        volume write + checkpoint, reader retire/swap, cache
-        invalidation, and only THEN dropping exactly the sealed prefix —
-        concurrent appends after the seal copy stay buffered."""
-        writer = FilesetWriter(
-            self.fs_root, self.namespace, self.shard_id, block_start,
-            self.opts.retention.block_size_ns, volume,
-        )
-        for sid, stags, stream in zip(ids, tags, streams):
-            self._pace_persist(len(stream))
-            writer.write_series(sid, stags, stream)
-        for sid, stags, stream in extra:
-            self._pace_persist(len(stream))
-            writer.write_series(sid, stags, stream)
-        writer.close()
-
-        if prev is not None:
-            self._retire(prev)
-        self._filesets[block_start] = FilesetReader(
-            self.fs_root, self.namespace, self.shard_id, block_start, volume
-        )
-        if self.cache is not None:  # cached decodes are for the old volume
-            self.cache.invalidate_block(self.namespace, self.shard_id,
-                                        block_start)
-        self.buffer.drop_window_prefix(block_start, raw_count)
-        self.bump_data_version()
-
-    def _flush_ragged(self, block_start: int) -> bool:
-        """The paged-memory flush body (M3_TPU_PAGED=1): ragged seal
-        (no [B, max_T] scatter), per-series merge against the previous
-        volume on CSR slices, length-bucketed ragged encode — streams
-        byte-identical to the padded body, volumes indistinguishable on
-        disk.  Durability order is the seed body's: seal without drop,
-        write + checkpoint, swap, only then drop the covered prefix."""
-        from m3_tpu.encoding.m3tsz import hostpath
-        from m3_tpu.ops import ragged
-
         sealed = self.buffer.seal_csr(block_start, drop=False)
         if sealed is None:
             return False
@@ -769,9 +642,32 @@ class Shard:
                 f"flush encode overflow: shard={self.shard_id} bs={block_start}"
             )
 
-        self._write_volume_and_swap(ids, tags, streams, extra,
-                                    block_start, volume, prev,
-                                    sealed.raw_count)
+        # the durability tail: paced volume write + checkpoint, reader
+        # retire/swap, cache invalidation, and only THEN dropping exactly
+        # the sealed prefix — concurrent appends after the seal copy stay
+        # buffered
+        writer = FilesetWriter(
+            self.fs_root, self.namespace, self.shard_id, block_start,
+            self.opts.retention.block_size_ns, volume,
+        )
+        for sid, stags, stream in zip(ids, tags, streams):
+            self._pace_persist(len(stream))
+            writer.write_series(sid, stags, stream)
+        for sid, stags, stream in extra:
+            self._pace_persist(len(stream))
+            writer.write_series(sid, stags, stream)
+        writer.close()
+
+        if prev is not None:
+            self._retire(prev)
+        self._filesets[block_start] = FilesetReader(
+            self.fs_root, self.namespace, self.shard_id, block_start, volume
+        )
+        if self.cache is not None:  # cached decodes are for the old volume
+            self.cache.invalidate_block(self.namespace, self.shard_id,
+                                        block_start)
+        self.buffer.drop_window_prefix(block_start, sealed.raw_count)
+        self.bump_data_version()
         return True
 
     # -- bootstrap --

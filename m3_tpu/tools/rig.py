@@ -683,7 +683,7 @@ def windowed_p99s_ms(scrape_fn, family: str, labels: dict,
                      run_window_fn, n_windows: int) -> list:
     """Per-window p99s from a CUMULATIVE server histogram: scrape at
     every window boundary, diff bucket counts, interpolate. The
-    pair-median protocol (bench #7's noisy-host discipline): callers
+    pair-median protocol (the noisy-host discipline): callers
     take the MEDIAN of the window p99s so one scheduler hiccup cannot
     fake an SLO breach."""
     out = []

@@ -29,15 +29,13 @@ tracker:
   and per-device memory; the plan cache is read only from an
   already-imported compiler module.
 
-``M3_TPU_COMPUTE_STATS=0`` disarms the per-call paths (``arm()`` is the
-programmatic toggle bench #16 flips); the table survives disarming so
-``/debug/compute`` keeps its history.
+``arm(False)`` disarms the per-call paths; the table survives disarming
+so ``/debug/compute`` keeps its history.
 """
 
 from __future__ import annotations
 
 import json
-import os
 import threading
 
 from m3_tpu.utils import backend
@@ -46,12 +44,12 @@ from m3_tpu.utils import backend
 # arming
 # ---------------------------------------------------------------------------
 
-_armed = os.environ.get("M3_TPU_COMPUTE_STATS", "1") != "0"
+_armed = True
 
 
 def arm(on: bool) -> None:
-    """Toggle the per-call recording paths (bench #16 overhead guard
-    flips this); the accumulated table is kept either way."""
+    """Toggle the per-call recording paths; the accumulated table is
+    kept either way."""
     global _armed
     _armed = bool(on)
 
@@ -344,4 +342,4 @@ def reset() -> None:
         _waste.clear()
         _evictions.clear()
         _sig_labels_seen.clear()
-    _armed = os.environ.get("M3_TPU_COMPUTE_STATS", "1") != "0"
+    _armed = True

@@ -25,8 +25,7 @@ import, like the shadow-lock checker) swaps ``threading.Lock/RLock``
 for wrappers keyed by CONSTRUCTION SITE (lockcheck's lock-class
 semantics: every ``Shard._lock`` is one class however many shards
 exist). The fast path is a non-blocking try-acquire plus one counter
-increment — an uncontended acquire pays no clock read at all (bench #10
-holds the armed write hot path inside the 0.85 noise bar). A failed
+increment — an uncontended acquire pays no clock read at all. A failed
 trylock IS the contention signal: only then does the wrapper time the
 blocking acquire and land the wait in the per-class histogram, so
 "which lock burns our p99" is a measured table — the consensus fsync

@@ -125,3 +125,43 @@ class TestWarmColdSplit:
         assert (START + 50 * MIN) in t.tolist()
         assert 7.5 in v.view(np.float64).tolist()
         db2.close()
+
+    def test_merged_series_longer_than_buffer_window_bit_exact(self, tmp_path):
+        """A cold flush where the merged series (40 old points + 1 new)
+        is longer than any series of the buffer window it merges (at
+        most 3 rows): every stream of the version-bumped volume, merged,
+        new and carried over, decodes under the scalar decoder to
+        exactly the acked samples, bit for bit."""
+        from m3_tpu.encoding.m3tsz import decode
+
+        db = make_db(tmp_path)
+        rng = np.random.default_rng(5)
+        want: dict[bytes, dict[int, int]] = {}
+
+        def put(name, t_ns, v):
+            write(db, name, t_ns, v)
+            want.setdefault(name, {})[t_ns] = bits(v)  # last write wins
+
+        for i in range(40):
+            put(b"long", START + i * MIN, float(rng.normal(50, 7)))
+        for i in range(4):
+            put(b"kept", START + i * MIN, float(i) + 0.25)
+        assert db.tick(START + 2 * HOUR + 11 * MIN)["flushed"] >= 1
+        put(b"long", START + 90 * MIN, 1.0 / 3.0)        # appended
+        put(b"kept2", START + 5 * MIN, 2.5)              # new in the volume
+        put(b"kept2", START + 3 * MIN, -0.0)             # out of order
+        put(b"kept2", START + 5 * MIN, 7.125)            # overwrites
+        assert db.namespaces["default"].cold_flush() >= 1
+
+        for name in (b"long", b"kept", b"kept2"):
+            shard, sid = shard_of(db, name)
+            reader = shard._filesets[START]
+            assert shard.buffer.points_in(START) == 0
+            dps = decode(reader.read(sid), int_optimized=False,
+                         default_time_unit=shard.opts.write_time_unit)
+            got = {d.timestamp_ns: bits(d.value) for d in dps}
+            assert [d.timestamp_ns for d in dps] == sorted(want[name])
+            assert got == want[name], name
+        shard, _sid = shard_of(db, b"long")
+        assert shard._filesets[START].volume == 1
+        db.close()

@@ -531,12 +531,10 @@ class TestChaosQuick:
                            for p, *_ in plan.schedule), \
                 "serial path must never reach the pipeline seam"
 
-    def test_chaos_paged_iterations_quick(self, tmp_path, monkeypatch):
-        """Paged columnar memory ARMED (ISSUE 15): the batched
-        kill/torn-write sweep with page-pool buffers and the ragged
-        flush body — zero acked-write loss, same contract as the seed
-        grow-array path."""
-        monkeypatch.setenv("M3_TPU_PAGED", "1")
+    def test_chaos_paged_iterations_quick(self, tmp_path):
+        """The batched kill/torn-write sweep over page-pool buffers and
+        the ragged flush body (ISSUE 15), four seeds of its own under
+        the batch spec — zero acked-write loss."""
         crashes = 0
         for seed in range(4):
             faults.configure(BATCH_CHAOS_SPEC, seed=seed)
@@ -545,11 +543,10 @@ class TestChaosQuick:
             crashes += crashed
         assert crashes >= 1
 
-    def test_repair_chaos_paged_iteration(self, tmp_path, monkeypatch):
-        """One seeded repair-storm iteration with paging armed: repair
-        convergence (rollup-digest equality) is unchanged by the paged
-        flush/snapshot bodies."""
-        monkeypatch.setenv("M3_TPU_PAGED", "1")
+    def test_repair_chaos_paged_iteration(self, tmp_path):
+        """One seeded repair-storm iteration (seed 1): repair convergence
+        (rollup-digest equality) through the ragged flush/snapshot
+        bodies."""
         _c, cycles = _repair_chaos_iteration(str(tmp_path / "pg"), 1)
         assert cycles >= 1
 

@@ -3,7 +3,7 @@
 The native kernels (native/hostops.cpp) are the CPU serving path for large
 flushes/fetches; these tests pin them to the numpy reference implementations
 they replace (same grouping, same stats, same Prometheus rate math), plus
-the bench baselines to the serving outputs (no-strawman check).
+the scalar reference loops to the serving outputs.
 """
 
 import numpy as np

@@ -3,17 +3,18 @@ ROADMAP #3).
 
 Contracts under test:
 - the paged page pool / PagedColumnLog are operation-for-operation
-  equivalent to the seed grow-array `_ColumnLog` (seeded property sweep
-  incl. page-boundary-straddling windows and prefix drops);
+  equivalent to a plain numpy model, concatenate and slice (seeded
+  property sweep incl. page-boundary-straddling windows and prefix
+  drops);
 - `ops.ragged.merge_csr` / `assemble_rows` are row-for-row identical to
   the per-series `merge_dedup` reference (exact uint64 bit patterns),
   including empty, singleton, duplicated and unsorted rows;
-- the ragged seal + length-bucketed encode produce BYTE-identical
-  streams to the padded seal + encode;
-- the full read path (buffer + filesets, pipelined and serial) returns
-  exactly the same samples with M3_TPU_PAGED=1 and =0, and engine
-  results (compiled and interpreted) agree to exact NaN masks + 1e-9;
-- the M3_TPU_PAGED=0 hatch pins the seed buffer bodies;
+- the ragged seal equals a per-series model of the writes, and the
+  length-bucketed encode produces BYTE-identical streams to the encode
+  of the fully padded rectangle;
+- the batched read finalize (buffer + filesets, pipelined and serial)
+  returns exactly the samples of the per-series read, and compiled and
+  interpreted engine results agree to exact NaN masks + 1e-9;
 - the device-resident hot tier serves repeated identical queries from
   warm prepared slabs, invalidates on any data-version bump, and the
   bf16 mirror engages only under the per-query precision grant.
@@ -26,7 +27,7 @@ from m3_tpu.ops import ragged
 from m3_tpu.query import explain
 from m3_tpu.query.engine import Engine
 from m3_tpu.storage import hottier, pagepool
-from m3_tpu.storage.buffer import ShardBuffer, _ColumnLog, merge_dedup
+from m3_tpu.storage.buffer import ShardBuffer, merge_dedup
 from m3_tpu.storage.database import Database
 from m3_tpu.storage.options import (
     DatabaseOptions, IndexOptions, NamespaceOptions, RetentionOptions,
@@ -91,15 +92,17 @@ class TestPagePool:
 
 
 class TestPagedColumnLog:
-    def test_property_parity_with_grow_log(self):
+    def test_property_parity_with_numpy_model(self):
         rng = np.random.default_rng(7)
         pool = pagepool.PagePool()
         for _ in range(10):
             paged = pagepool.PagedColumnLog(pool)
-            seed = _ColumnLog()
-            total = 0
+            # the model: three plain columns, concatenate and slice
+            model = (np.empty(0, np.int32), np.empty(0, np.int64),
+                     np.empty(0, np.uint64))
             for _ in range(int(rng.integers(2, 8))):
                 op = rng.random()
+                total = len(model[0])
                 if op < 0.55:
                     # bulk extend, sized to straddle page boundaries
                     m = int(rng.integers(1, 3000))
@@ -107,24 +110,22 @@ class TestPagedColumnLog:
                     t = rng.integers(0, 10**6, m).astype(np.int64)
                     v = rng.integers(0, 2**63, m).astype(np.uint64)
                     paged.extend(s, t, v)
-                    seed.extend(s, t, v)
-                    total += m
+                    model = tuple(np.concatenate([a, b])
+                                  for a, b in zip(model, (s, t, v)))
                 elif op < 0.85 or total == 0:
                     paged.append(3, 17, 99)
-                    seed.append(3, 17, 99)
-                    total += 1
+                    model = tuple(np.concatenate([a, np.array([x], a.dtype)])
+                                  for a, x in zip(model, (3, 17, 99)))
                 else:
                     k = int(rng.integers(0, total + 1))
                     paged.drop_prefix(k)
-                    # seed twin of drop_prefix: slice the arrays
-                    s0, t0, v0 = seed.view()
-                    seed = _ColumnLog()
-                    if total - k:
-                        seed.extend(s0[k:], t0[k:], v0[k:])
-                    total -= k
-                for a, b in zip(paged.view(), seed.view()):
+                    model = tuple(a[k:] for a in model)
+                assert paged.n == len(model[0])
+                for a, b in zip(paged.view(), model):
+                    assert a.dtype == b.dtype
                     np.testing.assert_array_equal(a, b)
             paged.release()
+        assert pool.pages_in_use == 0
 
     def test_view_cache_invalidated_across_drop_refill(self):
         """Regression (review finding): (n, head) is not unique over a
@@ -258,25 +259,43 @@ class TestRaggedSealEncode:
         rng = np.random.default_rng(21)
         buf = ShardBuffer(2 * HOUR)
         sids = [b"s%03d" % i for i in range(40)]
+        written: dict[bytes, list] = {}
         for _ in range(600):
             i = int(rng.integers(0, 40))
             # skewed: one series gets most points (the padding-tax shape)
             if rng.random() < 0.5:
                 i = 0
-            buf.write(sids[i], START + int(rng.integers(0, 3600)) * NS,
-                      bits(float(rng.integers(0, 1000))))
+            t = START + int(rng.integers(0, 3600)) * NS
+            v = bits(float(rng.integers(0, 1000)))
+            buf.write(sids[i], t, v)
+            written.setdefault(sids[i], []).append((t, v))
         bs0 = START - START % (2 * HOUR)  # window the writes landed in
-        padded = buf.seal(bs0, drop=False)
         csr = buf.seal_csr(bs0, drop=False)
-        np.testing.assert_array_equal(padded.series_indices,
-                                      csr.series_indices)
-        np.testing.assert_array_equal(padded.n_points, csr.n_points)
-        s_pad = hostpath.encode_blocks(
-            padded.times, padded.value_bits, padded.starts,
-            padded.n_points, TimeUnit.SECOND, False)
+        # the seal against a per-series model of the writes: rows in
+        # series-index order, each merge_dedup of its appends
+        assert [buf.series_ids[k] for k in csr.series_indices] \
+            == sorted(written, key=buf.series_index)
+        B = csr.n_series
+        T = int(csr.n_points.max())
+        pad_t = np.zeros((B, T), np.int64)
+        pad_v = np.zeros((B, T), np.uint64)
+        for r, k in enumerate(csr.series_indices):
+            rows = written[buf.series_ids[k]]
+            et, ev = merge_dedup(np.array([x[0] for x in rows], np.int64),
+                                 np.array([x[1] for x in rows], np.uint64))
+            a, b = csr.offsets[r], csr.offsets[r + 1]
+            np.testing.assert_array_equal(csr.times[a:b], et)
+            np.testing.assert_array_equal(csr.value_bits[a:b], ev)
+            # the fully padded rectangle, tail repeating the last time
+            pad_t[r, :len(et)] = et
+            pad_t[r, len(et):] = et[-1]
+            pad_v[r, :len(ev)] = ev
+        starts = np.full(B, bs0, np.int64)
+        s_pad = hostpath.encode_blocks(pad_t, pad_v, starts, csr.n_points,
+                                       TimeUnit.SECOND, False)
         s_rag = hostpath.encode_blocks_ragged(
-            csr.times, csr.value_bits, csr.offsets,
-            np.full(csr.n_series, bs0, np.int64), TimeUnit.SECOND, False)
+            csr.times, csr.value_bits, csr.offsets, starts,
+            TimeUnit.SECOND, False)
         assert s_pad == s_rag
 
 
@@ -301,104 +320,91 @@ def _build_db(root, rng, n_series=64, n_blocks=3, with_flush=True):
                 db.write_tagged("default", ids[i], tags[i], t,
                                 float(rng.integers(0, 100)))
         if with_flush and b < n_blocks - 1:
+            # START is not block-aligned: a round of writes straddles two
+            # windows, so the next round lands partly on a flushed volume
+            # (buffer rows over fileset rows of the same window)
             for shard in ns.shards.values():
-                if shard.buffer.points_in(bs):
-                    shard.flush(bs)
+                for w in shard.buffer.block_starts():
+                    shard.flush(w)
     return db, ns, ids
 
 
 class TestPagedReadParity:
-    def test_read_many_exact_parity_paged_vs_seed(self, tmp_path,
-                                                  monkeypatch):
-        """The acceptance property: buffer+fileset reads are SAMPLE-exact
-        (uint64 bit patterns) between the paged ragged finalize and the
-        seed per-series path, pipelined and serial."""
+    def test_read_many_exact_parity_batched_vs_per_series(self, tmp_path,
+                                                          monkeypatch):
+        """The acceptance property: buffer+fileset reads through the
+        batched ragged finalize, pipelined and serial, are SAMPLE-exact
+        (uint64 bit patterns) against the per-series read
+        (Namespace.read -> Shard.read: concatenate + merge_dedup)."""
         rng = np.random.default_rng(31)
-        results = {}
-        for paged in ("1", "0"):
-            monkeypatch.setenv("M3_TPU_PAGED", paged)
-            r2 = np.random.default_rng(31)  # identical data both sides
-            db, ns, ids = _build_db(str(tmp_path / f"p{paged}"), r2)
-            for pipe in ("1", "0"):
-                monkeypatch.setenv("M3_TPU_PIPELINE", pipe)
-                lo = START + int(rng.integers(0, 30)) * 60 * NS
-                hi = START + 3 * HOUR - int(rng.integers(0, 30)) * 60 * NS
-                got = ns.read_many(ids, lo, hi)
-                results[(paged, pipe, lo, hi)] = got
-            db.close()
-        for (paged, pipe, lo, hi), got in list(results.items()):
-            if paged != "1":
-                continue
-            # same (lo, hi) never repeats across rng draws, so compare
-            # each paged run against a fresh seed read of the same range
-            monkeypatch.setenv("M3_TPU_PAGED", "0")
+        db, ns, _names = _build_db(str(tmp_path / "p"),
+                                   np.random.default_rng(31))
+        ids = sorted(ns.series_ids())
+        assert any(sh._filesets and sh.buffer.block_starts()
+                   for sh in ns.shards.values())
+        n_samples = 0
+        for pipe in ("1", "0", "1", "0"):
             monkeypatch.setenv("M3_TPU_PIPELINE", pipe)
-            r2 = np.random.default_rng(31)
-            db, ns, ids = _build_db(str(tmp_path / f"chk{pipe}"), r2)
-            want = ns.read_many(ids, lo, hi)
-            for (gt, gv), (wt, wv) in zip(got, want):
+            lo = START + int(rng.integers(0, 30)) * 60 * NS
+            hi = START + 3 * HOUR - int(rng.integers(0, 30)) * 60 * NS
+            got = ns.read_many(ids, lo, hi)
+            assert len(got) == len(ids)
+            for sid, (gt, gv) in zip(ids, got):
+                wt, wv = ns.read(sid, lo, hi)
                 np.testing.assert_array_equal(gt, wt)
                 np.testing.assert_array_equal(gv, wv)
-            db.close()
+                n_samples += len(wt)
+        assert n_samples > 0
+        db.close()
 
     def test_read_many_ragged_matches_views(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("M3_TPU_PAGED", "1")
         monkeypatch.setenv("M3_TPU_PIPELINE", "1")
         rng = np.random.default_rng(41)
-        db, ns, ids = _build_db(str(tmp_path / "r"), rng)
+        db, ns, _names = _build_db(str(tmp_path / "r"), rng)
+        ids = sorted(ns.series_ids())
         pairs = ns.read_many(ids, START, START + 3 * HOUR)
         t, v, offs = ns.read_many_ragged(ids, START, START + 3 * HOUR)
-        assert len(offs) == len(ids) + 1
+        assert len(offs) == len(ids) + 1 and offs[-1] > 0
         for i, (pt, pv) in enumerate(pairs):
             a, b = offs[i], offs[i + 1]
             np.testing.assert_array_equal(t[a:b], pt)
             np.testing.assert_array_equal(v[a:b], pv)
         db.close()
 
-    def test_engine_parity_paged_vs_seed(self, tmp_path, monkeypatch):
-        """Ragged decode/aggregate parity through the ENGINE: compiled
-        and interpreted results agree between M3_TPU_PAGED=1 and =0 to
-        exact NaN masks + 1e-9 values (the bench correctness gate)."""
+    def test_engine_parity_compiled_vs_interpreted(self, tmp_path,
+                                                   monkeypatch):
+        """Ragged decode/aggregate parity through the ENGINE: the
+        compiled plans (which consume the ragged CSR) and the float64
+        interpreter agree to exact NaN masks + 1e-9 values."""
         queries = [
             "m",
             "sum by (host) (sum_over_time(m[30m]))",
             "rate(m[10m])",
             "max_over_time(m[20m])",
         ]
+        db, ns, ids = _build_db(str(tmp_path / "e"),
+                                np.random.default_rng(55))
+        eng = Engine(db, resolve_tiers=False)
         out = {}
-        for paged in ("1", "0"):
-            monkeypatch.setenv("M3_TPU_PAGED", paged)
-            rng = np.random.default_rng(55)
-            db, ns, ids = _build_db(str(tmp_path / f"e{paged}"), rng)
-            eng = Engine(db, resolve_tiers=False)
-            for compile_ in ("0", "1"):
-                monkeypatch.setenv("M3_TPU_QUERY_COMPILE", compile_)
-                for q in queries:
+        for compile_ in ("0", "1"):
+            monkeypatch.setenv("M3_TPU_QUERY_COMPILE", compile_)
+            for q in queries:
+                with explain.collect(True) as col:
                     vec, _ = eng.query_range(
                         q, START + 30 * 60 * NS, START + 3 * HOUR,
                         10 * 60 * NS)
-                    out[(paged, compile_, q)] = vec
-            db.close()
-        for compile_ in ("0", "1"):
-            for q in queries:
-                a = out[("1", compile_, q)]
-                b = out[("0", compile_, q)]
-                assert a.labels == b.labels, q
-                assert np.array_equal(np.isnan(a.values),
-                                      np.isnan(b.values)), q
-                assert np.allclose(a.values, b.values, rtol=1e-9, atol=0,
-                                   equal_nan=True), q
-
-    def test_hatch_pins_seed_buffer_bodies(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("M3_TPU_PAGED", "0")
-        buf = ShardBuffer(HOUR)
-        buf.write(b"a", START + NS, bits(1.0))
-        assert type(next(iter(buf._logs.values()))) is _ColumnLog
-        monkeypatch.setenv("M3_TPU_PAGED", "1")
-        buf2 = ShardBuffer(HOUR)
-        buf2.write(b"a", START + NS, bits(1.0))
-        assert type(next(iter(buf2._logs.values()))) \
-            is pagepool.PagedColumnLog
+                out[(compile_, q)] = vec, bool(col.compiled
+                                               and col.compiled["ran"])
+        db.close()
+        for q in queries:
+            (a, a_compiled), (b, b_compiled) = out[("1", q)], out[("0", q)]
+            assert not b_compiled, q
+            assert a.labels == b.labels, q
+            assert np.array_equal(np.isnan(a.values),
+                                  np.isnan(b.values)), q
+            assert np.allclose(a.values, b.values, rtol=1e-9, atol=0,
+                               equal_nan=True), q
+        assert any(out[("1", q)][1] for q in queries)
 
 
 @pytest.fixture
@@ -411,7 +417,6 @@ def small_tier(monkeypatch):
 
 class TestHotTier:
     def _db(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("M3_TPU_PAGED", "1")
         monkeypatch.setenv("M3_TPU_QUERY_COMPILE", "1")
         rng = np.random.default_rng(77)
         return _build_db(str(tmp_path / "h"), rng)
@@ -505,8 +510,7 @@ class TestHotTier:
 
 
 class TestFetchKey:
-    def test_fetch_key_tracks_data_version(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("M3_TPU_PAGED", "1")
+    def test_fetch_key_tracks_data_version(self, tmp_path):
         rng = np.random.default_rng(13)
         db, ns, ids = _build_db(str(tmp_path / "fk"), rng)
         eng = Engine(db, resolve_tiers=False)

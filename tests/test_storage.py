@@ -64,16 +64,49 @@ class TestShardBuffer:
         buf.write(b"b", START + 1 * 10**9, bits(1.0))
         buf.write(b"a", START + 1 * 10**9, bits(0.5))
         buf.write(b"a", START + 2 * 10**9, bits(3.0))  # dup of first
-        sealed = buf.seal(START)
+        sealed = buf.seal_csr(START)
         assert sealed.n_series == 2
         a = list(sealed.series_indices).index(buf.series_index(b"a"))
         assert sealed.n_points[a] == 2
+        lo, hi = sealed.offsets[a], sealed.offsets[a + 1]
         np.testing.assert_array_equal(
-            sealed.times[a, :2], [START + 10**9, START + 2 * 10**9]
+            sealed.times[lo:hi], [START + 10**9, START + 2 * 10**9]
         )
-        assert sealed.value_bits[a, 1] == bits(3.0)
+        assert sealed.value_bits[hi - 1] == bits(3.0)
         # sealed window is gone from the buffer
         assert buf.points_in(START) == 0
+
+    def test_drop_window_prefix_keeps_suffix_bit_exact(self):
+        """A flush drops exactly the rows its seal covered: rows appended
+        after the seal (here across a page boundary) stay readable bit
+        for bit, and seal again as the whole window."""
+        from m3_tpu.storage.pagepool import PAGE_ROWS
+
+        buf = ShardBuffer(2 * HOUR)
+        rng = np.random.default_rng(3)
+        n = PAGE_ROWS + 300
+        t = START + np.arange(n, dtype=np.int64) * 10**6
+        v = rng.integers(0, 2**63, n).astype(np.uint64)
+        ids = [b"a" if i % 3 else b"b" for i in range(n)]
+        covered = PAGE_ROWS - 100
+        buf.write_many(ids[:covered], t[:covered], v[:covered],
+                       [b""] * covered)
+        sealed = buf.seal_csr(START, drop=False)
+        buf.write_many(ids[covered:], t[covered:], v[covered:],
+                       [b""] * (n - covered))
+        buf.drop_window_prefix(START, sealed.raw_count)
+        assert buf.points_in(START) == n - covered
+        for sid in (b"a", b"b"):
+            keep = np.array([x == sid for x in ids[covered:]])
+            got_t, got_v = buf.read(sid, START, START + 2 * HOUR)
+            np.testing.assert_array_equal(got_t, t[covered:][keep])
+            np.testing.assert_array_equal(got_v, v[covered:][keep])
+        rest = buf.seal_csr(START)
+        assert rest.raw_count == n - covered == len(rest.times)
+        # a prefix that covers everything drops the window
+        buf.write(b"a", START + 1, bits(1.0))
+        buf.drop_window_prefix(START, 5)
+        assert buf.block_starts() == []
 
     def test_multiple_block_windows(self):
         buf = ShardBuffer(2 * HOUR)
