@@ -73,9 +73,11 @@ from __future__ import annotations
 import contextlib
 import functools
 import os
+import sys
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import numpy as np
 
@@ -124,6 +126,11 @@ class PlanSpec:
         return (self.base, tuple(
             (st[0], st[1], st[2]) if st[0] == "bin" else (st[0], st[1])
             for st in self.stages))
+
+    @property
+    def agg(self):
+        """The chain's one aggregation stage, or None."""
+        return next((st for st in self.stages if st[0] == "agg"), None)
 
     @property
     def sig_str(self) -> str:
@@ -539,9 +546,54 @@ def try_execute(engine, expr: Expr, eval_ts: np.ndarray):
     return _run_plan(engine, spec, eval_ts, col)
 
 
+class _TierProbe(NamedTuple):
+    """One plan's hot-tier lookup, made BEFORE its fetch."""
+    shifted: np.ndarray  # the selector's evaluation grid (@ and offset)
+    precision: str  # "f64" | "bf16" (the negotiated mirror): in the key
+    hkey: tuple | None  # None: no tier, or a facade without version truth
+    entry: dict | None  # the warm prepared entry; None on a miss
+
+
+def _probe_tier(engine, spec: PlanSpec, eval_ts, fetch_key) -> _TierProbe:
+    """Look the plan up in the device-resident hot tier (ROADMAP #3).
+    The prepared slab set is fully determined by (fetch content version,
+    base, grid, grouping, precision, requested device count), and all of
+    it is known before storage is read: `fetch_key` is the engine's
+    version key (taken before the read by design), the rest is the
+    plan's. One `tier.get` a plan."""
+    import zlib
+
+    from m3_tpu.parallel import mesh as mesh_mod
+    from m3_tpu.storage import hottier
+
+    shifted = engine._resolve_ts(spec.selector, eval_ts)
+    precision = "f64"
+    if hottier.query_precision() == "bf16" and spec.base in _BF16_OK_BASES:
+        precision = "bf16"
+    tier = hottier.default()
+    if tier is None or fetch_key is None:
+        return _TierProbe(shifted, precision, None, None)
+    mesh_req = mesh_mod.active_compute_mesh()
+    n_dev_req = int(mesh_req.devices.size) if mesh_req is not None else 1
+    bounds_range = spec.range_ns if spec.base != "instant" \
+        else engine.lookback_ns
+    agg = spec.agg
+    agg_key = (agg[2], agg[3]) if agg is not None else None
+    grid_fp = (len(eval_ts), zlib.adler32(shifted.tobytes()))
+    hkey = (fetch_key, spec.base, int(bounds_range), grid_fp,
+            agg_key, precision, n_dev_req)
+    return _TierProbe(shifted, precision, hkey, tier.get(hkey))
+
+
 def _run_plan(engine, spec: PlanSpec, eval_ts, col):
-    """Fetch + fused execution of ONE covered chain (shared by single-
-    plan queries and each side of a compiled vector-vector binop)."""
+    """Probe + fetch + fused execution of ONE covered chain (shared by
+    single-plan queries and each side of a compiled vector-vector
+    binop). The hot tier is probed first: a warm entry holds everything
+    `_execute` takes from a fetch, so a hit reads nothing — no index
+    match, no `read_many` — and charges the query limits with what the
+    fetch that prepared the entry was charged."""
+    from m3_tpu.utils.instrument import default_registry
+
     with contextlib.ExitStack() as stack:
         if col is not None:
             for node in spec.nodes[:-1]:
@@ -550,9 +602,27 @@ def _run_plan(engine, spec: PlanSpec, eval_ts, col):
         # lands exactly where the interpreter's plan tree puts it
         with col.node(spec.nodes[-1]) if col is not None \
                 else contextlib.nullcontext():
-            labels, raws = engine._fetch(spec.selector, eval_ts,
-                                         spec.range_ns)
-        out = _execute(engine, spec, labels, raws, eval_ts, col)
+            resolved = engine._resolve_fetch(spec.selector, eval_ts,
+                                             spec.range_ns)
+            probe = _probe_tier(engine, spec, eval_ts, resolved[-1])
+            limits = engine._active_limits()
+            if probe.entry is None:
+                series0, datapoints0 = limits.charged()
+                labels, raws = engine._fetch_resolved(spec.selector,
+                                                      resolved)
+                series1, datapoints1 = limits.charged()
+                charged = (series1 - series0, datapoints1 - datapoints0)
+            else:
+                labels = raws = None
+                charged = probe.entry["charged"]
+                # a repeat over a configured limit is refused as the
+                # first run was: same counts, same order
+                limits.add_series(charged[0])
+                limits.add_datapoints(charged[1])
+                default_registry().root_scope("storage").subscope(
+                    "hot_tier").counter("fetch_skipped")
+        out = _execute(engine, spec, labels, raws, charged, eval_ts, col,
+                       probe)
     return out
 
 
@@ -695,14 +765,18 @@ def _pad_eval_ts(eval_ts: np.ndarray) -> np.ndarray:
 _BF16_OK_BASES = {"instant", "min_over_time", "max_over_time"}
 
 
-def _prepare_slabs(engine, spec: PlanSpec, labels, raws, shifted,
+def _prepare_slabs(engine, spec: PlanSpec, labels, raws, charged, shifted,
                    T: int, S: int, agg, precision: str) -> dict:
     """Host prep for one covered plan: window bounds, per-device slab
     fill, grouping — everything about the call that is determined by
     (fetch content, plan base, grid) and therefore cacheable in the
     device-resident hot tier.  Returns the prepared-entry dict; arrays
     are committed to device (ordinary host buffers on CPU backends) so
-    a warm entry re-runs the program with zero host->device transfer."""
+    a warm entry re-runs the program with zero host->device transfer.
+    The entry also keeps, on the host, what `_execute` takes from the
+    fetch itself (series and sample counts, the series' labels where no
+    aggregation replaces them, what the query limits were `charged`),
+    so that a warm entry is served without the fetch (`_run_plan`)."""
     from m3_tpu.ops import temporal
     from m3_tpu.parallel import mesh as mesh_mod
     from m3_tpu.query import windows
@@ -837,8 +911,14 @@ def _prepare_slabs(engine, spec: PlanSpec, labels, raws, shifted,
     if not adjs_is_vs:
         arrays.append(adjs)
     nbytes = sum(int(getattr(a, "nbytes", 0)) for a in arrays)
+    if agg is None:
+        # the host bytes a warm entry holds beside its slabs: the label
+        # dicts (their keys and values are the index documents' own)
+        nbytes += sys.getsizeof(labels) + sum(map(sys.getsizeof, labels))
     return {"mesh": mesh, "n_dev": n_dev, "Sp": Sp, "Tp": Tp, "Gp": Gp,
             "G": G, "cap": cap, "mm_levels": mm_levels,
+            "S": S, "n_samples": n, "charged": charged,
+            "labels": labels if agg is None else None,
             "group_labels": group_labels, "adjs_is_vs": adjs_is_vs,
             "vs": vs, "adjs": adjs, "ts": ts, "csums": csums,
             "bmat": bmat, "lo_p": lo_p, "hi_p": hi_p,
@@ -846,18 +926,22 @@ def _prepare_slabs(engine, spec: PlanSpec, labels, raws, shifted,
             "precision": precision, "nbytes": nbytes}
 
 
-def _execute(engine, spec: PlanSpec, labels, raws, eval_ts, col):
-    import zlib
-
-    from m3_tpu.parallel import mesh as mesh_mod
+def _execute(engine, spec: PlanSpec, labels, raws, charged, eval_ts, col,
+             probe: _TierProbe):
+    """Run the fused program over the plan's prepared slabs: those of
+    the warm hot-tier entry `probe` found (labels, raws: None — nothing
+    was fetched; the entry holds what this function would take from
+    them), or those prepared here from the fetch, which then enter the
+    tier under `probe.hkey`."""
     from m3_tpu.query.engine import Vector, _compact
     from m3_tpu.storage import hottier
     from m3_tpu.utils import trace
     from m3_tpu.utils.instrument import default_registry
 
     T = len(eval_ts)
-    S = raws.n_series
-    agg = next((st for st in spec.stages if st[0] == "agg"), None)
+    agg = spec.agg
+    hkey, entry = probe.hkey, probe.entry
+    S = entry["S"] if entry is not None else raws.n_series
     if S == 0:
         # interpreter parity: an empty fetch compacts to an empty vector
         # at the base stage, and every covered stage preserves emptiness
@@ -867,28 +951,10 @@ def _execute(engine, spec: PlanSpec, labels, raws, eval_ts, col):
                               "cache": "hit"})
         return vec
 
-    shifted = engine._resolve_ts(spec.selector, eval_ts)
-
-    # device-resident hot tier probe (ROADMAP #3): the prepared slab set
-    # is fully determined by (fetch content version, base, grid,
-    # grouping, precision, requested device count) — a warm entry skips
-    # window bounds, slab fill AND the host->device transfer
-    mesh_req = mesh_mod.active_compute_mesh()
-    n_dev_req = int(mesh_req.devices.size) if mesh_req is not None else 1
+    # one lookup a plan: a warm entry skips the index match and the
+    # read (in _run_plan), window bounds, slab fill AND the host->device
+    # transfer
     tier = hottier.default()
-    precision = "f64"
-    if hottier.query_precision() == "bf16" and spec.base in _BF16_OK_BASES:
-        precision = "bf16"
-    bounds_range = spec.range_ns if spec.base != "instant" \
-        else engine.lookback_ns
-    hkey = None
-    entry = None
-    if tier is not None and getattr(raws, "fetch_key", None) is not None:
-        agg_key = (agg[2], agg[3]) if agg is not None else None
-        grid_fp = (T, zlib.adler32(shifted.tobytes()))
-        hkey = (raws.fetch_key, spec.base, int(bounds_range), grid_fp,
-                agg_key, precision, n_dev_req)
-        entry = tier.get(hkey)
     hot_state = None
     if hkey is not None:
         hot_state = "hit" if entry is not None else "miss"
@@ -896,13 +962,16 @@ def _execute(engine, spec: PlanSpec, labels, raws, eval_ts, col):
             "hot_tier").counter(hot_state)
     if entry is None:
         with trace.stage(trace.STAGE_SLAB_PREP):
-            entry = _prepare_slabs(engine, spec, labels, raws, shifted, T,
-                                   S, agg, precision)
+            entry = _prepare_slabs(engine, spec, labels, raws, charged,
+                                   probe.shifted, T, S, agg,
+                                   probe.precision)
         if hkey is not None:
             tier.put(hkey, entry, entry["nbytes"])
             default_registry().root_scope("storage").subscope(
                 "hot_tier").observe("hot_tier_entry_bytes",
                                     float(entry["nbytes"]))
+    else:
+        labels = entry["labels"]
 
     mesh = entry["mesh"]
     n_dev = entry["n_dev"]
@@ -976,7 +1045,7 @@ def _execute(engine, spec: PlanSpec, labels, raws, eval_ts, col):
     # too — the padded cells re-run every call, not just at prep)
     from m3_tpu.utils import compute_stats
 
-    n_samples = len(raws.values)
+    n_samples = entry["n_samples"]
     compute_stats.record_waste("query_slabs", "series", S, Sp)
     compute_stats.record_waste("query_slabs", "time", T, Tp)
     if agg is not None:

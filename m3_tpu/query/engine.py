@@ -247,7 +247,14 @@ class Engine:
         return eval_ts - sel.offset_ns
 
     def _fetch(self, sel: VectorSelector, eval_ts: np.ndarray, range_ns: int):
-        """(labels, RaggedSeries) for samples covering the windows.
+        """(labels, RaggedSeries) for samples covering the windows."""
+        return self._fetch_resolved(
+            sel, self._resolve_fetch(sel, eval_ts, range_ns))
+
+    def _resolve_fetch(self, sel: VectorSelector, eval_ts: np.ndarray,
+                       range_ns: int):
+        """(namespaces, t_min, t_max, fetch_key) of one selector fetch:
+        everything about it that is known before storage is read.
 
         Namespaces are chosen by tier resolution (query/resolver): a
         coarse-step read goes to the cheapest complete aggregated tier
@@ -257,10 +264,9 @@ class Engine:
         shifted = self._resolve_ts(sel, eval_ts)
         t_min = int(shifted[0]) - max(range_ns, self.lookback_ns)
         t_max = int(shifted[-1]) + 1
-        from m3_tpu.index.query import matchers_to_query
-        from m3_tpu.query import resolver
-
         if self.resolve_tiers:
+            from m3_tpu.query import resolver
+
             step_ns = int(eval_ts[1] - eval_ts[0]) if len(eval_ts) > 1 else 0
             ns_list, tier_info = resolver.resolve_read(
                 self.db, self.namespace, t_min, t_max, step_ns, range_ns,
@@ -268,13 +274,24 @@ class Engine:
             self._record_tier_choice(tier_info)
         else:
             ns_list = [self.namespace]
-        iq = matchers_to_query(sel.matchers)
-        warn_sink = getattr(self._warn_tls, "sink", None)
         # version key sampled BEFORE the read: a write racing the fetch
         # can then only make the key stale (harmless hot-tier miss) —
         # sampling after would cache pre-write data under the post-write
-        # version and serve it warm until the next bump
-        fetch_key = self._fetch_key(sel, ns_list, t_min, t_max)
+        # version and serve it warm until the next bump. The compiled
+        # path probes the hot tier with it between here and the read
+        # (compiler._run_plan), and on a hit reads nothing
+        return ns_list, t_min, t_max, self._fetch_key(sel, ns_list, t_min,
+                                                      t_max)
+
+    def _fetch_resolved(self, sel: VectorSelector, resolved):
+        """The storage read of a `_resolve_fetch` result: index match,
+        batched read, labels."""
+        from m3_tpu.index.query import matchers_to_query
+        from m3_tpu.query import resolver
+
+        ns_list, t_min, t_max, fetch_key = resolved
+        iq = matchers_to_query(sel.matchers)
+        warn_sink = getattr(self._warn_tls, "sink", None)
         ragged_res = resolver.fetch_tagged_ragged(
             self.db, ns_list, iq, t_min, t_max, warnings=warn_sink)
         if ragged_res is not None:

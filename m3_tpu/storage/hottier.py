@@ -1,14 +1,27 @@
 """Device-resident hot tier: a bounded, paged cache of PREPARED query
 slabs pinned in device memory (ROADMAP #3).
 
-The whole-query compiler's host prep (window bounds, per-device slab
-fill, prefix sums) plus the host->device transfer of those slabs is
-what a REPEATED dashboard query pays after the block cache has already
+The index match, the batched read and its CSR assembly, the
+whole-query compiler's host prep (window bounds, per-device slab fill,
+prefix sums) plus the host->device transfer of those slabs are what a
+REPEATED dashboard query pays after the block cache has already
 amortized the decode.  This tier keys the prepared slab set on the
 fetch's content identity — (namespace data versions, selector, time
-range, eval grid, plan base, precision) — so an unchanged repeat skips
-`window_bounds_batch`, `_slab_cuts`/`_fill_slabs` and the transfer
-entirely: the compiled program re-runs against warm device buffers.
+range, eval grid, plan base, precision) — all of it known BEFORE
+storage is read, so the compiler probes the tier first
+(`compiler._run_plan`) and an unchanged repeat skips the index match
+(`query_ids`), the read (`read_many`), `window_bounds_batch`,
+`_slab_cuts`/`_fill_slabs` and the transfer entirely: the compiled
+program re-runs against warm device buffers.  For that an entry keeps
+on the host, beside its device slabs, the little the compiler took
+from the fetch itself: the series and sample counts (padding ledger),
+the group labels of an aggregating plan or the series' label dicts of
+one without (counted into the entry's `nbytes`), and the series and
+datapoint counts the query limits were charged, which a hit charges
+again — a repeat over a limit is refused as its first run was.  Any
+write, flush, repair or expiry bumps the namespace's data version, so
+a warm entry is never served over changed data; the version is sampled
+before the read, so a racing write can only make an entry stale.
 
 On CPU backends the "device" is jax's host platform and the tier is an
 ordinary arena of committed buffers; on a TPU the same code pins the
@@ -22,8 +35,9 @@ a quantized entry.
 
 Saturation plane: byte occupancy/entries/evictions ride the
 ``queue_*{queue=hot_tier}`` gauges (PR-11 snapshot-hook seam, m3lint
-``inv-pagepool-gauge``); per-query hit/miss counters land under
-``storage.hot_tier`` and the ``hot_tier`` block on ``?explain=analyze``.
+``inv-pagepool-gauge``); per-plan ``hit``/``miss`` counters (one lookup
+a plan) and ``fetch_skipped`` (plans served without a fetch) land under
+``storage.hot_tier``, and the ``hot_tier`` block on ``?explain=analyze``.
 ``M3_TPU_HOT_TIER_MB=0`` disables the tier.
 """
 

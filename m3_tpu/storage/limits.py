@@ -43,6 +43,15 @@ class QueryLimits:
                 f"query spans {n_steps} steps, limit {self.max_steps}"
             )
 
+    def charged(self) -> tuple[int, int]:
+        """(series, datapoints) this thread's active query has been
+        charged so far. The compiled query path takes the difference
+        around a fetch, keeps it with the hot-tier entry the fetch
+        prepares, and charges it again when the entry serves a repeat
+        without reading (query/compiler.py _run_plan)."""
+        return (getattr(self._tl, "series", 0),
+                getattr(self._tl, "datapoints", 0))
+
     def add_series(self, n_series: int) -> None:
         # only count inside an active start_query..end_query scope: reads
         # from background work (repair, flush, direct library calls) are not

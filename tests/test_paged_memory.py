@@ -495,6 +495,171 @@ class TestHotTier:
         assert col.compiled["hot_tier"]["precision"] == "f64"
         db.close()
 
+    # query -> fused plans it runs (a vector-vector binop runs one a side)
+    _REPEATS = {
+        "aggregated": ("sum by (host) (sum_over_time(m[30m]))", 1),
+        "no_aggregation": ("max_over_time(m[30m])", 1),
+        "vector_binop": ("sum_over_time(m[30m]) / count_over_time(m[30m])",
+                         2),
+    }
+
+    @staticmethod
+    def _count_fetches(monkeypatch):
+        """Counts of the namespace's index matches and batched reads."""
+        from m3_tpu.storage.namespace import Namespace
+
+        calls = {"query_ids": 0, "read_many": 0}
+
+        def counting(name, counted_as):
+            inner = getattr(Namespace, name)
+
+            def wrapper(self, *a, **kw):
+                calls[counted_as] += 1
+                return inner(self, *a, **kw)
+            monkeypatch.setattr(Namespace, name, wrapper)
+
+        counting("query_ids", "query_ids")
+        counting("read_many", "read_many")
+        counting("read_many_ragged", "read_many")
+        return calls
+
+    @staticmethod
+    def _tier_counters():
+        from m3_tpu.utils.instrument import default_registry
+
+        counters, _g, _t, hists = default_registry().snapshot()
+        out = {k: counters.get((f"storage.hot_tier.{k}", ()), 0.0)
+               for k in ("hit", "miss", "fetch_skipped")}
+        out["reads_timed"] = hists.get(("db.read_many_seconds", ()),
+                                       (None, None, 0.0, 0))[3]
+        return out
+
+    @pytest.mark.parametrize("case", sorted(_REPEATS))
+    def test_warm_repeat_reads_nothing(self, case, tmp_path, monkeypatch,
+                                       small_tier):
+        """Probe before fetch: an identical repeat is served from the
+        warm entry with no index match and no read, bit for bit what
+        the first run and the interpreter answer; a write and a flush
+        each make the next run a miss that fetches; `fetch_skipped`
+        counts exactly the hits."""
+        q, plans = self._REPEATS[case]
+        db, ns, ids = self._db(tmp_path, monkeypatch)
+        eng = Engine(db, resolve_tiers=False)
+        calls = self._count_fetches(monkeypatch)
+        at_start = self._tier_counters()
+
+        def run():
+            before = dict(calls), self._tier_counters()
+            with explain.collect(True) as col:
+                vec, _ = eng.query_range(q, START + 30 * 60 * NS,
+                                         START + 3 * HOUR, 10 * 60 * NS)
+            after = self._tier_counters()
+            delta = {k: calls[k] - before[0][k] for k in calls}
+            delta.update({k: after[k] - before[1][k] for k in after})
+            sides = (col.compiled or {}).get("sides") or [col.compiled]
+            return vec, delta, [side["hot_tier"]["hit"]
+                                for side in sides if side]
+
+        fetched = {"query_ids": plans, "read_many": plans,
+                   "reads_timed": plans, "hit": 0, "miss": plans,
+                   "fetch_skipped": 0}
+        skipped = {"query_ids": 0, "read_many": 0, "reads_timed": 0,
+                   "hit": plans, "miss": 0, "fetch_skipped": plans}
+
+        def same_bits(a, b):
+            assert a.labels == b.labels
+            assert a.values.shape == b.values.shape
+            np.testing.assert_array_equal(a.values.view(np.uint64),
+                                          b.values.view(np.uint64))
+
+        v1, d1, hits1 = run()
+        assert d1 == fetched and hits1 == [False] * plans
+        assert len(v1.labels) > 0 and np.isfinite(v1.values).any()
+        v2, d2, hits2 = run()
+        assert d2 == skipped and hits2 == [True] * plans
+        same_bits(v2, v1)
+        monkeypatch.setenv("M3_TPU_QUERY_COMPILE", "0")
+        vi, di, interpreted = run()
+        assert di["query_ids"] == plans and di["hit"] == di["miss"] == 0
+        assert interpreted == []
+        same_bits(v2, vi)
+        monkeypatch.setenv("M3_TPU_QUERY_COMPILE", "1")
+
+        # any write bumps the namespace's data version: the next run
+        # misses, fetches and answers with the new sample
+        db.write_tagged("default", ids[0],
+                        [(b"__name__", b"m"), (b"host", b"h00"),
+                         (b"i", b"000")], START + 2 * HOUR + NS, 5.0)
+        v3, d3, hits3 = run()
+        assert d3 == fetched and hits3 == [False] * plans
+        assert not np.array_equal(v3.values.view(np.uint64),
+                                  v1.values.view(np.uint64))
+        v4, d4, _ = run()
+        assert d4 == skipped
+        same_bits(v4, v3)
+        # so does a flush, which leaves every answer as it was
+        version = ns.data_version()
+        assert sum(bool(shard.flush(w)) for shard in ns.shards.values()
+                   for w in shard.buffer.block_starts()) > 0
+        assert ns.data_version() != version
+        v5, d5, hits5 = run()
+        assert d5 == fetched and hits5 == [False] * plans
+        same_bits(v5, v3)
+        _v6, d6, _ = run()
+        assert d6 == skipped
+
+        total = self._tier_counters()
+        assert total["fetch_skipped"] - at_start["fetch_skipped"] \
+            == total["hit"] - at_start["hit"] == 3 * plans
+        db.close()
+
+    def test_query_limits_refuse_a_warm_repeat(self, tmp_path, monkeypatch,
+                                               small_tier):
+        """A hit charges the query limits with what the fetch that
+        prepared its entry was charged: a repeat over a limit is refused
+        without a read, exactly as a first run is."""
+        from m3_tpu.storage.limits import QueryLimitError, QueryLimits
+
+        db, ns, ids = self._db(tmp_path, monkeypatch)
+        limits = QueryLimits()
+        eng = Engine(db, limits=limits, resolve_tiers=False)
+        calls = self._count_fetches(monkeypatch)
+        q = "max_over_time(m[30m])"
+
+        def run():
+            return eng.query_range(q, START + 30 * 60 * NS,
+                                   START + 3 * HOUR, 10 * 60 * NS)[0]
+
+        v1 = run()
+        series, datapoints = limits.charged()
+        assert series == 64 and datapoints > series
+        assert calls == {"query_ids": 1, "read_many": 1}
+        run()
+        assert limits.charged() == (series, datapoints)  # the hit's charge
+        assert calls == {"query_ids": 1, "read_many": 1}
+
+        for name, charged in (("max_datapoints", datapoints),
+                              ("max_series", series)):
+            setattr(limits, name, charged - 1)
+            with pytest.raises(QueryLimitError) as warm:
+                run()
+            assert calls == {"query_ids": 1, "read_many": 1}  # no read
+            hottier.default().clear()
+            with pytest.raises(QueryLimitError) as cold:
+                run()
+            assert calls["query_ids"] == 2      # the first run's refusal
+            assert str(warm.value).split(",")[1] \
+                == str(cold.value).split(",")[1]
+            # at the limit itself both pass, and the entry is warm again
+            setattr(limits, name, charged)
+            np.testing.assert_array_equal(run().values, v1.values)
+            fetches = dict(calls)
+            np.testing.assert_array_equal(run().values, v1.values)
+            assert calls == fetches
+            setattr(limits, name, 0)
+            calls.update({"query_ids": 1, "read_many": 1})
+        db.close()
+
     def test_lru_stays_under_byte_cap(self):
         tier = hottier.HotTier(max_bytes=1000)
         for i in range(20):
