@@ -2,7 +2,7 @@
 
     python -m pytest benchmarks/tests -q
 
-The generator is seeded; every data file names things that exist; the
+The generator is seeded (what the data files name: test_manifest.py); the
 plain reference agrees with the program's own float64 interpreter; a
 ``--rehearse`` run of each cell ends in a well-formed last line; each
 cell's control comes out not correct; and a run with the timed path
@@ -25,7 +25,6 @@ sys.path.insert(0, REPO)
 sys.path.insert(0, BENCH)
 
 from harness import compare, reference, tsbs  # noqa: E402
-from harness.readers import READERS  # noqa: E402
 
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
 UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}")
@@ -246,65 +245,6 @@ class TestCompare:
             {"metric": {"hostname": "a"}, "values": r} for r in rows]}}
         bad, gap, n = compare.matrix_gap(served, labels, self.TS, ref)
         assert bad is None and gap == 0.0 and n == 6
-
-
-@pytest.mark.parametrize("path", _files("layer_metrics"),
-                         ids=os.path.basename)
-def test_layer_metric_files_name_what_exists(path):
-    spec = _load(path)
-    assert os.path.basename(path) == spec["name"] + ".json"
-    assert NAME.fullmatch(spec["name"]) and UNIT.fullmatch(spec["unit"])
-    assert spec["reader"] in READERS
-    assert spec["better"] in ("lower", "higher")
-    assert spec["source"] in ("device_trace", "program_span",
-                              "program_counter", "host_clock")
-    for cell in spec["cells"]:
-        assert cell in CELLS
-        kind = _load(os.path.join(BENCH, "workloads", cell + ".json"))
-        traffic = open(os.path.join(BENCH, "traffic",
-                                    kind["traffic"] + ".py")).read()
-        assert f'"{spec["moves"]}"' in traffic   # the kind reports it
-    entry = next((m for m in MANIFEST["per_layer"]
-                  if m["name"] == spec["name"]), None)
-    if any(cell in MEASURED for cell in spec["cells"]):
-        assert entry is not None
-        e2e = {m["name"]: m for m in MANIFEST["end_to_end"]}
-        assert entry["moves"] == spec["moves"] and spec["moves"] in e2e
-        assert entry["workloads"] == spec["cells"]
-        for cell in spec["cells"]:
-            assert cell in e2e[spec["moves"]].get("workloads", MEASURED)
-        assert (entry["unit"], entry["layer"], entry["source"],
-                entry["better"]) == (spec["unit"], spec["layer"],
-                                     spec["source"], spec["better"])
-    else:
-        assert entry is None    # no cell of the manifest reads it
-
-
-def test_manifest_names_files_that_exist():
-    assert MANIFEST["paths"] == ["benchmarks"]
-    for cfg in MANIFEST["configs"]:
-        doc = _load(os.path.join(REPO, cfg["file"]))
-        assert doc["name"] == cfg["name"] and doc["source"] == cfg["source"]
-        assert sorted(doc["reduced"]) == sorted(cfg["reduced"])
-        assert any(w["config"] == cfg["name"] for w in MANIFEST["workloads"])
-    for w in MANIFEST["workloads"]:
-        cell = _load(os.path.join(BENCH, "workloads", w["name"] + ".json"))
-        assert (cell["config"], cell["traffic"], cell["chips"]) == \
-            (w["config"], w["traffic"], w["chips"])
-    for path in _files("workloads"):
-        cell = _load(path)
-        assert os.path.basename(path) == cell["name"] + ".json"
-        assert os.path.exists(os.path.join(BENCH, "configs",
-                                           cell["config"] + ".json"))
-        assert os.path.exists(os.path.join(BENCH, "traffic",
-                                           cell["traffic"] + ".py"))
-        params = cell["traffic_params"]
-        for q in list(params.get("mix", {})) + [
-                q["type"] for q in params.get("prime", [])]:
-            assert os.path.exists(os.path.join(BENCH, "queries",
-                                               q + ".json"))
-    with open(os.path.join(BENCH, "harness", "peaks.json")) as f:
-        assert json.load(f)["TPU v5 lite"]["hbm_bytes_per_s"] == 819e9
 
 
 def _run(cell, *extra, launcher=None):

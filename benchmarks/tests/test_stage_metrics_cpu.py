@@ -42,14 +42,18 @@ def traced_line():
 def test_the_line_carries_the_new_metrics_beside_the_old(traced_line):
     per_layer = {m["name"]: m for m in MANIFEST["per_layer"]
                  if CELL in m["workloads"]}
-    assert len(per_layer) == 17
+    # how many: the manifest's own count (test_manifest.py holds it to the
+    # files), not a number written here (it read 17 while they grew to 20)
     off_device = {n for n, m in per_layer.items()
                   if m["source"] != "device_trace"}
     assert off_device >= set(STAGE_METRICS) | {"query_cpu_ms"}
     metrics = traced_line["metrics"]
-    assert set(metrics) == off_device
+    # the rehearsal's 240 series all fit the block cache: a window that
+    # launches no decoder has no streams a launch to report
+    assert off_device - {"decode_streams_per_launch"} <= set(metrics) \
+        <= off_device
     assert traced_line["correct"] is True and traced_line["failed"] == 0
-    for name in off_device:
+    for name in metrics:
         assert metrics[name]["unit"] == per_layer[name]["unit"]
         assert metrics[name]["value"] >= 0
 

@@ -13,8 +13,10 @@ caches and warms every type.
 Parameters (the cell's ``traffic_params``): ``workers``, ``mix``
 ({query type: weight}), ``prime`` ([{type, hosts}]: requests that set-up
 sends once each before the warm-up, compared like the window's),
-``warm_s`` and ``warm_rounds`` (the loop runs in rounds of ``warm_s``
-seconds until one compiles nothing, ``warm_rounds`` at the most),
+``warm_s``, ``warm_clean_rounds`` and ``warm_rounds`` (the loop runs in
+rounds of ``warm_s`` seconds until ``warm_clean_rounds`` rounds in a row,
+1 where the cell does not say, compiled nothing, ``warm_rounds`` at the
+most),
 ``verify_max``, ``verify_streams``, and the two program
 counters the traced interval's least bytes are reckoned from:
 ``plan_launch_counter`` (a regex over ``/metrics`` keys whose one group is
@@ -38,6 +40,12 @@ from harness.client import BenchFailure, Client, check_log
 from harness.loadgen import FAILED_MS, ClosedLoop, percentile
 
 NS = tsbs.NS
+
+
+def warm_done(misses: list[int], want_clean: int) -> bool:
+    """The warm-up's rule: its last `want_clean` rounds compiled nothing
+    (`misses`: the compiles of each round so far)."""
+    return len(misses) >= want_clean and not any(misses[-want_clean:])
 
 
 class Requests:
@@ -174,6 +182,7 @@ class Traffic:
         # warm every type, one request at a time, and learn from the
         # program's launch counter which plan signature serves it; then
         # the loop itself: the block cache and the connections
+        t0 = time.perf_counter()
         rx = re.compile(p["plan_launch_counter"])
         n_warm = 0
         for name in p["mix"]:
@@ -189,12 +198,22 @@ class Traffic:
                     if self.sig_type.setdefault(m.group(1), name) != name:
                         self.sig_type[m.group(1)] = ""   # serves two types
         run.say("plan signatures: " + json.dumps(self.sig_type))
-        # rounds of the loop itself, until one meets no shape that its
-        # process had not compiled yet (the decoder's row bucket follows
-        # from what the block cache has dropped, so only traffic finds
-        # them), `warm_rounds` at the most
-        n_burst = rounds = 0
-        while True:
+        self.warm_rounds()
+        run.say(f"warm-up: {n_warm} single requests and the rounds in "
+                f"{time.perf_counter() - t0:.1f}s")
+
+    def warm_rounds(self) -> list[int]:
+        """Rounds of the loop itself, `warm_s` seconds each, until
+        `warm_clean_rounds` of them in a row (1 where the cell does not
+        say) met no shape that the process had not compiled yet (the
+        decoder's row bucket follows from what the block cache has
+        dropped, so only traffic finds them), `warm_rounds` at the most.
+        Says and returns the compiles of each round."""
+        run, p = self.run, self.run.params
+        want_clean, most = int(p.get("warm_clean_rounds", 1)), \
+            int(p["warm_rounds"])
+        n_burst, misses = 0, []
+        while len(misses) < most and not warm_done(misses, want_clean):
             before = run.metrics()
             burst = ClosedLoop(
                 run.port, self.workers,
@@ -207,14 +226,16 @@ class Traffic:
                 raise BenchFailure(
                     f"warm-up query failed: {bad[0][5][:300]!r}")
             n_burst += len(burst.records)
-            rounds += 1
             after = run.metrics()
-            if rounds >= int(p["warm_rounds"]) or not any(
-                    k.endswith("[miss]") and n > before.get(k, 0.0)
-                    for k, n in after.items()):
-                break
-        run.say(f"warm-up: {n_warm} + {n_burst} queries ({rounds} rounds) "
-                f"in {time.perf_counter() - t0:.1f}s")
+            misses.append(int(sum(
+                n - before.get(k, 0.0) for k, n in after.items()
+                if k.endswith("[miss]") and n > before.get(k, 0.0))))
+        ended = (f"{want_clean} clean in a row"
+                 if warm_done(misses, want_clean)
+                 else f"the limit of {most} rounds")
+        run.say(f"warm-up rounds: {n_burst} queries in {len(misses)} rounds, "
+                f"compiles a round {misses}, ended by {ended}")
+        return misses
 
     # -- the window -----------------------------------------------------------
 
