@@ -78,14 +78,19 @@ def encode_blocks(times, vbits, starts, n_points,
     # per-program execute histogram on the batch rectangle
     sig = f"B{times.shape[0]}xT{times.shape[1]}" + \
         ("|int" if int_optimized else "")
-    with dispatch.jit_tracker("m3tsz_encode", jitted, sig=sig):
-        blocks = encode_fn(
-            jnp.asarray(times), jnp.asarray(vbits),
-            jnp.asarray(starts), jnp.asarray(n_points), unit,
-        )
-    if bool(blocks.overflow):
-        raise OverflowError("batched encode overflow")
-    return m3tsz_tpu.blocks_to_bytes(blocks, n_rows)
+    from m3_tpu.utils import trace
+
+    # one stage around the encoder call AND the transfers that wait for
+    # it (the overflow flag, then words and bit lengths), as the decoder's
+    with trace.stage(trace.STAGE_ENCODE_WAIT):
+        with dispatch.jit_tracker("m3tsz_encode", jitted, sig=sig):
+            blocks = encode_fn(
+                jnp.asarray(times), jnp.asarray(vbits),
+                jnp.asarray(starts), jnp.asarray(n_points), unit,
+            )
+        if bool(blocks.overflow):
+            raise OverflowError("batched encode overflow")
+        return m3tsz_tpu.blocks_to_bytes(blocks, n_rows)
 
 
 def _pad_encode_batch(times, vbits, starts, n_points):

@@ -7,6 +7,7 @@ native path is an accelerator, never a requirement.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import os
 import subprocess
@@ -32,13 +33,20 @@ _tried = False
 
 
 def _build() -> bool:
+    # to a name of this process's own beside _SO, then renamed onto it:
+    # another process (an xdist worker on a fresh checkout) never loads
+    # a half-written library
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", _SO, _SRC],
+            ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120,
         )
+        os.replace(tmp, _SO)
         return True
     except (OSError, subprocess.SubprocessError):
+        with contextlib.suppress(OSError):
+            os.unlink(tmp)
         return False
 
 

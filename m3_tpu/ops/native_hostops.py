@@ -9,6 +9,7 @@ back to the numpy host path when no compiler is available.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import os
 import subprocess
@@ -35,14 +36,20 @@ _I32 = ctypes.c_int32
 
 
 def _build() -> bool:
+    # to a name of this process's own, then renamed onto _SO, as in
+    # encoding/m3tsz/native.py
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-             "-o", _SO, _SRC],
+             "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120,
         )
+        os.replace(tmp, _SO)
         return True
     except (OSError, subprocess.SubprocessError):
+        with contextlib.suppress(OSError):
+            os.unlink(tmp)
         return False
 
 

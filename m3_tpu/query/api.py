@@ -662,19 +662,22 @@ class CoordinatorAPI:
     # -- ingest --
 
     def _remote_write(self, body: bytes):
-        payload = snappy.decompress(body)
-        series = protowire.decode_write_request(payload)
-        entries = []
-        for ts in series:
-            name = b""
-            tags = []
-            for k, v in ts.labels:
-                if k == b"__name__":
-                    name = v
-                else:
-                    tags.append((k, v))
-            for ts_ms, value in ts.samples:
-                entries.append((name, tags, ts_ms * 1_000_000, value))
+        from m3_tpu.utils import trace
+
+        with trace.stage(trace.STAGE_WRITE_DECODE):
+            payload = snappy.decompress(body)
+            series = protowire.decode_write_request(payload)
+            entries = []
+            for ts in series:
+                name = b""
+                tags = []
+                for k, v in ts.labels:
+                    if k == b"__name__":
+                        name = v
+                    else:
+                        tags.append((k, v))
+                for ts_ms, value in ts.samples:
+                    entries.append((name, tags, ts_ms * 1_000_000, value))
         self._admit_write(len(entries))
         batch = getattr(self.db, "write_batch", None)
         if batch is not None and (

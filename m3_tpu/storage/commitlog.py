@@ -108,6 +108,10 @@ class CommitLogWriter:
         # would be a silent-loss lie. Callers that survive the error (a
         # request handler swallowing it) must rotate to a fresh log.
         self._failed: Exception | None = None
+        # block windows this FILE holds entries of: added to with the
+        # append, under the lock, and handed over whole by rotate(), so a
+        # retired log is never missing a window it has a datapoint of
+        self.windows: set[int] = set()
         # saturation plane: acked bytes sitting in the user-space buffer
         # (lost on SIGKILL until flushed) vs the flush threshold
         from m3_tpu.utils.instrument import monitor_queue
@@ -118,7 +122,9 @@ class CommitLogWriter:
             log=os.path.basename(os.path.dirname(path)))
 
     def write(self, series_id: bytes, encoded_tags: bytes, time_ns: int,
-              value_bits: int, unit: int) -> None:
+              value_bits: int, unit: int, window: int | None = None) -> None:
+        """`window`: the block window of `time_ns`, where the caller
+        tracks which windows a log covers (Database's retirement rule)."""
         faults.check("commitlog.write")
         with self._lock:
             # poison check INSIDE the lock: a writer blocked here while a
@@ -135,6 +141,8 @@ class CommitLogWriter:
                     + encoded_tags
             self._buf += struct.pack(">BIqQB", 1, sidx, time_ns, value_bits,
                                      unit)
+            if window is not None:
+                self.windows.add(window)
             if len(self._buf) >= self._flush_every:
                 # the WAL write/fsync seam deliberately completes under
                 # the writer lock: the lock IS the append/flush ordering
@@ -151,7 +159,7 @@ class CommitLogWriter:
 
     def write_many(self, series_ids: list[bytes], tags_list: list[bytes],
                    times: np.ndarray, value_bits: np.ndarray,
-                   unit: int) -> None:
+                   unit: int, windows=()) -> None:
         """ONE commitlog append for a whole batch (columns: parallel
         series/tags lists + int64 time and uint64 value-bit arrays, all
         sharing the namespace's time unit). The datapoint records render
@@ -162,7 +170,8 @@ class CommitLogWriter:
         see nothing new. One fault-point hit and one flush-threshold
         check per batch (the per-point path checks per entry, so chunk
         BOUNDARIES may differ once a batch crosses the threshold; the
-        entry stream never does)."""
+        entry stream never does). `windows`: the block windows of
+        `times`, as for write()."""
         # same semantic seam as the per-point write() above — one name, one
         # injection schedule, whichever path the caller took
         # m3lint: disable=inv-fault-point-unique
@@ -176,6 +185,7 @@ class CommitLogWriter:
             # m3lint: disable=lock-blocking-call
             self._write_many_locked(series_ids, tags_list, times,
                                     value_bits, unit)
+            self.windows.update(windows)
 
     def _write_many_locked(self, series_ids, tags_list, times, value_bits,
                            unit) -> None:
@@ -251,6 +261,32 @@ class CommitLogWriter:
         except BaseException as e:
             self._failed = e
             raise
+
+    def rotate(self, path: str) -> tuple[str, set[int]]:
+        """Retire the current file and go on in `path`, under the
+        writer's own lock: an append is either whole in the retired file
+        (flushed and fsynced here, so it holds whole chunks only) or
+        whole in the new one, and no caller ever holds a closed writer.
+        Returns the retired file's (path, windows). A poisoned writer
+        retires its file unflushed (as close() leaves it) and is sound
+        again in the new one; a flush that fails HERE leaves the writer
+        poisoned in the old file for the next rotation."""
+        with self._lock:
+            if self._failed is None:
+                # deliberate: the fsync completes under the writer lock,
+                # as in flush()
+                # m3lint: disable=lock-blocking-call
+                self._flush_locked(fsync=True)
+            self._f.close()
+            retired = (self.path, self.windows)
+            os.makedirs(os.path.dirname(path), exist_ok=True)
+            self._f = open(path, "ab")
+            self.path = path
+            self._buf.clear()
+            self._series = {}
+            self.windows = set()
+            self._failed = None
+        return retired
 
     def close(self) -> None:
         self._unmonitor()

@@ -9,6 +9,7 @@ tick calls driven by the host control plane.
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import os
 import time
@@ -29,6 +30,7 @@ log = logging.getLogger(__name__)
 # series); the handle pre-resolves the metric key so the per-datapoint
 # hot path pays one lock + bisect per observation, nothing more
 _scope = default_registry().root_scope("db")
+_storage_scope = default_registry().root_scope("storage")
 _observe_write = _scope.histogram_handle("write_seconds")
 # the batched seam observes ONCE per batch; the points counter keeps
 # throughput accounting comparable with the per-point histogram's count
@@ -51,6 +53,11 @@ def _f64_to_bits(v: float) -> int:
     return int(np.float64(v).view(np.uint64))
 
 
+def _windows_of(times: np.ndarray, block_size_ns: int) -> list[int]:
+    """The block windows a batch's timestamps fall in."""
+    return np.unique(times - (times % block_size_ns)).tolist()
+
+
 class Database:
     """Single-node database ("local" topology mode of the reference)."""
 
@@ -59,8 +66,6 @@ class Database:
         self.opts = db_opts or DatabaseOptions()
         self.namespaces: dict[str, Namespace] = {}
         self._commitlogs: dict[str, commitlog.CommitLogWriter] = {}
-        # block windows logged into the ACTIVE commitlog, per namespace
-        self._log_windows: dict[str, set[int]] = {}
         # rotated logs awaiting deletion:
         # ns -> [(path, windows-it-covers, retired_at_ns)]
         self._retired_logs: dict[str, list[tuple[str, set[int], int]]] = {}
@@ -127,18 +132,29 @@ class Database:
         log = self._commitlogs.pop(name, None)
         if log is not None:
             log.close()
-        self._log_windows.pop(name, None)
         self._retired_logs.pop(name, None)
         for key in [k for k in self._snapshot_times if k[0] == name]:
             del self._snapshot_times[key]
 
+    def _commitlog_path(self, namespace: str) -> str:
+        return os.path.join(self.commitlog_dir(namespace),
+                            f"commitlog-{time.time_ns()}.db")
+
     def _open_commitlog(self, namespace: str) -> None:
-        d = self.commitlog_dir(namespace)
-        path = os.path.join(d, f"commitlog-{int(time.time()*1e9)}.db")
         self._commitlogs[namespace] = commitlog.CommitLogWriter(
-            path, self.opts.commitlog_flush_every_bytes
-        )
-        self._log_windows[namespace] = set()
+            self._commitlog_path(namespace),
+            self.opts.commitlog_flush_every_bytes)
+
+    def _rotate_commitlog(self, namespace: str, now_ns: int) -> None:
+        """Retire the active log's file (recording its windows and when)
+        and go on in a new one. The writer swaps files under its own
+        lock, so the request threads that hold it never meet a closed
+        file."""
+        old_path, old_windows = self._commitlogs[namespace].rotate(
+            self._commitlog_path(namespace))
+        self._retired_logs.setdefault(namespace, []).append(
+            (old_path, old_windows, now_ns))
+        _storage_scope.counter("commitlog_rotations")
 
     def open(self, now_ns: int | None = None) -> None:
         """Open + bootstrap: filesets first, then commitlog replay on top
@@ -431,8 +447,9 @@ class Database:
         vbits = _f64_to_bits(value)
         log = self._commitlogs.get(namespace)
         if log is not None:
-            log.write(series_id, encoded_tags, t_ns, vbits, int(ns.opts.write_time_unit))
-            self._log_windows[namespace].add(ns.opts.retention.block_start(t_ns))
+            log.write(series_id, encoded_tags, t_ns, vbits,
+                      int(ns.opts.write_time_unit),
+                      ns.opts.retention.block_start(t_ns))
         shard.write(series_id, t_ns, vbits, encoded_tags)
         if ns.index is not None and encoded_tags:
             # tagged-at-the-wire writes are index-visible like write_tagged,
@@ -456,8 +473,9 @@ class Database:
         vbits = _f64_to_bits(value)
         log = self._commitlogs.get(namespace)
         if log is not None:
-            log.write(series_id, enc, t_ns, vbits, int(ns.opts.write_time_unit))
-            self._log_windows[namespace].add(ns.opts.retention.block_start(t_ns))
+            log.write(series_id, enc, t_ns, vbits,
+                      int(ns.opts.write_time_unit),
+                      ns.opts.retention.block_start(t_ns))
         shard.write(series_id, t_ns, vbits, enc)
         if ns.index is not None:
             ns.index.insert(series_id, fields, t_ns)
@@ -484,7 +502,8 @@ class Database:
         t0 = time.perf_counter()
         try:
             with trace.span(trace.DB_WRITE_BATCH, namespace=namespace,
-                            entries=len(entries)):
+                            entries=len(entries)), \
+                    trace.stage(trace.STAGE_WRITE_BATCH):
                 results, n_ok = self._write_batch_traced(namespace, entries)
         finally:
             _observe_write_batch(time.perf_counter() - t0)
@@ -494,6 +513,7 @@ class Database:
 
     def _write_batch_traced(self, namespace, entries
                             ) -> tuple[list[str | None], int]:
+        from m3_tpu.utils import trace
         from m3_tpu.utils.ident import encode_tags, tags_to_id
 
         ns = self.namespaces[namespace]
@@ -572,13 +592,16 @@ class Database:
         if clog is not None:
             all_ok = len(ok) == n
             ok_idx = None if all_ok else np.asarray(ok, np.intp)
+            t_ok = times if all_ok else times[ok_idx]
             try:
-                clog.write_many(
-                    series_ids if all_ok else [series_ids[i] for i in ok],
-                    encs if all_ok else [encs[i] for i in ok],
-                    times if all_ok else times[ok_idx],
-                    vbits if all_ok else vbits[ok_idx],
-                    int(ns.opts.write_time_unit))
+                with trace.stage(trace.STAGE_WRITE_COMMITLOG):
+                    clog.write_many(
+                        series_ids if all_ok else [series_ids[i] for i in ok],
+                        encs if all_ok else [encs[i] for i in ok],
+                        t_ok,
+                        vbits if all_ok else vbits[ok_idx],
+                        int(ns.opts.write_time_unit),
+                        _windows_of(t_ok, ns.opts.retention.block_size_ns))
             except faults.SimulatedCrash:
                 raise  # no handler survives a kill
             except Exception as ex:  # noqa: BLE001 - WAL failure: nothing
@@ -588,11 +611,6 @@ class Database:
                 for i in ok:
                     results[i] = str(ex)
                 return results, 0
-            r = ns.opts.retention
-            windows = self._log_windows[namespace]
-            t_ok = times if all_ok else times[ok_idx]
-            for w in np.unique(t_ok - (t_ok % r.block_size_ns)).tolist():
-                windows.add(int(w))
         # buffer + index: reuse the routing pass; `results` doubles as the
         # error vector so entries degraded above skip the index insert
         ns.write_many(series_ids, times, vbits, encs, fields_list,
@@ -614,10 +632,12 @@ class Database:
         (chunk boundaries only move the flush-threshold checks, as the
         batched write_many already documents)."""
         from m3_tpu.storage import pipeline
+        from m3_tpu.utils import trace
 
         n = len(entries)
         chunk = pipeline.wal_chunk_entries()
         unit = int(ns.opts.write_time_unit)
+        block_ns = ns.opts.retention.block_size_ns
         lane = pipeline.default_executor().lane(f"wal:{namespace}")
         chunks = [ok[lo:lo + chunk] for lo in range(0, len(ok), chunk)]
         futs = []
@@ -626,14 +646,14 @@ class Database:
             futs.append(lane.submit(
                 lambda s=[series_ids[i] for i in ch],
                 g=[encs[i] for i in ch], t=times[idx], v=vbits[idx]:
-                clog.write_many(s, g, t, v, unit)))
-        r = ns.opts.retention
-        windows = self._log_windows[namespace]
+                clog.write_many(s, g, t, v, unit, _windows_of(t, block_ns))))
         mask = np.zeros(n, bool)
         n_ok = 0
         for fut, ch in zip(futs, chunks):
             try:
-                fut.result()
+                # what this thread waits for the lane's append
+                with trace.stage(trace.STAGE_WRITE_COMMITLOG):
+                    fut.result()
             except faults.SimulatedCrash:
                 raise  # no handler survives a kill
             except Exception as ex:  # noqa: BLE001 - this chunk was never
@@ -644,9 +664,6 @@ class Database:
                     results[i] = str(ex)
                 continue
             idx = np.asarray(ch, np.intp)
-            t_ch = times[idx]
-            for w in np.unique(t_ch - (t_ch % r.block_size_ns)).tolist():
-                windows.add(int(w))
             mask[:] = False
             mask[idx] = True
             routed_chunk = {}
@@ -761,19 +778,38 @@ class Database:
         of backfilled (already-flushed) windows, snapshot of in-flight
         windows, retention expiry, commitlog rotation (a log retires once
         its windows are flushed OR snapshotted after it was rotated — the
-        reference flush model, storage/README.md + coldflush.go)."""
-        now_ns = now_ns if now_ns is not None else time.time_ns()
+        reference flush model, storage/README.md + coldflush.go).
+
+        The cycle is one root stage of the stage clock's route `tick`
+        (utils/trace.py): snapshot, flush and rotation are stages
+        beneath it, and expiry and index persist and compaction are the
+        root's self-time."""
+        from m3_tpu.utils import trace
+
+        ctx = dataclasses.replace(trace.start_request(),
+                                  route=trace.ROUTE_TICK)
+        with trace.activate(ctx), trace.stage(trace.STAGE_TICK):
+            return self._tick_staged(
+                now_ns if now_ns is not None else time.time_ns())
+
+    def _tick_staged(self, now_ns: int) -> dict:
+        from m3_tpu.utils import trace
+
         flushed = cold_flushed = expired = 0
         ropts = self._runtime_opts
         snap_on = ropts is None or ropts.snapshot_enabled
         flush_on = ropts is None or ropts.flush_enabled
-        snapped = self.snapshot(now_ns) if snap_on else {}
+        snapped = {}
+        if snap_on:
+            with trace.stage(trace.STAGE_TICK_SNAPSHOT):
+                snapped = self.snapshot(now_ns)
         for name, ns in self.namespaces.items():
-            n = ns.flush(now_ns) if flush_on else 0
-            # cold pass AFTER the warm pass (reference mediator ordering):
-            # backfilled blocks merge into version-bumped volumes without
-            # delaying first-volume warm flushes
-            n_cold = ns.cold_flush() if flush_on else 0
+            with trace.stage(trace.STAGE_TICK_FLUSH):
+                n = ns.flush(now_ns) if flush_on else 0
+                # cold pass AFTER the warm pass (reference mediator
+                # ordering): backfilled blocks merge into version-bumped
+                # volumes without delaying first-volume warm flushes
+                n_cold = ns.cold_flush() if flush_on else 0
             flushed += n
             cold_flushed += n_cold
             n += n_cold  # both make windows durable for commitlog retirement
@@ -798,18 +834,16 @@ class Database:
                 index_persist.expire_index_files(
                     self.fs_root, name, cutoff, ns.opts.index.block_size_ns
                 )
-            if ((n or ns_snapped) and name in self._commitlogs
-                    and self._log_windows.get(name)):
-                # the active log's windows are durable (fileset volume or
-                # snapshot): retire it (recording windows + when) and start
-                # a new one; retirement completes in _cleanup_retired_logs
-                old = self._commitlogs[name]
-                old.close()
-                self._retired_logs.setdefault(name, []).append(
-                    (old.path, self._log_windows.get(name, set()), now_ns)
-                )
-                self._open_commitlog(name)
-            if name in self._commitlogs:
+            clog = self._commitlogs.get(name)
+            if clog is None:
+                continue
+            # one stage a namespace a cycle, whether or not it rotates
+            with trace.stage(trace.STAGE_TICK_ROTATE):
+                if (n or ns_snapped) and clog.windows:
+                    # the active log's windows are durable (fileset
+                    # volume or snapshot): retire its file; retirement
+                    # completes in _cleanup_retired_logs
+                    self._rotate_commitlog(name, now_ns)
                 self._cleanup_retired_logs(name, ns, now_ns)
         return {"flushed": flushed, "cold_flushed": cold_flushed,
                 "expired": expired, "snapshotted": sum(snapped.values())}
@@ -904,12 +938,9 @@ class Database:
             if clog is not None:
                 # tiles hit the commitlog like every other write into the
                 # target namespace, one append for the whole shard's batch
-                clog.write_many(sids, encs, t_arr, v_arr,
-                                int(tgt.opts.write_time_unit))
-                windows = self._log_windows[target_ns]
-                bs = tgt.opts.retention.block_size_ns
-                for win in np.unique(t_arr - (t_arr % bs)).tolist():
-                    windows.add(int(win))
+                clog.write_many(
+                    sids, encs, t_arr, v_arr, int(tgt.opts.write_time_unit),
+                    _windows_of(t_arr, tgt.opts.retention.block_size_ns))
             errors = tgt.write_many(sids, t_arr, v_arr, encs, fields_list)
             written += sum(1 for err in errors if err is None)
         return written

@@ -195,12 +195,16 @@ class Namespace:
             by_shard, err_map = self.route_many(series_ids)
             errors = [err_map.get(i) for i in range(n)] if err_map \
                 else [None] * n
-        for shard_id, rows in by_shard.items():
-            ridx = np.asarray(rows, np.intp)
-            rows_l = rows.tolist() if hasattr(rows, "tolist") else list(rows)
-            self.shards[shard_id].write_many(
-                [series_ids[i] for i in rows_l], times[ridx],
-                value_bits[ridx], [tags_list[i] for i in rows_l])
+        from m3_tpu.utils import trace
+
+        with trace.stage(trace.STAGE_WRITE_BUFFER):
+            for shard_id, rows in by_shard.items():
+                ridx = np.asarray(rows, np.intp)
+                rows_l = rows.tolist() if hasattr(rows, "tolist") \
+                    else list(rows)
+                self.shards[shard_id].write_many(
+                    [series_ids[i] for i in rows_l], times[ridx],
+                    value_bits[ridx], [tags_list[i] for i in rows_l])
         if self.index is not None and fields_list is not None:
             cand = only_rows if only_rows is not None else range(n)
             ok = [i for i in cand

@@ -455,6 +455,7 @@ class Shard:
         (the flush-model snapshot role, reference storage/README.md,
         persist/fs/snapshot_metadata_{read,write}.go)."""
         from m3_tpu.encoding.m3tsz import hostpath
+        from m3_tpu.utils.instrument import default_registry
 
         faults.check("shard.snapshot", shard=self.shard_id,
                      block_start=block_start)
@@ -472,6 +473,11 @@ class Shard:
             )
         except OverflowError:
             return False
+        # what the encoder took in and wrote: 16 B a sample in, the
+        # streams' bytes out
+        scope = default_registry().root_scope("storage")
+        scope.counter("snapshot_samples", len(sealed.times))
+        scope.counter("snapshot_bytes", sum(map(len, streams)))
         writer = FilesetWriter(
             snapshot_root, self.namespace, self.shard_id, block_start,
             self.opts.retention.block_size_ns, snapshot_id,

@@ -197,6 +197,18 @@ TIMERS = {
 #   query_stage_cpu_seconds {route,stage}      counter: the same spans'
 #       thread CPU self-time (time.thread_time_ns); wall minus CPU is
 #       time the thread waited (GIL, locks, the device)
+# The write route's stages under `request`: write.decode, write.batch,
+# write.commitlog, write.buffer. The mediator's cycle is a route of its
+# own, `tick` (storage/database.py tick, outside any request): root
+# `tick`, tick.snapshot.host, tick.flush, tick.rotate, and beneath the
+# snapshot (or a flush) encode.device_wait, the device encoder's call
+# (encoding/m3tsz/hostpath.py encode_blocks, on any route); the root's
+# _count is the cycles. Beside them, storage scope:
+#   storage_snapshot_samples / storage_snapshot_bytes   samples the
+#       tick's snapshots sealed and the stream bytes their encoder
+#       wrote (storage/shard.py snapshot)
+#   storage_commitlog_rotations                commitlog files retired
+#       (storage/database.py _rotate_commitlog)
 #
 # Tier-resolution read routing (query/resolver.resolve_read), query.tier
 # scope with a {tier=...} label (raw / stitched / pinned_raw /

@@ -85,10 +85,10 @@ PLACEMENT_SYNC_DEFER = "placement.sync.defer"
 WIRE_FALLBACK = "wire.fallback"
 PIPELINE_CONSUME = "storage.pipeline.consume"
 
-# stage names of the served query path: one per layer boundary (PERF.md
-# section 3), the `stage` label of query_stage_seconds and the keys of
-# QueryStats.stages. A stage whose jit_tracker reports a miss closes as
-# the *.compile name instead.
+# stage names of the served paths (query, write, tick): one per layer
+# boundary (PERF.md section 3), the `stage` label of query_stage_seconds
+# and the keys of QueryStats.stages. A query stage whose jit_tracker
+# reports a miss closes as the *.compile name instead.
 STAGE_REQUEST = "request"
 STAGE_PARSE_PLAN = "parse_plan"
 STAGE_QUERY_IDS = "query_ids"
@@ -97,16 +97,33 @@ STAGE_GATHER = "read_many.gather"
 STAGE_DECODE_HOST = "decode.host"
 STAGE_DECODE_WAIT = "decode.device_wait"
 STAGE_DECODE_COMPILE = "decode.compile"
+# the device encoder's call and the transfers that wait for it
+# (hostpath.encode_blocks), under whatever route seals a block: the
+# tick's snapshot and flush, the wire codec's re-encode
+STAGE_ENCODE_WAIT = "encode.device_wait"
 STAGE_SLAB_PREP = "slab_prep"
 STAGE_PLAN_DISPATCH = "plan.dispatch"
 STAGE_PLAN_WAIT = "plan.device_wait"
 STAGE_PLAN_COMPILE = "plan.compile"
 STAGE_EVAL = "eval"
 STAGE_RENDER = "render"
+# the write route (query/api.py _remote_write under the `request` root)
+STAGE_WRITE_DECODE = "write.decode"
+STAGE_WRITE_BATCH = "write.batch"
+STAGE_WRITE_COMMITLOG = "write.commitlog"
+STAGE_WRITE_BUFFER = "write.buffer"
+# the route `tick`: one root stage a cycle (storage/database.py tick),
+# published when the cycle ends; what no stage beneath it covers
+# (expiry, index persist and compaction) is its self-time
+STAGE_TICK = "tick"
+STAGE_TICK_SNAPSHOT = "tick.snapshot.host"   # less the encoder's wait
+STAGE_TICK_FLUSH = "tick.flush"
+STAGE_TICK_ROTATE = "tick.rotate"
 
 # the `route` label's values (query/api.py route_of); a stage opened
 # outside any routed request reports as ROUTE_OTHER
 ROUTE_OTHER = "other"
+ROUTE_TICK = STAGE_TICK   # the mediator's cycle: root stage and route
 
 # a thread whose outermost span stays open publishes its closed stages
 # at this many (a request closes some twenty)
