@@ -278,7 +278,8 @@ def _decode_streams_device(streams: list[bytes], unit: TimeUnit,
             if int_optimized:
                 dec = tpu_int.decode_int(words, unit, max_points=max_points)
             else:
-                dec = m3tsz_tpu.decode(words, unit, max_points=max_points)
+                dec = m3tsz_tpu.decode(words, unit, max_points=max_points,
+                                       n_live=len(streams))
             vals, times, err, counts = jax.device_get(
                 (dec.values if int_optimized else dec.value_bits,
                  dec.times, dec.error, dec.n_points))

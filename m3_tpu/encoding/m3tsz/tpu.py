@@ -8,8 +8,9 @@ these kernels process a whole (series x timestep) block per dispatch:
   each datapoint's payload in a 192-bit register, then scatter-add the
   (disjoint) bit pieces into the output word tensor. Because every bit is
   produced by exactly one datapoint, integer add == bitwise or.
-- **decode**: lax.scan over timesteps (the format is inherently sequential
-  per stream) vmapped over series — throughput comes from the batch axis.
+- **decode**: a loop over timesteps (the format is inherently sequential
+  per stream) batched over series — throughput comes from the batch axis;
+  the TPU lowering's loop ends where every stream has met its end marker.
 
 Streams are bit-identical to the scalar encoder configured with
 int_optimized=False and a fixed time unit (the storage engine's block-write
@@ -475,10 +476,14 @@ def decode(
     unit: TimeUnit = TimeUnit.SECOND,
     max_points: int = 1024,
     impl: str | None = None,
+    n_live: int | None = None,
 ) -> DecodedBlocks:
     """Batched M3TSZ float-mode decode (platform dispatch; `impl` as in
-    encode_bits)."""
-    return _decode_jit(words, unit, max_points, _resolve_impl(impl))
+    encode_bits). `n_live`: the first rows that hold streams, where the
+    rest pad the batch to a shape bucket (all of them when None); the
+    TPU lowering stops as soon as every live row is at its end."""
+    return _decode_jit(words, unit, max_points, _resolve_impl(impl),
+                       np.int32(words.shape[0] if n_live is None else n_live))
 
 
 @functools.partial(jax.jit, static_argnames=("unit", "max_points", "impl"))
@@ -487,10 +492,11 @@ def _decode_jit(
     unit: TimeUnit,
     max_points: int,
     impl: str,
+    n_live: jnp.ndarray,
 ) -> DecodedBlocks:
     with jax.named_scope("m3.decode.scan"):
         if impl == "tree":
-            return _decode_shift(words, unit, max_points)
+            return _decode_shift(words, unit, max_points, n_live)
         return _decode_gather(words, unit, max_points)
 
 
@@ -592,8 +598,9 @@ def _decode_gather(
 
 def _decode_shift(
     words: jnp.ndarray,  # [B, W] uint64
-    unit: TimeUnit = TimeUnit.SECOND,
-    max_points: int = 1024,
+    unit: TimeUnit,
+    max_points: int,
+    n_live: jnp.ndarray,  # int32 scalar: rows from here on are padding
 ) -> DecodedBlocks:
     """Batched M3TSZ float-mode decode via a shifting stream buffer.
 
@@ -606,6 +613,14 @@ def _decode_shift(
     1024 steps]: 0.145 s against 0.003 s, one run each, PR 21) with pure
     elementwise work that XLA tiles; throughput comes from the batch axis
     and HBM bandwidth.
+
+    The loop ends at the step where every live row has met its end of
+    stream, not after `max_points` steps: the capacity is reckoned from
+    the longest stream at 2 bits a point (hostpath._max_points: 2,048
+    steps for an hour of 10 s readings, 360 points), and every step past
+    the last point is device time, and a step's worth of events in a
+    device trace, for nothing. Rows from `n_live` on (shape-bucket
+    padding: zero words never reach an end marker) start out done.
     """
     from m3_tpu.ops import bits32 as b32
 
@@ -746,10 +761,29 @@ def _decode_shift(
         start,
         jnp.zeros((B,), I64),
         zb, zb, zb, zb,
-        jnp.zeros((B,), bool),
+        jnp.arange(B, dtype=jnp.int32) >= n_live,
         jnp.zeros((B,), bool),
     )
-    carry, (ts, vh, vl, ok) = lax.scan(step, init, jnp.arange(max_points))
+
+    # a scan that stops early: the per-step outputs land in [P, B]
+    # buffers at row i, as lax.scan stacks them, and rows past the last
+    # step stay zero / not ok, which is what a done row's steps write
+    def more(state):
+        i, carry, _outs = state
+        done = carry[8]
+        return (i < max_points) & ~jnp.all(done)
+
+    def advance(state):
+        i, carry, outs = state
+        carry, step_outs = step(carry, i)
+        return (i + 1, carry, tuple(
+            lax.dynamic_update_index_in_dim(buf, row, i, 0)
+            for buf, row in zip(outs, step_outs)))
+
+    outs0 = tuple(jnp.zeros((max_points, B), dt)
+                  for dt in (I64, u32, u32, bool))
+    _, carry, (ts, vh, vl, ok) = lax.while_loop(
+        more, advance, (jnp.int32(0), init, outs0))
     err = carry[-1]
     return DecodedBlocks(
         times=ts.T,

@@ -345,12 +345,16 @@ class Engine:
                 return None
             if not getattr(ns, "has_version_truth", False):
                 # facades (cluster, fanout) have no local version truth;
-                # fanout would even DELEGATE data_version to its local
+                # fanout would even DELEGATE data_version_in to its local
                 # namespace, keying out remote-zone changes — no hot tier
                 # (cluster facades still serve ragged reads, which is why
                 # this is a separate marker from supports_ragged_read)
                 return None
-            parts.append((name, ns.ns_uid, ns.data_version()))
+            # the version of the blocks this range touches, not of the
+            # namespace: a write to the head block leaves a fetch over
+            # sealed history warm
+            parts.append((name, ns.ns_uid,
+                          ns.data_version_in(t_min, t_max)))
         mk = tuple(sorted((m.name, getattr(m.match_type, "value",
                                            str(m.match_type)), m.value)
                           for m in sel.matchers))

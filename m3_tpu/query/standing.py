@@ -7,11 +7,14 @@ per plan signature via the lru_cache program factory; the bounded plan
 cache keys evaluations like any query), but evaluation is INCREMENTAL
 per ingest batch instead of per request:
 
-- every evaluation is keyed by the hot tier's fetch identity —
-  ``(Namespace.data_version(), selector matchers, evaluation grid)`` —
-  the exact key the compiled path uses for device-resident prepared
-  slabs (storage/hottier.py). An unchanged key means the inputs cannot
-  have changed: the rule is SKIPPED without touching storage.
+- every evaluation is keyed by
+  ``(Namespace.data_version(), selector matchers, evaluation grid)``:
+  the shape of the key the compiled path uses for device-resident
+  prepared slabs (storage/hottier.py), with the version of the whole
+  namespace where a fetch takes that of its range's blocks (a rule
+  reads the newest windows, so the head block's writes are what moves
+  it). An unchanged key means the inputs cannot have changed: the rule
+  is SKIPPED without touching storage.
 - a changed namespace version is refined to shard granularity:
   ``Shard.data_version`` bumps tell the evaluator precisely WHICH
   shards' content moved, and a rule re-evaluates only when a bumped

@@ -476,9 +476,11 @@ class Database:
             log.write(series_id, enc, t_ns, vbits,
                       int(ns.opts.write_time_unit),
                       ns.opts.retention.block_start(t_ns))
-        shard.write(series_id, t_ns, vbits, enc)
+        # the index first, as in Namespace.write_many: the write's version
+        # bump tells a fetch that the series is matched as well as stored
         if ns.index is not None:
             ns.index.insert(series_id, fields, t_ns)
+        shard.write(series_id, t_ns, vbits, enc)
         _observe_write(time.perf_counter() - t0)
         return series_id
 

@@ -6,9 +6,10 @@ whole-query compiler's host prep (window bounds, per-device slab fill,
 prefix sums) plus the host->device transfer of those slabs are what a
 REPEATED dashboard query pays after the block cache has already
 amortized the decode.  This tier keys the prepared slab set on the
-fetch's content identity — (namespace data versions, selector, time
-range, eval grid, plan base, precision) — all of it known BEFORE
-storage is read, so the compiler probes the tier first
+fetch's content identity — (the namespaces' data versions over the
+fetch's range, selector, time range, eval grid, plan base, precision)
+— all of it known BEFORE storage is read, so the compiler probes the
+tier first
 (`compiler._run_plan`) and an unchanged repeat skips the index match
 (`query_ids`), the read (`read_many`), `window_bounds_batch`,
 `_slab_cuts`/`_fill_slabs` and the transfer entirely: the compiled
@@ -18,10 +19,14 @@ from the fetch itself: the series and sample counts (padding ledger),
 the group labels of an aggregating plan or the series' label dicts of
 one without (counted into the entry's `nbytes`), and the series and
 datapoint counts the query limits were charged, which a hit charges
-again — a repeat over a limit is refused as its first run was.  Any
-write, flush, repair or expiry bumps the namespace's data version, so
-a warm entry is never served over changed data; the version is sampled
-before the read, so a racing write can only make an entry stale.
+again — a repeat over a limit is refused as its first run was.  The
+version in the key is that of the blocks the fetch's range touches
+(`Namespace.data_version_in`): a write, flush, repair or bootstrap
+bumps the version of the block it changed, expiry and a placement
+change bump every range's, so a warm entry is never served over
+changed data, and the head block's writes leave an entry over sealed
+history warm; the version is sampled before the read, so a racing
+write can only make an entry stale.
 
 On CPU backends the "device" is jax's host platform and the tier is an
 ordinary arena of committed buffers; on a TPU the same code pins the

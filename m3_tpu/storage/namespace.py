@@ -75,17 +75,45 @@ class Namespace:
         return getattr(self.database, "limits", None)
 
     def data_version(self) -> tuple:
-        """Content-version fingerprint for the device-resident hot tier
-        (storage/hottier.py): changes whenever any owned shard's readable
-        content could have changed. The placement epoch (bumped by every
-        add/remove_shard) rides along because a remove+add swap can
-        return the version SUM to a previously-seen value with different
-        readable content — a sum alone would alias and serve stale
-        pages."""
+        """Content-version fingerprint of the whole namespace: changes
+        whenever any owned shard's readable content could have changed,
+        in any block. The standing engine keys a rule's evaluation on it
+        (query/standing.py: its rules read the newest windows, where the
+        head block's writes are what matters); a selector fetch keys on
+        `data_version_in` of its own range. The placement epoch (bumped
+        by every add/remove_shard) rides along because a remove+add swap
+        can return the version SUM to a previously-seen value with
+        different readable content — a sum alone would alias and serve
+        stale pages."""
         shards = list(self.shards.values())  # placement changes mutate
         # the dict concurrently; iterate a snapshot
         return (self._placement_epoch, len(shards),
                 sum(s.data_version for s in shards))
+
+    def data_version_in(self, t_min: int, t_max: int) -> tuple:
+        """Content-version fingerprint of what a fetch over
+        `[t_min, t_max)` can return, for the device-resident hot tier
+        (storage/hottier.py): the samples lie in the blocks from
+        `block_start(t_min)` to `block_start(t_max - 1)`, so a write,
+        flush, repair or bootstrap of any other block leaves it as it
+        was and sealed history stays warm beside the head block's
+        writes. It cannot alias — two different readable contents of the
+        range never share a fingerprint: every shard's term is a sum of
+        counters that only ever grow and are never reset or pruned (its
+        structural counter, which expiry bumps, and the version of each
+        block of the range, 0 for one never written or loaded:
+        `Shard.data_version_in`), so the sum over a fixed set of shards
+        never returns to a value it had; and the set of shards changes
+        only with the placement epoch, which rides along as it does in
+        `data_version`. A series is indexed before its first sample
+        bumps a version (`write_many`), so the fingerprint that holds a
+        sample also matches its series."""
+        r = self.opts.retention
+        first = r.block_start(t_min)
+        last = r.block_start(max(t_max - 1, t_min))
+        shards = list(self.shards.values())  # as in data_version
+        return (self._placement_epoch, len(shards),
+                sum(s.data_version_in(first, last) for s in shards))
 
     def add_shard(self, shard_id: int, now_ns: int | None = None) -> Shard:
         """Start owning a shard (placement assignment). Local fileset data
@@ -135,9 +163,12 @@ class Namespace:
                      t_ns: int, value_bits: int, encoded_tags: bytes = b"") -> None:
         """Write + reverse-index the series in the datapoint's index block
         (the writeAndIndex path, reference storage/shard.go:869-896)."""
-        self.shard_for(series_id).write(series_id, t_ns, value_bits, encoded_tags)
+        # the index first: the write's version bump then tells a fetch
+        # that the series is matched as well as stored (see write_many)
+        shard = self.shard_for(series_id)
         if self.index is not None:
             self.index.insert(series_id, tags, t_ns)
+        shard.write(series_id, t_ns, value_bits, encoded_tags)
 
     def shards_of(self, series_ids: list[bytes]) -> list[int]:
         """The shard of each id, == shard_set.lookup_many(series_ids):
@@ -197,14 +228,12 @@ class Namespace:
                 else [None] * n
         from m3_tpu.utils import trace
 
-        with trace.stage(trace.STAGE_WRITE_BUFFER):
-            for shard_id, rows in by_shard.items():
-                ridx = np.asarray(rows, np.intp)
-                rows_l = rows.tolist() if hasattr(rows, "tolist") \
-                    else list(rows)
-                self.shards[shard_id].write_many(
-                    [series_ids[i] for i in rows_l], times[ridx],
-                    value_bits[ridx], [tags_list[i] for i in rows_l])
+        # the index before the buffers: a shard bumps its block's version
+        # as its rows land, and a fetch that samples the new version must
+        # match a series first seen in this batch, or the hot tier would
+        # keep an answer without it under the version that has it. (A
+        # fetch in between matches a series with no sample yet, which it
+        # drops, under the old version.)
         if self.index is not None and fields_list is not None:
             cand = only_rows if only_rows is not None else range(n)
             ok = [i for i in cand
@@ -213,6 +242,14 @@ class Namespace:
                 self.index.insert_many([series_ids[i] for i in ok],
                                        [fields_list[i] for i in ok],
                                        times[np.asarray(ok, np.intp)])
+        with trace.stage(trace.STAGE_WRITE_BUFFER):
+            for shard_id, rows in by_shard.items():
+                ridx = np.asarray(rows, np.intp)
+                rows_l = rows.tolist() if hasattr(rows, "tolist") \
+                    else list(rows)
+                self.shards[shard_id].write_many(
+                    [series_ids[i] for i in rows_l], times[ridx],
+                    value_bits[ridx], [tags_list[i] for i in rows_l])
         return errors
 
     def query_ids(self, query: Query, start_ns: int, end_ns: int, limit=None):

@@ -352,7 +352,7 @@ def bootstrap_shard_from_peers(db, namespace: str, shard_id: int,
                 raise
             except Exception:  # noqa: BLE001 - unreachable peer adds none
                 pass
-    written = 0
+    streamed: list[int] = []
     for bs in sorted(all_starts):
         if bs in shard._filesets:
             continue  # already have a volume
@@ -372,22 +372,32 @@ def bootstrap_shard_from_peers(db, namespace: str, shard_id: int,
         shard._filesets[bs] = FilesetReader(
             shard.fs_root, namespace, shard_id, bs, 0
         )
-        shard.bump_data_version()
-        written += 1
+        # with the swap, whatever comes after it: a lost bump would leave
+        # a hot-tier entry over this block stale for good
+        shard.bump_data_version(bs)
+        streamed.append(bs)
     # the reverse index learns the streamed series (spanning every index
     # block the data block overlaps, like fs bootstrap)
     if ns.index is not None:
         from m3_tpu.utils.ident import decode_tags
 
-        for bs in sorted(all_starts):
-            reader = shard._filesets.get(bs)
-            if reader is None:
-                continue
-            for i in range(reader.n_series):
-                sid, tags_blob = reader.entry_at(i)
-                if tags_blob:
-                    ns.index_insert_spanning(sid, decode_tags(tags_blob), bs)
-    return written
+        try:
+            for bs in sorted(all_starts):
+                reader = shard._filesets.get(bs)
+                if reader is None:
+                    continue
+                for i in range(reader.n_series):
+                    sid, tags_blob = reader.entry_at(i)
+                    if tags_blob:
+                        ns.index_insert_spanning(
+                            sid, decode_tags(tags_blob), bs)
+        finally:
+            # and again once a fetch finds the series as well as the
+            # volume (or as far as the index got): one in between keyed a
+            # match without them under the swap's version
+            for bs in streamed:
+                shard.bump_data_version(bs)
+    return len(streamed)
 
 
 def _merged_block_from_peers(namespace, shard_id, bs, peers, pacer=None):
@@ -607,14 +617,23 @@ def repair_shard_block(db, namespace: str, shard_id: int, block_start: int,
         shard._filesets[block_start] = FilesetReader(
             shard.fs_root, namespace, shard_id, block_start, volume
         )
-        shard.bump_data_version()
+        # with the swap, whatever comes after it: a lost bump would leave
+        # a hot-tier entry over this block stale for good
+        shard.bump_data_version(block_start)
         if shard.cache is not None:  # cached decodes predate the repair
             shard.cache.invalidate_block(namespace, shard_id, block_start)
     # peer-only series become queryable
     if ns.index is not None:
         from m3_tpu.utils.ident import decode_tags
 
-        for sid, (tags, _stream) in merged.items():
-            if tags:
-                ns.index_insert_spanning(sid, decode_tags(tags), block_start)
+        try:
+            for sid, (tags, _stream) in merged.items():
+                if tags:
+                    ns.index_insert_spanning(sid, decode_tags(tags),
+                                             block_start)
+        finally:
+            # and again once a fetch finds the peer-only series as well
+            # as the volume (or as far as the index got): one in between
+            # keyed a match without them under the swap's version
+            shard.bump_data_version(block_start)
     return result

@@ -187,14 +187,24 @@ class Shard:
         # volumes.
         self.warm_writes = 0
         self.cold_writes = 0
-        # monotone data-content version: bumped by every mutation a read
+        # monotone data-content versions: bumped by every mutation a read
         # could observe (writes, flush/volume swaps, bootstrap, repair,
+        # expiry), after the data is in place. `data_version` counts them
+        # all (the standing engine's per-shard invalidation reads it);
+        # each bump also lands in exactly one of `_block_versions[bs]`
+        # (a change to what a read of block `bs` returns) or
+        # `_structural_version` (what cannot be laid to one block:
         # expiry). The device-resident hot tier (storage/hottier.py) keys
-        # prepared query slabs on it — an unchanged version means an
-        # identical fetch, so warm device pages can serve without a
-        # rebuild. Guarded by _seq_lock (a lost bump would serve stale
-        # pages, the one unacceptable failure mode).
+        # prepared query slabs on `data_version_in` of the blocks a fetch's
+        # range touches — unchanged means an identical fetch, so warm
+        # device pages serve without a rebuild, and a write to the head
+        # block leaves sealed history warm. No counter is ever reset or
+        # pruned (one int a block, as `_write_seq`): a sum of them never
+        # returns to a value it had. Guarded by _seq_lock (a lost bump
+        # would serve stale pages, the one unacceptable failure mode).
         self.data_version = 0
+        self._block_versions: dict[int, int] = {}
+        self._structural_version = 0
 
     # -- write --
 
@@ -211,7 +221,7 @@ class Shard:
         # clean without the point
         with self._seq_lock:
             self._write_seq[bs] = self._write_seq.get(bs, 0) + 1
-            self.data_version += 1
+            self._bump_locked(bs)
         return idx
 
     def write_many(self, series_ids: list[bytes], times: np.ndarray,
@@ -233,14 +243,39 @@ class Shard:
         with self._seq_lock:
             for w, c in zip(uniq.tolist(), counts.tolist()):
                 self._write_seq[w] = self._write_seq.get(w, 0) + c
-            self.data_version += 1
+                self._bump_locked(w)
 
-    def bump_data_version(self) -> None:
-        """Mark the shard's readable content changed (volume swaps from
-        flush/bootstrap/repair, expiry) — hot-tier entries keyed on the
-        old version stop matching."""
+    def _bump_locked(self, block_start: int | None) -> None:
+        self.data_version += 1
+        if block_start is None:
+            self._structural_version += 1
+        else:
+            self._block_versions[block_start] = \
+                self._block_versions.get(block_start, 0) + 1
+
+    def bump_data_version(self, block_start: int | None = None) -> None:
+        """Mark readable content changed: of the block at `block_start`
+        (a volume swap from flush/bootstrap/repair), or with None of the
+        shard as a whole (expiry, anything not laid to one block) —
+        hot-tier entries keyed on the old version of a range that holds
+        the block, or with None of any range, stop matching."""
         with self._seq_lock:
-            self.data_version += 1
+            self._bump_locked(block_start)
+
+    def data_version_in(self, first_block: int, last_block: int) -> int:
+        """Content version of the blocks `first_block`..`last_block`
+        (block starts, inclusive): the structural counter plus each
+        block's own, a never-touched block reading 0. Every term is
+        monotone and none is ever reset or dropped, so two different
+        readable contents of those blocks never share a value. Lock-free
+        like a read of `data_version`: the key is sampled before the
+        read, so a racing bump can only make an entry stale."""
+        # one int a block ever touched (a handful within retention), so
+        # this walk is cheap for any range, a query from time 0 too;
+        # list() because writers add blocks meanwhile
+        blocks = sum(v for bs, v in list(self._block_versions.items())
+                     if first_block <= bs <= last_block)
+        return self._structural_version + blocks
 
     def write_seq(self, block_start: int) -> int:
         return self._write_seq.get(block_start, 0)
@@ -673,7 +708,7 @@ class Shard:
             self.cache.invalidate_block(self.namespace, self.shard_id,
                                         block_start)
         self.buffer.drop_window_prefix(block_start, sealed.raw_count)
-        self.bump_data_version()
+        self.bump_data_version(block_start)
         return True
 
     # -- bootstrap --
@@ -700,9 +735,12 @@ class Shard:
             # namespace creation, PR 7) can race a maintenance pass
             with self._maint_lock:
                 self._filesets[block_start] = reader
+            # a volume written under another block size reaches past the
+            # one block its start names: every range's version moves
+            self.bump_data_version(
+                block_start if reader.block_size_ns == r.block_size_ns
+                else None)
             n += 1
-        if n:
-            self.bump_data_version()
         return n
 
     # -- maintenance --

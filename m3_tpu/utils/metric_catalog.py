@@ -112,9 +112,13 @@ TIMERS = {
 #       the pagepool snapshot hook
 #   queue_depth/capacity/dropped {queue=hot_tier}    prepared-slab bytes
 #       used / byte cap / LRU evictions (storage/hottier)
-#   storage_hot_tier_hit / storage_hot_tier_miss     per-query counters
+#   storage_hot_tier_hit / storage_hot_tier_miss     per-plan counters
 #       (compiled path; the same outcome rides the ?explain=analyze
-#       hot_tier block)
+#       hot_tier block); an entry misses once the version of a block
+#       its fetch's range touches has moved (Namespace.data_version_in),
+#       not on a write to any other block
+#   storage_hot_tier_fetch_skipped                   plans served from a
+#       warm entry with no index match and no read
 #
 # Series -> shard routing (storage/sharding.py ShardRoutes):
 #   storage_shard_route_hit / storage_shard_route_miss   series ids

@@ -299,12 +299,13 @@ class TestRaggedSealEncode:
         assert s_pad == s_rag
 
 
-def _build_db(root, rng, n_series=64, n_blocks=3, with_flush=True):
+def _build_db(root, rng, n_series=64, n_blocks=3, with_flush=True,
+              index_block=HOUR):
     db = Database(root, DatabaseOptions(n_shards=4))
     ns = db.create_namespace("default", NamespaceOptions(
         retention=RetentionOptions(retention_ns=1000 * HOUR,
                                    block_size_ns=HOUR),
-        index=IndexOptions(enabled=True, block_size_ns=HOUR),
+        index=IndexOptions(enabled=True, block_size_ns=index_block),
         writes_to_commitlog=False, snapshot_enabled=False))
     db.open(START)
     ids = [b"m,host=h%02d,i=%03d" % (i % 8, i) for i in range(n_series)]
@@ -405,6 +406,13 @@ class TestPagedReadParity:
             assert np.allclose(a.values, b.values, rtol=1e-9, atol=0,
                                equal_nan=True), q
         assert any(out[("1", q)][1] for q in queries)
+
+
+def _same_bits(a, b):
+    assert a.labels == b.labels
+    assert a.values.shape == b.values.shape
+    np.testing.assert_array_equal(a.values.view(np.uint64),
+                                  b.values.view(np.uint64))
 
 
 @pytest.fixture
@@ -566,23 +574,17 @@ class TestHotTier:
         skipped = {"query_ids": 0, "read_many": 0, "reads_timed": 0,
                    "hit": plans, "miss": 0, "fetch_skipped": plans}
 
-        def same_bits(a, b):
-            assert a.labels == b.labels
-            assert a.values.shape == b.values.shape
-            np.testing.assert_array_equal(a.values.view(np.uint64),
-                                          b.values.view(np.uint64))
-
         v1, d1, hits1 = run()
         assert d1 == fetched and hits1 == [False] * plans
         assert len(v1.labels) > 0 and np.isfinite(v1.values).any()
         v2, d2, hits2 = run()
         assert d2 == skipped and hits2 == [True] * plans
-        same_bits(v2, v1)
+        _same_bits(v2, v1)
         monkeypatch.setenv("M3_TPU_QUERY_COMPILE", "0")
         vi, di, interpreted = run()
         assert di["query_ids"] == plans and di["hit"] == di["miss"] == 0
         assert interpreted == []
-        same_bits(v2, vi)
+        _same_bits(v2, vi)
         monkeypatch.setenv("M3_TPU_QUERY_COMPILE", "1")
 
         # any write bumps the namespace's data version: the next run
@@ -596,7 +598,7 @@ class TestHotTier:
                                   v1.values.view(np.uint64))
         v4, d4, _ = run()
         assert d4 == skipped
-        same_bits(v4, v3)
+        _same_bits(v4, v3)
         # so does a flush, which leaves every answer as it was
         version = ns.data_version()
         assert sum(bool(shard.flush(w)) for shard in ns.shards.values()
@@ -604,7 +606,7 @@ class TestHotTier:
         assert ns.data_version() != version
         v5, d5, hits5 = run()
         assert d5 == fetched and hits5 == [False] * plans
-        same_bits(v5, v3)
+        _same_bits(v5, v3)
         _v6, d6, _ = run()
         assert d6 == skipped
 
@@ -674,7 +676,399 @@ class TestHotTier:
         assert len(tier) == 0 and tier.bytes_used == 0
 
 
+# `_build_db`'s data blocks: START lies 1,600 s into an hour, so the
+# three rounds of writes fill the windows BLOCK0 .. BLOCK0 + 3 h. The
+# first two are sealed (a volume, nothing buffered), the third holds a
+# volume and buffered cold rows, the last is the head: buffered only.
+BLOCK0 = START - 1600 * NS
+HEAD = BLOCK0 + 3 * HOUR
+# query_range(start, end) of a `[10m]` plan at a step of 10 m: SEALED
+# reads [START, START + 80 m], the two sealed blocks and no other;
+# AT_HEAD reads [HEAD + 400 s, START + 3 h], the head block alone
+SEALED = (START + 10 * 60 * NS, START + 80 * 60 * NS)
+AT_HEAD = (START + 2 * HOUR + 50 * 60 * NS, START + 3 * HOUR)
+
+
+class TestRangeScopedVersion:
+    """The version in a fetch key is that of the blocks the fetch's
+    range touches (ISSUE 38): what changes another block leaves an
+    entry warm, what changes a block of the range makes it miss, and
+    the fingerprint never returns to a value it had."""
+
+    _PLANS = {"by": "sum by (host) (sum_over_time(m[10m]))",
+              "no_by": "max_over_time(m[10m])"}
+
+    def _warm(self, tmp_path, monkeypatch, span=SEALED, plan="by",
+              index_block=HOUR, name="r"):
+        """A database, and `run()` -> (vector, was it a hit, fetches it
+        made); the entry of `plan` over `span` is warm on return."""
+        monkeypatch.setenv("M3_TPU_QUERY_COMPILE", "1")
+        db, ns, ids = _build_db(str(tmp_path / name),
+                                np.random.default_rng(77),
+                                index_block=index_block)
+        eng = Engine(db, resolve_tiers=False)
+        calls = TestHotTier._count_fetches(monkeypatch)
+
+        def run():
+            before = dict(calls)
+            with explain.collect(True) as col:
+                vec, _ = eng.query_range(self._PLANS[plan], span[0],
+                                         span[1], 10 * 60 * NS)
+            run.last = vec
+            return (vec, col.compiled["hot_tier"]["hit"],
+                    {k: calls[k] - before[k] for k in calls})
+
+        v1, hit1, _ = run()
+        v2, hit2, fetches = run()
+        assert (hit1, hit2) == (False, True)
+        assert fetches == {"query_ids": 0, "read_many": 0}
+        assert len(v1.labels) > 0 and np.isfinite(v1.values).any()
+        _same_bits(v1, v2)
+        return db, ns, ids, run
+
+    @staticmethod
+    def _write(db, t_ns, value=5.0, host=0, i=0):
+        """One sample of `_build_db`'s series `i` (or of a new one)."""
+        db.write_tagged("default", b"m,host=h%02d,i=%03d" % (host, i),
+                        [(b"__name__", b"m"), (b"host", b"h%02d" % host),
+                         (b"i", b"%03d" % i)], t_ns, value)
+
+    # -- (a) another block's changes leave the entry warm --
+
+    @staticmethod
+    def _outside_write(db, ns, tmp_path):
+        TestRangeScopedVersion._write(db, START + 3 * HOUR - 10 * NS)
+
+    @staticmethod
+    def _outside_flush(db, ns, tmp_path):
+        # the cold flush of BLOCK0 + 2 h and the head's first volume
+        assert sum(bool(shard.flush(w)) for shard in ns.shards.values()
+                   for w in shard.buffer.block_starts()
+                   if w >= BLOCK0 + 2 * HOUR) > 4
+
+    @staticmethod
+    def _outside_snapshot(db, ns, tmp_path):
+        assert sum(shard.snapshot(HEAD, str(tmp_path / "snap"), 1)
+                   for shard in ns.shards.values()) == 4
+
+    @staticmethod
+    def _outside_tick(db, ns, tmp_path):
+        # a mediator cycle while the head is open: the cold flush of
+        # BLOCK0 + 2 h, no expiry, index persist and compaction
+        out = db.tick(HEAD + 30 * 60 * NS)
+        assert out["cold_flushed"] > 0 and out["expired"] == 0
+
+    @pytest.mark.parametrize("event", ["write", "flush", "snapshot", "tick"])
+    def test_other_blocks_leave_the_entry_warm(self, event, tmp_path,
+                                               monkeypatch, small_tier):
+        db, ns, ids, run = self._warm(tmp_path, monkeypatch)
+        key = ns.data_version_in(SEALED[0] - 10 * 60 * NS, SEALED[1] + 1)
+        whole = ns.data_version()
+        getattr(self, "_outside_" + event)(db, ns, tmp_path)
+        assert ns.data_version_in(SEALED[0] - 10 * 60 * NS,
+                                  SEALED[1] + 1) == key
+        # the namespace's own version moves as before (query/standing.py)
+        assert (ns.data_version() != whole) == (event != "snapshot")
+        warm, hit, fetches = run()
+        assert hit and fetches == {"query_ids": 0, "read_many": 0}
+        hottier.default().clear()
+        fresh, hit, fetches = run()
+        assert not hit and fetches == {"query_ids": 1, "read_many": 1}
+        _same_bits(warm, fresh)
+        db.close()
+
+    # -- (b) a change to a block of the range makes the next run miss --
+
+    @staticmethod
+    def _inside_warm_write(db, ns, run, tmp_path):
+        shards = list(ns.shards.values())
+        before = sum(s.warm_writes for s in shards)
+        TestRangeScopedVersion._write(db, START + 3 * HOUR - 10 * NS)
+        assert sum(s.warm_writes for s in shards) == before + 1
+        return "changed"
+
+    @staticmethod
+    def _inside_cold_write(db, ns, run, tmp_path):
+        shards = list(ns.shards.values())
+        before = sum(s.cold_writes for s in shards)
+        TestRangeScopedVersion._write(db, START + 20 * 60 * NS)
+        assert sum(s.cold_writes for s in shards) == before + 1
+        return "changed"
+
+    @staticmethod
+    def _inside_flush(db, ns, run, tmp_path):
+        TestRangeScopedVersion._write(db, START + 20 * 60 * NS)
+        assert not run()[1] and run()[1]  # warm over the buffered row
+        assert sum(bool(shard.flush(BLOCK0))
+                   for shard in ns.shards.values()) == 1
+        return "same"  # the volume swap moves no sample
+
+    @staticmethod
+    def _inside_bootstrap(db, ns, run, tmp_path):
+        assert sum(shard.bootstrap_from_fs()
+                   for shard in ns.shards.values()) >= 8
+        return "same"
+
+    @staticmethod
+    def _inside_repair(db, ns, run, tmp_path):
+        from m3_tpu.storage import peers
+
+        peer, pns, _ids = _build_db(str(tmp_path / "peer"),
+                                    np.random.default_rng(77))
+        TestRangeScopedVersion._write(peer, START + 20 * 60 * NS)
+        repaired = 0
+        for sid, shard in pns.shards.items():
+            if shard.flush(BLOCK0):
+                repaired += peers.repair_shard_block(
+                    db, "default", sid, BLOCK0,
+                    [peers.InProcessPeer(peer)]).repaired
+        peer.close()
+        assert repaired == 1
+        return "changed"
+
+    @staticmethod
+    def _inside_expiry(db, ns, run, tmp_path):
+        # the retention cutoff passes BLOCK0 and no other block
+        assert ns.expire(BLOCK0 + HOUR + 1000 * HOUR) == 4
+        return "changed"
+
+    # a placement change moves every range's version, whatever the shard
+    # holds (a fifth shard, which no series routes to, holds nothing)
+    @staticmethod
+    def _inside_add_shard(db, ns, run, tmp_path):
+        ns.add_shard(4)
+        return "same"
+
+    @staticmethod
+    def _inside_remove_shard(db, ns, run, tmp_path):
+        ns.add_shard(4)
+        assert not run()[1] and run()[1]  # warm over five shards
+        ns.remove_shard(4)
+        return "same"
+
+    @pytest.mark.parametrize("event,span", [
+        ("warm_write", AT_HEAD), ("cold_write", SEALED), ("flush", SEALED),
+        ("bootstrap", SEALED), ("repair", SEALED), ("expiry", SEALED),
+        ("remove_shard", SEALED), ("add_shard", SEALED)])
+    def test_a_block_of_the_range_makes_it_miss(self, event, span, tmp_path,
+                                                monkeypatch, small_tier):
+        db, ns, ids, run = self._warm(tmp_path, monkeypatch, span=span)
+        answer = getattr(self, "_inside_" + event)(db, ns, run, tmp_path)
+        before = run.last
+        after, hit, fetches = run()
+        assert not hit and fetches == {"query_ids": 1, "read_many": 1}
+        same = np.array_equal(after.values.view(np.uint64),
+                              before.values.view(np.uint64))
+        assert same == (answer == "same")
+        again, hit, fetches = run()
+        assert hit and fetches == {"query_ids": 0, "read_many": 0}
+        _same_bits(after, again)
+        db.close()
+
+    # -- (c) a range over two blocks misses on a write to either --
+
+    @pytest.mark.parametrize("block", [0, 1])
+    def test_two_block_range_misses_on_either(self, block, tmp_path,
+                                              monkeypatch, small_tier):
+        db, ns, ids, run = self._warm(tmp_path, monkeypatch)
+        first = ns.opts.retention.block_start(SEALED[0] - 10 * 60 * NS)
+        assert first == BLOCK0
+        assert ns.opts.retention.block_start(SEALED[1]) == BLOCK0 + HOUR
+        self._write(db, BLOCK0 + block * HOUR + 1700 * NS)
+        after, hit, fetches = run()
+        assert not hit and fetches == {"query_ids": 1, "read_many": 1}
+        db.close()
+
+    # -- (d) a series first seen in the head block, entry warm --
+
+    @pytest.mark.parametrize("index_block", [HOUR, 4 * HOUR],
+                             ids=["index_1h", "index_4h"])
+    @pytest.mark.parametrize("plan", sorted(_PLANS))
+    def test_new_series_at_the_head_keeps_the_answer(
+            self, plan, index_block, tmp_path, monkeypatch, small_tier):
+        """Under a 4 h index block the new series is matched by the
+        sealed range's index query too (it holds no sample there, so the
+        fetch drops it): warm and fresh agree in labels, rows and NaN
+        masks."""
+        db, ns, ids, run = self._warm(tmp_path, monkeypatch, plan=plan,
+                                      index_block=index_block)
+        from m3_tpu.index.query import Matcher, MatchType, matchers_to_query
+
+        iq = matchers_to_query([Matcher(MatchType.EQUAL, b"__name__", b"m")])
+        matched = len(ns.index.query(iq, SEALED[0], SEALED[1]))
+        self._write(db, START + 3 * HOUR - 10 * NS, host=99, i=999)
+        assert len(ns.index.query(iq, SEALED[0], SEALED[1])) \
+            == matched + (index_block > HOUR)
+        warm, hit, fetches = run()
+        assert hit and fetches == {"query_ids": 0, "read_many": 0}
+        hottier.default().clear()
+        fresh, hit, _ = run()
+        assert not hit
+        assert not any(lb.get("host") == "h99" for lb in fresh.labels)
+        _same_bits(warm, fresh)
+        db.close()
+
+    # -- (e) the fingerprint never returns to a value it had --
+
+    def _fingerprints_across_expiry(self, db, ns):
+        span = (SEALED[0] - 10 * 60 * NS, SEALED[1] + 1)
+        seen = [ns.data_version_in(*span)]
+        for k in range(3):
+            self._write(db, START + (20 + k) * 60 * NS)
+            seen.append(ns.data_version_in(*span))
+        # everything expires: the blocks' volumes and buffered rows go
+        assert ns.expire(HEAD + 2000 * HOUR) > 0
+        seen.append(ns.data_version_in(*span))
+        for k in range(6):
+            self._write(db, START + (20 + k) * 60 * NS)
+            seen.append(ns.data_version_in(*span))
+        return seen
+
+    def _fingerprints_across_placement(self, db, ns):
+        span = (SEALED[0] - 10 * 60 * NS, SEALED[1] + 1)
+        seen = [ns.data_version_in(*span)]
+        for _ in range(3):
+            # a shard that comes back is a new object with new counters:
+            # the sum alone returns to what it was, the epoch does not
+            ns.remove_shard(0)
+            seen.append(ns.data_version_in(*span))
+            ns.add_shard(0)
+            seen.append(ns.data_version_in(*span))
+        assert len({fp[2] for fp in seen}) < len(seen)
+        return seen
+
+    @pytest.mark.parametrize("across", ["expiry", "placement"])
+    def test_fingerprint_does_not_alias(self, across, tmp_path):
+        db, ns, ids = _build_db(str(tmp_path / "e"),
+                                np.random.default_rng(77))
+        seen = getattr(self, "_fingerprints_across_" + across)(db, ns)
+        assert len(set(seen)) == len(seen)
+        db.close()
+
+    @pytest.mark.parametrize("path", ["write_tagged", "write_batch",
+                                      "namespace_write_tagged"])
+    def test_series_is_indexed_before_its_version_moves(self, path, tmp_path,
+                                                        monkeypatch):
+        """The fingerprint that holds a series' first sample also matches
+        the series: every write path indexes before the shard's buffer
+        append bumps the block's version, or a fetch in between would
+        keep an answer without the series under the version that has
+        it."""
+        from m3_tpu.index.query import Matcher, MatchType, matchers_to_query
+        from m3_tpu.storage.shard import Shard
+        from m3_tpu.utils.ident import encode_tags, tags_to_id
+
+        db, ns, ids = _build_db(str(tmp_path / "o"),
+                                np.random.default_rng(77))
+        iq = matchers_to_query([Matcher(MatchType.EQUAL, b"host", b"h99")])
+        t = START + 3 * HOUR - 10 * NS
+        matched_at_bump = []
+
+        def bump(shard, block_start):
+            matched_at_bump.append(len(ns.index.query(iq, t, t + 1)))
+            return inner(shard, block_start)
+        inner = Shard._bump_locked
+        monkeypatch.setattr(Shard, "_bump_locked", bump)
+        name = b"m,host=h99,i=999"
+        tags = [(b"__name__", b"m"), (b"host", b"h99"), (b"i", b"999")]
+        if path == "write_tagged":
+            db.write_tagged("default", name, tags, t, 1.0)
+        elif path == "write_batch":
+            assert db.write_batch("default", [(name, tags, t, 1.0)]) == [None]
+        else:
+            fields = [(b"__name__", name), *tags]
+            ns.write_tagged(tags_to_id(name, tags), fields, t, bits(1.0),
+                            encode_tags(fields))
+        assert matched_at_bump == [1]
+        db.close()
+
+    @pytest.mark.parametrize("swap,span", [("repair", SEALED),
+                                           ("peer_bootstrap", AT_HEAD)])
+    def test_swap_moves_the_version_though_its_index_phase_raises(
+            self, swap, span, tmp_path, monkeypatch, small_tier):
+        """`storage/peers.py` indexes what a peer sent AFTER the volume
+        swap, and a peer's tags may not decode: the swap's own bump must
+        not wait for that, or the entry over the block stays warm over
+        the old volume for good (a retry skips a block it has)."""
+        from m3_tpu.storage import peers
+        from m3_tpu.utils import ident
+
+        db, ns, ids, run = self._warm(tmp_path, monkeypatch, span=span)
+        peer, pns, _ids = _build_db(str(tmp_path / "peer"),
+                                    np.random.default_rng(77))
+        block = BLOCK0 if swap == "repair" else HEAD
+        # to a series the block's index has: the index phase will add none
+        i = next(i for i in range(len(ids)) for sid in ns.series_ids()
+                 if sid.endswith(b"|i=%03d" % i)
+                 and len(ns.read(sid, block, block + HOUR)[0]))
+        self._write(peer, START + 20 * 60 * NS if swap == "repair"
+                    else START + 3 * HOUR - 10 * NS, host=i % 8, i=i)
+        flushed = [sid for sid, shard in pns.shards.items()
+                   if shard.flush(block)]
+        assert flushed and not any(block in shard._filesets
+                                   for shard in ns.shards.values()) \
+            == (swap == "peer_bootstrap")
+
+        def undecodable(blob):
+            raise ValueError("tags do not decode")
+        raised = 0
+        with monkeypatch.context() as m:
+            m.setattr(ident, "decode_tags", undecodable)
+            for sid in flushed:
+                with pytest.raises(ValueError, match="do not decode"):
+                    if swap == "repair":
+                        peers.repair_shard_block(
+                            db, "default", sid, block,
+                            [peers.InProcessPeer(peer)])
+                    else:
+                        peers.bootstrap_shard_from_peers(
+                            db, "default", sid, [peers.InProcessPeer(peer)])
+                raised += 1
+        peer.close()
+        assert raised == len(flushed)
+        before = run.last
+        after, hit, fetches = run()
+        assert not hit and fetches == {"query_ids": 1, "read_many": 1}
+        assert not np.array_equal(after.values.view(np.uint64),
+                                  before.values.view(np.uint64))
+        db.close()
+
+    def test_untouched_block_reads_zero(self, tmp_path):
+        """A block never written or loaded is version 0 and part of the
+        fingerprint; a range from time 0 reads every touched block."""
+        db, ns, ids = _build_db(str(tmp_path / "z"),
+                                np.random.default_rng(77))
+        far = HEAD + 500 * HOUR
+        assert ns.data_version_in(far, far + HOUR) == (0, 4, 0)
+        self._write(db, far + NS)
+        assert ns.data_version_in(far, far + HOUR) == (0, 4, 1)
+        for shard in ns.shards.values():
+            assert len(shard._block_versions) <= 5
+            assert shard.data_version_in(BLOCK0 - 90 * HOUR, HEAD) == sum(
+                v for bs, v in shard._block_versions.items() if bs <= HEAD)
+            assert shard.data_version_in(0, far) == sum(
+                shard._block_versions.values())
+        db.close()
+
+
 class TestFetchKey:
+    @pytest.mark.parametrize("where,moves", [("head", False),
+                                             ("inside", True)])
+    def test_fetch_key_is_scoped_to_the_range(self, where, moves, tmp_path):
+        rng = np.random.default_rng(13)
+        db, ns, ids = _build_db(str(tmp_path / "fk"), rng)
+        eng = Engine(db, resolve_tiers=False)
+        from m3_tpu.query.promql import parse
+
+        sel = parse("m").expr if hasattr(parse("m"), "expr") else parse("m")
+        grid = np.array([START + 20 * 60 * NS], np.int64)
+        key = eng._resolve_fetch(sel, grid, 0)[-1]
+        t = {"head": START + 3 * HOUR - 10 * NS,
+             "inside": START + 18 * 60 * NS}[where]
+        TestRangeScopedVersion._write(db, t, 1.0)
+        assert (eng._resolve_fetch(sel, grid, 0)[-1] != key) == moves
+        db.close()
+
     def test_fetch_key_tracks_data_version(self, tmp_path):
         rng = np.random.default_rng(13)
         db, ns, ids = _build_db(str(tmp_path / "fk"), rng)

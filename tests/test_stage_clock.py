@@ -286,8 +286,8 @@ class TestTrackerIncludesTheWait:
         from m3_tpu.encoding.m3tsz import hostpath, tpu
         from m3_tpu.utils.xtime import TimeUnit
 
-        def blocking_decode(words, unit, max_points):
-            real = real_decode(words, unit, max_points=max_points)
+        def blocking_decode(words, unit, **kw):
+            real = real_decode(words, unit, **kw)
             return real._replace(times=_Blocks(np.asarray(real.times)))
 
         real_decode = tpu.decode
@@ -381,7 +381,7 @@ class TestProgramNames:
 
         text = tpu._decode_jit.lower(
             jnp.zeros((2, 4), jnp.uint64), TimeUnit.SECOND, 16,
-            "scatter").as_text(debug_info=True)
+            "scatter", jnp.int32(2)).as_text(debug_info=True)
         assert "m3.decode.scan" in text
 
 
