@@ -324,7 +324,10 @@ class Namespace:
     def _read_many_traced(self, series_ids, start_ns, end_ns,
                           want_ragged: bool = False):
         from m3_tpu.storage import pipeline
+        from m3_tpu.utils.instrument import default_registry
 
+        default_registry().record_many((), (
+            ("storage.read_series", (), len(series_ids)),))
         by_shard: dict[int, list[int]] = {}
         for i, shard_id in enumerate(self.shards_of(series_ids)):
             if shard_id not in self.shards:
@@ -368,6 +371,8 @@ class Namespace:
         merge_dedup resolves last-write-wins.
         """
         from m3_tpu.ops import ragged
+        from m3_tpu.utils import trace
+        from m3_tpu.utils.instrument import default_registry
 
         # fragments of the namespace-level ragged combine (one merged
         # per-shard CSR each) are only tracked when the caller asked for
@@ -383,13 +388,20 @@ class Namespace:
             groups.extend(shard.plan_read_groups(sids, start_ns, end_ns,
                                                  parts))
         run_read_groups(groups)
-        for plan in plans:
-            self._finalize_shard_read(plan, start_ns, end_ns, out, frags)
-        if frags is not None:
-            # pure O(N) scatter: each fragment is already merged and
-            # filtered, and every row lives in exactly one fragment —
-            # the combine just lands rows at their query-order positions
-            return ragged.combine_fragments(frags, len(series_ids))
+        # the request thread's CSR assembly: a span (transparent to the
+        # stage clock, so `read_many` keeps its meaning) and its histogram
+        with trace.span(trace.READ_ASSEMBLE, series=len(series_ids),
+                        groups=len(groups)), \
+                default_registry().root_scope("db") \
+                .histogram("read_assemble_seconds"):
+            for plan in plans:
+                self._finalize_shard_read(plan, start_ns, end_ns, out, frags)
+            if frags is not None:
+                # pure O(N) scatter: each fragment is already merged and
+                # filtered, and every row lives in exactly one fragment —
+                # the combine just lands rows at their query-order
+                # positions
+                return ragged.combine_fragments(frags, len(series_ids))
         return out
 
     def _finalize_shard_read(self, plan, start_ns, end_ns, out,

@@ -67,18 +67,28 @@ class _FilesetReadGroup:
     def consume(self, payload) -> tuple:
         """Account the probe, land the hits, and return the group's
         ``decode_misses`` entry."""
-        from m3_tpu.utils import querystats
-
         keys, cached, miss_idx, streams = payload
         parts = self.parts
-        querystats.record(
-            cache_hits=len(self.series_ids) - len(miss_idx),
-            cache_misses=len(miss_idx))
+        count_block_cache(len(self.series_ids) - len(miss_idx),
+                          len(miss_idx))
         if cached is not None:
             for i, hit in enumerate(cached):
                 if hit is not None and len(hit[0]):
                     parts[i].append(hit)
         return keys, miss_idx, streams, parts
+
+
+def count_block_cache(hits: int, misses: int) -> None:
+    """One (shard, block) group's probe of the decoded-block cache, one
+    lookup a series: on the query's own record (``?explain``) and on the
+    process's counters ``storage_block_cache_hit`` / ``_miss``."""
+    from m3_tpu.utils import querystats
+    from m3_tpu.utils.instrument import default_registry
+
+    querystats.record(cache_hits=hits, cache_misses=misses)
+    default_registry().record_many((), (
+        ("storage.block_cache.hit", (), hits),
+        ("storage.block_cache.miss", (), misses)))
 
 
 def decode_misses(pending: list[tuple], opts: NamespaceOptions,
@@ -446,10 +456,7 @@ class Shard:
                         parts[i].append((ct, cv))
                     continue
                 miss_idx.append(i)
-            from m3_tpu.utils import querystats
-
-            querystats.record(cache_hits=n - len(miss_idx),
-                              cache_misses=len(miss_idx))
+            count_block_cache(n - len(miss_idx), len(miss_idx))
             if not miss_idx:
                 continue
             # batched fetch: one merge-join walk of the volume's index for

@@ -21,6 +21,11 @@ HISTOGRAMS = {
     "write_batch_seconds",      # storage.db fused batch write
     "write_batch_size",         # storage.db entries per batch
     "read_many_seconds",        # storage.ns fused batch read
+    "read_assemble_seconds",    # db: the request thread's CSR assembly
+    #                             of a pipelined batched read (every
+    #                             shard's finalize + the namespace's
+    #                             combine_fragments; span
+    #                             storage.ns.read_assemble)
     "shard_flush_seconds",      # shard warm flush
     "commitlog_fsync_seconds",  # WAL fsync wall time
     "persist_seconds",          # fileset/index/kv persist (per-scope)
@@ -119,6 +124,15 @@ TIMERS = {
 #       not on a write to any other block
 #   storage_hot_tier_fetch_skipped                   plans served from a
 #       warm entry with no index match and no read
+#
+# Batched reads (storage/namespace.py, storage/shard.py), storage scope:
+#   storage_read_series                        series ids a namespace
+#       read_many / read_many_ragged reads, added to once per call
+#   storage_block_cache_hit / storage_block_cache_miss   (series, block)
+#       lookups of the decoded-block cache, added to once per (shard,
+#       block) group (count_block_cache; the same split rides the
+#       query's ?explain record): hit + miss over storage_read_series is
+#       the blocks a read series spans
 #
 # Series -> shard routing (storage/sharding.py ShardRoutes):
 #   storage_shard_route_hit / storage_shard_route_miss   series ids

@@ -70,6 +70,9 @@ SHARD_FLUSH = "storage.shard.flush"
 SESSION_FETCH = "client.session.fetch_many"
 AGG_FLUSH = "aggregator.flush"
 READ_MANY = "storage.ns.read_many"
+# the request thread's CSR assembly of a batched read, every shard's
+# finalize and the namespace-level combine: a plain span, not a stage
+READ_ASSEMBLE = "storage.ns.read_assemble"
 DBNODE_HANDLE = "dbnode.handle"
 FANOUT_READ = "query.fanout.read_many"
 MSG_SEND = "msg.producer.send"

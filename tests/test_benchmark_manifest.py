@@ -15,6 +15,7 @@ import os
 import re
 import sys
 
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -288,3 +289,224 @@ def test_a_write_or_tick_metric_reads_a_stage_or_counter_the_program_has(
         named = re.search(r'stage="([^"]+)"', rx)
         if named:
             assert named.group(1).replace("\\.", ".") in stages
+
+
+# -- tsbs-cpu-14h.ranges (ISSUE 39) -------------------------------------------
+
+RANGES = "tsbs-cpu-14h.ranges"
+RANGES_METRICS = [m["name"] for m in MANIFEST["per_layer"]
+                  if RANGES in m["workloads"]]
+RANGES_TWINS = [("hot_tier_hit_pct.ranges", "hot_tier_hit_pct"),
+                ("compiles_in_window.ranges", "compiles_in_window.dash"),
+                ("device_idle_pct.ranges", "device_idle_pct.dash")] + [
+    (name + ".ranges", name) for name in (
+        "api_query_ms", "fetch_ms", "index_match_ms", "decode_host_ms",
+        "decode_device_wait_ms", "decode_streams_per_launch", "slab_prep_ms",
+        "plan_device_wait_ms", "render_ms", "query_cpu_ms")]
+RANGES_NEW = ["block_cache_hit_pct.ranges", "blocks_per_series.ranges",
+              "fetch_assemble_ms.ranges"]
+# TSBS uses/devops: (range, step and window, hosts, fields) of each type
+TSBS_RANGES = {"single-groupby-1-1-12": (43200, 60, 1, 1),
+               "single-groupby-5-1-12": (43200, 60, 1, 5),
+               "cpu-max-all-1": (28800, 3600, 1, 10),
+               "cpu-max-all-8.8h": (28800, 3600, 8, 10),
+               "double-groupby-1.12h": (43200, 3600, "all", 1)}
+
+
+def _ranges_cell():
+    return manifest._load(BENCH, "workloads", RANGES + ".json")
+
+
+def _ranges_kind():
+    from run import load_traffic
+
+    return load_traffic("promql_ranges").prepare.__globals__
+
+
+def test_the_ranges_cell_lists_its_metrics_and_no_roofline():
+    assert sorted(RANGES_METRICS) == sorted(
+        [twin for twin, _orig in RANGES_TWINS] + RANGES_NEW)
+    assert not [n for n in RANGES_METRICS if "roofline" in n or "mfu" in n]
+    assert all(m["workloads"] == [RANGES] for m in MANIFEST["per_layer"]
+               if RANGES in m["workloads"])
+    # only the idle share reads the slice, and any program in it will do
+    assert [n for n in RANGES_METRICS
+            if _metric(n)["reader"].startswith("trace_")] \
+        == ["device_idle_pct.ranges"]
+    # the cell reports both end-to-end metrics the kind measures
+    reported = sorted(m["name"] for m in MANIFEST["end_to_end"]
+                      if RANGES in m.get("workloads", []))
+    assert reported == sorted(_ranges_kind()["Traffic"].e2e)
+    assert "report" not in _ranges_cell()
+
+
+@pytest.mark.parametrize("twin,original", RANGES_TWINS)
+def test_a_ranges_twin_reads_what_its_original_reads(twin, original):
+    got, spec = _metric(twin), _metric(original)
+    assert (got["reader"], got.get("args", {})) == \
+        (spec["reader"], spec.get("args", {}))
+    assert (got["unit"], got["layer"], got["source"], got["better"]) \
+        == (spec["unit"], spec["layer"], spec["source"], spec["better"])
+    assert got["cells"] == [RANGES]
+
+
+@pytest.mark.parametrize("qtype", sorted(TSBS_RANGES))
+def test_a_ranges_query_type_carries_tsbs_own_range_and_step(qtype):
+    spec = manifest._load(BENCH, "queries", qtype + ".json")
+    range_s, step_s, hosts, fields = TSBS_RANGES[qtype]
+    assert (spec["range_s"], spec["step_s"], spec["window_s"]) == \
+        (range_s, step_s, step_s)
+    assert (spec["hosts"], len(spec["fields"])) == (hosts, fields)
+    assert qtype in _ranges_cell()["traffic_params"]["mix"]
+    # no `__name__` under `by` and no comparison (PERF.md section 7)
+    assert "__name__" not in spec.get("by", []) and ">" not in spec["promql"]
+
+
+def test_the_ranges_configuration_is_seven_whole_blocks_of_tsbs_cpu_2k():
+    cfg = manifest._load(BENCH, "configs", "tsbs-cpu-14h.json")
+    base = manifest._load(BENCH, "configs", "tsbs-cpu-2k.json")
+    assert cfg["node"] == base["node"]
+    assert cfg["guarantees"] == base["guarantees"]
+    assert (cfg["scale"], cfg["series"], cfg["interval_s"],
+            cfg["history_points"]) == (167, 1670, 10, 5040)
+    # 14 h of 10 s readings: seven 2 h blocks, 8.42 M samples
+    assert cfg["history_points"] * cfg["interval_s"] == 7 * 2 * 3600
+    assert cfg["series"] * cfg["history_points"] == 8_416_800
+    assert sorted(cfg["reduced"]) == ["history_points", "scale"]
+    assert cfg["rehearse"]["scale"] >= 8 + 4      # cpu-max-all-8.8h draws 8
+    assert _ranges_cell()["chips"] == 1
+
+
+def test_the_window_starts_are_made_from_the_seed_alone_and_fit_the_span():
+    from harness import ranges
+
+    kind = _ranges_kind()
+    types = {q: manifest._load(BENCH, "queries", q + ".json")
+             for q in TSBS_RANGES}
+    points = 5040
+    starts = {q: ranges.n_starts(spec, points) for q, spec in types.items()}
+    # whole minutes on which a 12 h (8 h) window fits 14 h less 10 s
+    assert starts["double-groupby-1.12h"] == 120
+    assert starts["cpu-max-all-1"] == 360
+    deck = sorted(types)
+
+    def made(seed):
+        r = kind["Requests"](np.random.default_rng([seed, 3]), deck, types,
+                             167, starts)
+        return [r.get(i) for i in range(500)]
+
+    a, b, c = made(2**31 + 39), made(2**31 + 39), made(39)
+    assert a == b and a != c
+    t_first = 1_790_006_400 * ranges.NS
+    t_last = t_first + ranges.span_ns(points)
+    seen = {q: set() for q in types}
+    for name, hosts, offset in a:
+        spec = types[name]
+        at = ranges.eval_ts(spec, t_first, offset)
+        start = int(at[0]) - spec["step_s"] * ranges.NS
+        assert t_first <= start and int(at[-1]) <= t_last
+        assert (start - t_first) % (60 * ranges.NS) == 0
+        assert len(at) == spec["range_s"] // spec["step_s"]
+        assert len(set(hosts)) == len(hosts)
+        seen[name].add(offset)
+    # uniform: a hundred windows of a type start at many minutes
+    assert all(len(offsets) > 40 for offsets in seen.values())
+
+
+def _ranges_window(queries=500):
+    """(before, after): /metrics as parsed at the window's two ends."""
+    def at(k):
+        m = _stage_keys("query_range", {
+            "request": 1, "parse_plan": 1, "query_ids": 0.9,
+            "read_many": 0.9, "read_many.gather": 0.9, "decode.host": 0.3,
+            "decode.device_wait": 0.3, "slab_prep": 0.9,
+            "plan.device_wait": 1, "render": 1}, k * queries, 0.05, 0.02)
+        m['decode_batch_streams{path="device"}'] = k * queries * 0.3 * 60
+        m["storage_hot_tier_hit"] = k * 50.0
+        m["storage_hot_tier_miss"] = k * 450.0
+        m["storage_block_cache_hit"] = k * 450 * 54 * 0.9
+        m["storage_block_cache_miss"] = k * 450 * 54 * 0.1
+        m["storage_read_series"] = k * 450 * 9.0
+        m["db_read_assemble_seconds_sum"] = k * 450 * 0.02
+        m["db_read_assemble_seconds_count"] = k * 450.0
+        m["coordinator_request_seconds_sum"] = k * queries * 0.3
+        m["coordinator_request_seconds_count"] = float(k * queries)
+        m["jit_run[miss]"] = 3.0
+        return m
+
+    return at(1), at(2)
+
+
+def _ranges_reading(before, after):
+    from harness.readers import Reading
+
+    facts = _ranges_kind()["window_facts"](
+        _ranges_cell()["traffic_params"]["window_facts"], before, after)
+    return Reading(before, after, 51.0, facts, {"hbm_bytes_per_s": 819e9},
+                   _trace(), 2.2)
+
+
+@pytest.mark.parametrize("name", RANGES_METRICS)
+def test_a_ranges_metric_reads_a_number_from_a_sound_window(name):
+    from harness.readers import READERS
+
+    spec = _metric(name)
+    value = READERS[spec["reader"]](_ranges_reading(*_ranges_window()),
+                                    **spec.get("args", {}))
+    assert isinstance(value, float) and value >= 0.0
+    want = {"block_cache_hit_pct.ranges": 90.0,
+            "blocks_per_series.ranges": 6.0,
+            "fetch_assemble_ms.ranges": 1000 * 450 * 0.02 / 500,
+            "hot_tier_hit_pct.ranges": 10.0,
+            "api_query_ms.ranges": 300.0, "render_ms.ranges": 50.0,
+            "fetch_ms.ranges": 0.9 * 50.0,
+            "decode_streams_per_launch.ranges": 60.0,
+            "compiles_in_window.ranges": 0.0}
+    if name in want:
+        assert value == pytest.approx(want[name])
+    if spec["unit"] == "%":
+        assert value <= 100.0
+
+
+@pytest.mark.parametrize("name", RANGES_METRICS)
+def test_a_ranges_metric_is_left_out_where_the_program_lacks_its_source(
+        name):
+    """The parent's program has neither the block cache's counters nor
+    the series counter nor the assembly's histogram: the three new
+    metrics' readers return nothing and do not raise; the twins read."""
+    from harness.readers import READERS
+
+    new = re.compile(r"storage_block_cache_|storage_read_series"
+                     r"|db_read_assemble_seconds")
+    before, after = ({k: v for k, v in m.items() if not new.search(k)}
+                     for m in _ranges_window())
+    spec = _metric(name)
+    value = READERS[spec["reader"]](_ranges_reading(before, after),
+                                    **spec.get("args", {}))
+    assert (value is None) == (name in RANGES_NEW)
+
+
+@pytest.mark.parametrize("name", RANGES_NEW)
+def test_a_new_ranges_metric_reads_a_counter_or_histogram_the_program_has(
+        name):
+    """Each name the three new metrics read is a counter or a histogram
+    of storage/ (read as text: no import of the program)."""
+    spec = _metric(name)
+    regexes = list(spec["args"].get("num", [])) + list(
+        spec["args"].get("den", []))
+    if spec["reader"] == "fact_ratio":
+        facts = _ranges_cell()["traffic_params"]["window_facts"]
+        regexes = [rx for fact in (spec["args"]["num"], spec["args"]["den"])
+                   for rx in facts[fact]["keys"]]
+    text = "".join(open(os.path.join(REPO, "m3_tpu", "storage", f)).read()
+                   for f in ("shard.py", "namespace.py"))
+    for rx in regexes:
+        m = re.fullmatch(r"storage_(\w+?)_(hit|miss|series)", rx)
+        if m:
+            assert f'"storage.{m.group(1)}.{m.group(2)}"' in text \
+                or f'"storage.{m.group(1)}_{m.group(2)}"' in text
+        elif rx.startswith("db_"):
+            leaf = rx[len("db_"):-len("_sum")]
+            assert f'.histogram("{leaf}")' in text
+        else:
+            assert "query_stage_seconds_count" in rx
