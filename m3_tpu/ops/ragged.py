@@ -6,7 +6,8 @@ paged KV blocks): variable-length series stay CONCATENATED with an
 and the whole-query compiler's slab prep already consume — instead of
 being padded to rectangles or materialized as one Python array pair per
 series.  This module is the pure-kernel layer of that layout, shared by
-the storage read finalize (`Shard.finish_read_many`), the paged buffer
+the storage read finalize (`Namespace._assemble_read`,
+`Shard.finish_read_many`), the paged buffer
 seal (`ShardBuffer.seal_csr`) and the length-bucketed ragged encode
 (`hostpath.encode_blocks_ragged`):
 
@@ -16,9 +17,10 @@ seal (`ShardBuffer.seal_csr`) and the length-bucketed ragged encode
   compress pass for the range filter — replacing the per-series
   ``np.concatenate`` + ``merge_dedup`` calls that profiled at ~15% of
   the sparse read path (PR 14 handoff).
-- ``assemble_rows`` builds the CSR from per-row part lists with slice
-  assigns into ONE preallocated pair of columns (no per-series
-  concatenate objects).
+- ``assemble_rows`` builds the CSR from per-row part lists with ONE
+  bytes join a column (``join_rows``), then one ``merge_csr`` — no
+  per-series concatenate objects, and no release of the interpreter
+  lock per part.
 - ``length_buckets`` groups rows of similar length so a batched
   rectangular consumer (the device block encoder) pads each row only to
   its bucket's max, never the global max — the ingest-side padding tax.
@@ -108,10 +110,41 @@ def merge_csr(times: np.ndarray, vbits: np.ndarray, offsets: np.ndarray,
 
 def assemble_rows(parts_rows: list[list[tuple[np.ndarray, np.ndarray]]],
                   start_ns: int | None = None, end_ns: int | None = None):
-    """(times, vbits, offsets) CSR from per-row part lists.
+    """(times, vbits, offsets) CSR from per-row part lists: ``join_rows``
+    then ``merge_csr``'s last-write-wins and range filter."""
+    times, vbits, offsets, _, _ = join_rows(parts_rows)
+    return merge_csr(times, vbits, offsets, start_ns, end_ns)
+
+
+_TIMES = np.dtype(np.int64)
+_VBITS = np.dtype(np.uint64)
+
+
+def _join(parts: list, dtype: np.dtype) -> tuple[np.ndarray, list[int]]:
+    """ONE copy of ``parts`` into a new column of ``dtype``, bit for bit
+    ``np.concatenate`` of the parts cast to ``dtype``.  A bytes join
+    holds the interpreter lock for the whole copy; ``np.concatenate``
+    gives it away and takes it back around every part longer than
+    numpy's threshold (about 500 elements), and under load each take
+    waits behind every other thread.  A part that is not a C-contiguous
+    array of ``dtype`` is first made one in place in ``parts``; their
+    indices come back."""
+    apart = []
+    for k, p in enumerate(parts):
+        if p.dtype != dtype or not p.flags.c_contiguous:
+            parts[k] = np.ascontiguousarray(p, dtype)
+            apart.append(k)
+    return np.frombuffer(bytearray().join(parts), dtype), apart
+
+
+def join_rows(parts_rows: list[list[tuple[np.ndarray, np.ndarray]]]):
+    """Unmerged (times, vbits, offsets) CSR of per-row part lists, with
+    the number of parts it copied and of those that first needed a
+    conversion copy of their own (not C-contiguous, or not int64 times
+    / uint64 value bits).
 
     The outer loop only FLATTENS (list appends); the data moves in ONE
-    np.concatenate per column — no per-series concatenate objects, no
+    copy per column (``_join``) — no per-series concatenate objects, no
     per-part slice assigns.  Rows arrive in order, so part data is
     already row-contiguous and the offsets come from a length scatter;
     each row's part order is preserved, which is what keeps
@@ -137,16 +170,16 @@ def assemble_rows(parts_rows: list[list[tuple[np.ndarray, np.ndarray]]],
                 lo(n)
     offsets = np.zeros(R + 1, np.int64)
     if not flat_t:
-        return np.empty(0, np.int64), np.empty(0, np.uint64), offsets
+        return np.empty(0, np.int64), np.empty(0, np.uint64), offsets, 0, 0
     # rows_of is non-decreasing (outer loop order): a weighted bincount
     # scatters the per-part lengths into per-row counts in one pass
     counts = np.bincount(np.asarray(rows_of, np.int64),
                          weights=np.asarray(lens_of, np.float64),
                          minlength=R).astype(np.int64)
     np.cumsum(counts, out=offsets[1:])
-    times = np.concatenate(flat_t)
-    vbits = np.concatenate(flat_v)
-    return merge_csr(times, vbits, offsets, start_ns, end_ns)
+    times, apart_t = _join(flat_t, _TIMES)
+    vbits, apart_v = _join(flat_v, _VBITS)
+    return times, vbits, offsets, len(flat_t), len(set(apart_t + apart_v))
 
 
 def pairs_to_csr(pairs: list[tuple[np.ndarray, np.ndarray]]):
@@ -176,32 +209,6 @@ def split_csr(times: np.ndarray, vbits: np.ndarray, offsets: np.ndarray
     return [(times[offsets[i]:offsets[i + 1]],
              vbits[offsets[i]:offsets[i + 1]])
             for i in range(len(offsets) - 1)]
-
-
-def combine_fragments(frags: list, n_rows: int):
-    """Combine already-merged CSR fragments into one CSR ordered by
-    target row id — the namespace-level combine: each shard's finalize
-    produced a merged CSR over ITS series, and every target row lives in
-    exactly ONE fragment, so this is a pure O(N) scatter (no sort).
-    ``frags``: [(row_ids [R_f] int64, times, vbits, offsets)]."""
-    counts = np.zeros(n_rows, np.int64)
-    for idxs, _t, _v, offs in frags:
-        counts[idxs] = np.diff(offs)
-    offsets = np.zeros(n_rows + 1, np.int64)
-    np.cumsum(counts, out=offsets[1:])
-    n = int(offsets[-1])
-    times = np.empty(n, np.int64)
-    vbits = np.empty(n, np.uint64)
-    for idxs, t, v, offs in frags:
-        if not len(t):
-            continue
-        lens = np.diff(offs)
-        within = np.arange(len(t), dtype=np.int64) \
-            - np.repeat(offs[:-1], lens)
-        dst = np.repeat(offsets[idxs], lens) + within
-        times[dst] = t
-        vbits[dst] = v
-    return times, vbits, offsets
 
 
 def length_buckets(lens: np.ndarray, factor: float = 2.0):

@@ -298,7 +298,7 @@ class Namespace:
     def read_many_ragged(self, series_ids: list[bytes], start_ns: int,
                          end_ns: int):
         """Batch read returning the RAGGED (times, vbits, offsets) CSR
-        aligned to `series_ids` (ROADMAP #3): the per-shard finalize
+        aligned to `series_ids` (ROADMAP #3): the read's one finalize
         hands its merged columns straight through — no per-series tuple
         materialization — and the resolver/engine feed the CSR directly
         into `RaggedSeries`, which is exactly what the whole-query
@@ -337,14 +337,14 @@ class Namespace:
         chunk = len(series_ids) or 1
         if limits is not None and getattr(limits, "max_datapoints", 0):
             chunk = min(chunk, self.READ_MANY_LIMIT_CHUNK)
-        out: list = [None] * len(series_ids)
         if pipeline.active() and chunk >= len(series_ids):
             # pipelined dataflow (no datapoint-limit chunking): ONE
             # flattened schedule of per-(shard, block) gather legs
             # across every shard, then one decode of what they missed
             return self._read_many_pipelined(series_ids, by_shard,
-                                             start_ns, end_ns, out,
+                                             start_ns, end_ns,
                                              want_ragged=want_ragged)
+        out: list = [None] * len(series_ids)
         for shard_id, idxs in by_shard.items():
             shard = self.shards[shard_id]
             for lo in range(0, len(idxs), chunk):
@@ -358,69 +358,85 @@ class Namespace:
         return out
 
     def _read_many_pipelined(self, series_ids, by_shard, start_ns, end_ns,
-                             out, want_ragged: bool = False):
+                             want_ragged: bool = False):
         """Per-(shard, block) groups through the executor seam
         (shard.run_read_groups): the fileset gathers of every shard run
         on the pool while this thread lands the cache hits of those that
         have arrived; when the last has, ONE batched decode takes what
-        all groups missed, and then the shards' series FINALIZE (buffer
-        merge + limits accounting, the partial columns downstream host
-        prep consumes). Results are identical to the serial path: the
-        groups' hits and decoded misses land in the same nested order,
-        and per-series parts keep the filesets-then-buffer order
-        merge_dedup resolves last-write-wins.
+        all groups missed, and then ONE finalize over every row of every
+        shard (`_assemble_read`). Results are identical to the serial
+        path: the groups' hits and decoded misses land in the same
+        nested order, and per-series parts keep the filesets-then-buffer
+        order merge_dedup resolves last-write-wins.
         """
         from m3_tpu.ops import ragged
         from m3_tpu.utils import trace
         from m3_tpu.utils.instrument import default_registry
 
-        # fragments of the namespace-level ragged combine (one merged
-        # per-shard CSR each) are only tracked when the caller asked for
-        # the CSR back
-        frags: list | None = [] if want_ragged else None
+        # one parts list a row, in query order: a shard's groups land
+        # their hits and decoded misses in the same list objects, so the
+        # read's one assembly finds every row where the query has it
+        parts: list[list] = [[] for _ in series_ids]
         groups = []
         plans = []
         for shard_id, idxs in by_shard.items():
             shard = self.shards[shard_id]
             sids = [series_ids[i] for i in idxs]
-            parts: list[list] = [[] for _ in idxs]
-            plans.append((shard, idxs, sids, parts))
+            rows = [parts[i] for i in idxs]
+            plans.append((shard, idxs, sids, rows))
             groups.extend(shard.plan_read_groups(sids, start_ns, end_ns,
-                                                 parts))
+                                                 rows))
         run_read_groups(groups)
         # the request thread's CSR assembly: a span (transparent to the
         # stage clock, so `read_many` keeps its meaning) and its histogram
         with trace.span(trace.READ_ASSEMBLE, series=len(series_ids),
-                        groups=len(groups)), \
+                        groups=len(groups)) as sp, \
                 default_registry().root_scope("db") \
                 .histogram("read_assemble_seconds"):
-            for plan in plans:
-                self._finalize_shard_read(plan, start_ns, end_ns, out, frags)
-            if frags is not None:
-                # pure O(N) scatter: each fragment is already merged and
-                # filtered, and every row lives in exactly one fragment —
-                # the combine just lands rows at their query-order
-                # positions
-                return ragged.combine_fragments(frags, len(series_ids))
-        return out
+            t, v, offs, n_parts, n_apart = self._assemble_read(
+                series_ids, plans, parts, start_ns, end_ns)
+            if sp is not None:
+                sp.tags["parts"] = n_parts
+        default_registry().record_many((), (
+            ("storage.read_assemble.parts", (), n_parts),
+            ("storage.read_assemble.parts_apart", (), n_apart)))
+        if want_ragged:
+            return t, v, offs
+        return ragged.split_csr(t, v, offs)
 
-    def _finalize_shard_read(self, plan, start_ns, end_ns, out,
-                             frags: list | None = None) -> None:
-        """Batched ragged finalize (ROADMAP #3): ONE merge pass over the
-        shard's series; out[] carries zero-copy row slices of the shard
-        CSR."""
+    def _assemble_read(self, series_ids, plans, parts, start_ns, end_ns):
+        """The read's one finalize (ROADMAP #3): each shard's buffer leg
+        (`Shard.append_buffered`: buffered points LAST in their rows'
+        parts lists), then ONE copy a column and one merge over every
+        row (`ragged.join_rows`, `merge_csr`), and the limits charged
+        once a series. Returns the query-ordered (times, vbits, offsets)
+        CSR, the parts copied and those that needed a conversion copy
+        of their own first."""
         import numpy as np
 
-        shard, idxs, sids, parts = plan
+        from m3_tpu.ops import ragged
+
+        n_apart = 0
+        if len(set(series_ids)) != len(series_ids):
+            # duplicate ids: the buffer's CSR gather is one row per id —
+            # take the per-series finalize (correctness over speed on a
+            # shape no production caller emits)
+            pairs: list = [None] * len(series_ids)
+            for shard, idxs, sids, rows in plans:
+                for i, sid, pl in zip(idxs, sids, rows):
+                    pairs[i] = shard.finish_read(sid, pl, start_ns, end_ns)
+            n_parts = sum(map(len, parts))
+            t, v, offs = ragged.pairs_to_csr(pairs)
+        else:
+            for shard, _idxs, sids, rows in plans:
+                shard.append_buffered(sids, rows, start_ns, end_ns)
+            t, v, offs, n_parts, n_apart = ragged.join_rows(parts)
+            t, v, offs = ragged.merge_csr(t, v, offs, start_ns, end_ns)
         limits = self.limits
-        t, v, offs = shard.finish_read_many(sids, parts, start_ns, end_ns)
-        for j, i in enumerate(idxs):
-            a, b = int(offs[j]), int(offs[j + 1])
-            if limits is not None:
-                limits.add_datapoints(b - a)
-            out[i] = (t[a:b], v[a:b])
-        if frags is not None:
-            frags.append((np.asarray(idxs, np.int64), t, v, offs))
+        if limits is not None:
+            for n in np.diff(offs).tolist():
+                limits.add_datapoints(n)
+        return t, v, offs, n_parts, n_apart
 
     def flush(self, now_ns: int) -> int:
         """WARM flush: first volume for aged-out buffered windows."""

@@ -409,17 +409,24 @@ class Shard:
             pairs = [self.finish_read(sid, list(pl), start_ns, end_ns)
                      for sid, pl in zip(series_ids, parts)]
             return ragged.pairs_to_csr(pairs)
+        self.append_buffered(series_ids, parts, start_ns, end_ns)
+        return ragged.assemble_rows(parts, start_ns, end_ns)
+
+    def append_buffered(self, series_ids: list[bytes], parts: list[list],
+                        start_ns: int, end_ns: int) -> None:
+        """The buffer leg of a batched finalize: each series' buffered
+        points in the windows the range touches, appended LAST to its
+        parts list (one `ShardBuffer.read_many_csr`, not range-filtered):
+        last-write-wins keeps them, exactly the finish_read append order
+        (parts lists are owned by the read — appending in place, like
+        finish_read). Requires unique ids."""
         bt, bv, boffs = self.buffer.read_many_csr(series_ids, start_ns,
                                                   end_ns)
         if len(bt):
-            # buffer leg LAST: last-write-wins keeps buffered points,
-            # exactly the finish_read append order (parts lists are
-            # owned by this read — appending in place, like finish_read)
-            for i, pl in enumerate(parts):
-                a, b = boffs[i], boffs[i + 1]
+            for pl, a, b in zip(parts, boffs[:-1].tolist(),
+                                boffs[1:].tolist()):
                 if b > a:
                     pl.append((bt[a:b], bv[a:b]))
-        return ragged.assemble_rows(parts, start_ns, end_ns)
 
     def finish_read(self, series_id: bytes, parts: list, start_ns: int,
                     end_ns: int) -> tuple[np.ndarray, np.ndarray]:

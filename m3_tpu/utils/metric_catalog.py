@@ -23,9 +23,9 @@ HISTOGRAMS = {
     "read_many_seconds",        # storage.ns fused batch read
     "read_assemble_seconds",    # db: the request thread's CSR assembly
     #                             of a pipelined batched read (every
-    #                             shard's finalize + the namespace's
-    #                             combine_fragments; span
-    #                             storage.ns.read_assemble)
+    #                             shard's buffer leg, the read's one
+    #                             join + merge, the limits' charge;
+    #                             span storage.ns.read_assemble)
     "shard_flush_seconds",      # shard warm flush
     "commitlog_fsync_seconds",  # WAL fsync wall time
     "persist_seconds",          # fileset/index/kv persist (per-scope)
@@ -133,6 +133,14 @@ TIMERS = {
 #       block) group (count_block_cache; the same split rides the
 #       query's ?explain record): hit + miss over storage_read_series is
 #       the blocks a read series spans
+#   storage_read_assemble_parts                (series, block) and buffer
+#       parts a pipelined namespace read's one CSR assembly copied,
+#       added to once per read (Namespace._read_many_pipelined; also the
+#       `parts` tag of the span storage.ns.read_assemble)
+#   storage_read_assemble_parts_apart          of those, the parts that
+#       first needed a conversion copy of their own (not C-contiguous,
+#       or not int64 / uint64: ops/ragged.join_rows); 0 on the served
+#       path, where decoded rows and buffer slices are both
 #
 # Series -> shard routing (storage/sharding.py ShardRoutes):
 #   storage_shard_route_hit / storage_shard_route_miss   series ids

@@ -210,6 +210,47 @@ class TestRaggedKernels:
                 np.testing.assert_array_equal(t[a:b], et)
                 np.testing.assert_array_equal(v[a:b], ev)
 
+    @pytest.mark.parametrize("odd", ["none", "noncontiguous", "float64"])
+    def test_join_rows_is_concatenate_bit_for_bit(self, odd):
+        """The one copy a column equals np.concatenate of the parts (cast
+        to int64 / uint64) on the property cases; a part that is not a
+        C-contiguous int64 / uint64 array takes a conversion copy and
+        counts apart once, whichever of its columns needed it."""
+        rng = np.random.default_rng(13)
+        for trial in range(15):
+            parts_rows = [_random_rows(rng, int(rng.integers(0, 4)), 12)
+                          for _ in range(int(rng.integers(1, 8)))]
+            flat = [p for parts in parts_rows for p in parts if len(p[0])]
+            want_t = np.concatenate([p[0] for p in flat]) if flat \
+                else np.empty(0, np.int64)
+            want_v = np.concatenate([p[1] for p in flat]) if flat \
+                else np.empty(0, np.uint64)
+            apart = 0
+            longer = [k for k, p in enumerate(flat) if len(p[0]) > 1]
+            if longer and odd == "noncontiguous":
+                # every other element of a doubled row: the same values,
+                # a stride of two (numpy calls a one-element array
+                # contiguous whatever its stride)
+                t, v = flat[longer[0]]
+                flat[longer[0]] = (np.repeat(t, 2)[::2],
+                                   np.repeat(v, 2)[::2])
+                apart = 1
+            elif flat and odd == "float64":
+                # whole seconds in ns are exact in a float64
+                flat[-1] = (flat[-1][0].astype(np.float64), flat[-1][1])
+                apart = 1
+            it = iter(flat)
+            rows = [[next(it) if len(p[0]) else p for p in parts]
+                    for parts in parts_rows]
+            t, v, offs, n_parts, n_apart = ragged.join_rows(rows)
+            assert t.dtype == np.int64 and v.dtype == np.uint64
+            np.testing.assert_array_equal(t, want_t, err_msg=f"trial {trial}")
+            np.testing.assert_array_equal(v, want_v)
+            np.testing.assert_array_equal(
+                np.diff(offs), [sum(len(p[0]) for p in parts)
+                                for parts in rows])
+            assert (n_parts, n_apart) == (len(flat), apart)
+
     def test_length_buckets_cover_and_bound_waste(self):
         rng = np.random.default_rng(3)
         lens = rng.integers(0, 10_000, 200)
@@ -406,6 +447,176 @@ class TestPagedReadParity:
             assert np.allclose(a.values, b.values, rtol=1e-9, atol=0,
                                equal_nan=True), q
         assert any(out[("1", q)][1] for q in queries)
+
+
+def _assemble_counters():
+    from m3_tpu.utils.instrument import default_registry
+
+    counters = default_registry().snapshot()[0]
+    return [counters.get((f"storage.read_assemble.{k}", ()), 0)
+            for k in ("parts", "parts_apart")]
+
+
+FIRST = START - START % HOUR
+
+
+def _one_pass_ns(root, n_shards):
+    db = Database(root, DatabaseOptions(n_shards=n_shards,
+                                        block_cache_entries=100_000))
+    ns = db.create_namespace("default", NamespaceOptions(
+        retention=RetentionOptions(retention_ns=1000 * HOUR,
+                                   block_size_ns=HOUR),
+        index=IndexOptions(enabled=False),
+        writes_to_commitlog=False, snapshot_enabled=False))
+    db.open(FIRST)
+    return db, ns
+
+
+class TestOnePassRead:
+    """A namespace read assembles every shard's rows at once, in query
+    order (`Namespace._assemble_read`): row for row what the per-series
+    finalize (`Shard.finish_read`) gives, with one copy a column however
+    many (series, block) parts the read has."""
+
+    # (shards, blocks, duplicate ids)
+    CASES = [(1, 1, False), (1, 4, True), (1, 7, False),
+             (8, 1, True), (8, 3, False), (8, 7, False), (8, 5, True)]
+
+    @staticmethod
+    def _reference(ns, sid, lo, hi):
+        """The per-series finalize over the same groups, and the parts
+        the one-pass assembly copies for the row: its sealed parts and
+        its buffered slice, which is not range-filtered."""
+        from m3_tpu.storage.shard import run_read_groups
+
+        shard = ns.shard_for(sid)
+        pl: list = []
+        run_read_groups(shard.plan_read_groups([sid], lo, hi, [pl]))
+        n = len(pl) + bool(len(shard.buffer.read_many_csr([sid], lo,
+                                                          hi)[0]))
+        t, v = shard.finish_read(sid, pl, lo, hi)
+        return t, v, n
+
+    @pytest.mark.parametrize("n_shards,n_blocks,dup", CASES)
+    def test_one_pass_equals_the_per_series_finalize(
+            self, tmp_path, monkeypatch, n_shards, n_blocks, dup):
+        monkeypatch.setenv("M3_TPU_PIPELINE", "1")
+        rng = np.random.default_rng(1000 * n_shards + 10 * n_blocks + dup)
+        db, ns = _one_pass_ns(str(tmp_path / "one"), n_shards)
+        # ids 40-47 are never written: empty rows
+        ids = [b"one-pass-%03d" % i for i in range(48)]
+        sealed: list = []
+        for b in range(n_blocks):
+            bs = FIRST + b * HOUR
+            for i in range(40):
+                if rng.random() < 0.2:
+                    continue
+                for s in rng.integers(0, 3600, int(rng.integers(1, 8))):
+                    t = bs + int(s) * NS
+                    ns.write(ids[i], t, bits(float(rng.integers(0, 100))))
+                    if b < n_blocks - 1:
+                        sealed.append((i, t))
+            if b < n_blocks - 1:
+                for shard in ns.shards.values():
+                    for w in shard.buffer.block_starts():
+                        shard.flush(w)
+        # buffered points over sealed ones: the same timestamps, new
+        # values, which the buffer's last write wins
+        for k in rng.choice(len(sealed), min(len(sealed), 25), replace=False):
+            i, t = sealed[int(k)]
+            ns.write(ids[i], t, bits(1000.0 + int(k)))
+        lo = FIRST + int(rng.integers(1, 29)) * 60 * NS
+        hi = FIRST + (n_blocks - 1) * HOUR + int(rng.integers(31, 59)) * 60 * NS
+        if dup:
+            ids += [ids[3], ids[45], ids[3]]
+        ref = [self._reference(ns, sid, lo, hi) for sid in ids]
+
+        # decoded rows as the cache holds them, two of them re-laid: one
+        # with a stride of two, one with float64 times
+        apart = 0
+        if not dup:
+            odd = [(shard, bs, reader, sid) for shard in ns.shards.values()
+                   for bs, reader in shard._filesets.items()
+                   if bs + HOUR > lo for sid in ids
+                   if shard is ns.shard_for(sid)]
+            for shard, bs, reader, sid in odd:
+                key = ("default", shard.shard_id, bs, reader.volume, sid)
+                hit = db.block_cache.get(key)
+                if hit is None or len(hit[0]) < 2:
+                    continue  # one element is contiguous at any stride
+                t, v = hit
+                db.block_cache.put(key, (np.repeat(t, 2)[::2], v) if apart
+                                   else (t.astype(np.float64), v))
+                apart += 1
+                if apart == 2:
+                    break
+            assert apart == 2 or n_blocks == 1
+
+        before = _assemble_counters()
+        t, v, offs = ns.read_many_ragged(ids, lo, hi)
+        parts, parts_apart = (a - b for a, b in zip(_assemble_counters(),
+                                                    before))
+        assert t.dtype == np.int64 and v.dtype == np.uint64
+        assert len(offs) == len(ids) + 1
+        n_samples = 0
+        for i, (wt, wv, _n) in enumerate(ref):
+            a, b = offs[i], offs[i + 1]
+            np.testing.assert_array_equal(t[a:b], wt, err_msg=f"row {i}")
+            np.testing.assert_array_equal(v[a:b], wv, err_msg=f"row {i}")
+            n_samples += len(wt)
+        assert n_samples > 0 and offs[-1] == n_samples
+        assert not any(len(r[0]) for r in ref[40:48])
+        assert parts_apart == apart
+        if not dup:
+            assert parts == sum(r[2] for r in ref)
+        pairs = ns.read_many(ids, lo, hi)
+        for i, (pt, pv) in enumerate(pairs):
+            np.testing.assert_array_equal(pt, t[offs[i]:offs[i + 1]])
+            np.testing.assert_array_equal(pv, v[offs[i]:offs[i + 1]])
+        db.close()
+
+    def test_assembly_keeps_the_lock_beside_a_spinning_thread(self, tmp_path):
+        """1,169 parts of 720 points (167 series x 7 two-hour blocks, the
+        `double-groupby-1.12h` read) assemble in one copy a column: a
+        pure-Python thread spinning beside the caller costs it a few
+        switch intervals, not one wait for each part copied."""
+        import threading
+        import time
+
+        db, ns = _one_pass_ns(str(tmp_path / "spin"), 8)
+        ids = [b"spin-%03d" % i for i in range(167)]
+        step = np.arange(720, dtype=np.int64) * 10 * NS
+        parts = [[(FIRST + b * 2 * HOUR + step,
+                   np.full(720, bits(float(i)), np.uint64))
+                  for b in range(7)] for i in range(len(ids))]
+        by_shard: dict = {}
+        for i, s in enumerate(ns.shards_of(ids)):
+            by_shard.setdefault(s, []).append(i)
+        plans = [(ns.shards[s], idxs, [ids[i] for i in idxs],
+                  [parts[i] for i in idxs]) for s, idxs in by_shard.items()]
+        lo, hi = FIRST + 67 * 60 * NS, FIRST + 13 * HOUR + 7 * 60 * NS
+        stop = threading.Event()
+        deadline = time.monotonic() + 30  # the spinner's own bound
+
+        def spin():
+            while not stop.is_set() and time.monotonic() < deadline:
+                pass
+
+        spinner = threading.Thread(target=spin, daemon=True)
+        spinner.start()
+        try:
+            t0 = time.perf_counter()
+            t, _v, offs, n_parts, n_apart = ns._assemble_read(
+                ids, plans, parts, lo, hi)
+            wall = time.perf_counter() - t0
+        finally:
+            stop.set()
+            spinner.join(timeout=30)
+        assert not spinner.is_alive()
+        assert (n_parts, n_apart) == (7 * 167, 0)
+        assert offs[-1] == len(t) and t[0] >= lo and t[-1] < hi
+        assert wall < 0.25, f"assembly took {wall * 1e3:.0f} ms"
+        db.close()
 
 
 def _same_bits(a, b):

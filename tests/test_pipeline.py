@@ -275,7 +275,8 @@ class TestPipelinedReads:
 
     def test_decode_follows_the_last_gather(self, tmp_path, monkeypatch):
         """No stream decodes before every group's gather has landed, and
-        every shard finalizes after the decode."""
+        the read's one finalize, over every shard's rows, follows the
+        decode."""
         from m3_tpu.encoding.m3tsz import hostpath
         from m3_tpu.storage.namespace import Namespace
         from m3_tpu.storage.shard import _FilesetReadGroup
@@ -285,7 +286,7 @@ class TestPipelinedReads:
         events = []
         gather = _FilesetReadGroup.gather
         decode = hostpath.decode_streams_batch
-        finalize = Namespace._finalize_shard_read
+        finalize = Namespace._assemble_read
 
         def spy(name, fn):
             def wrapped(*a, **kw):
@@ -298,11 +299,11 @@ class TestPipelinedReads:
                             spy("gather", gather))
         monkeypatch.setattr(hostpath, "decode_streams_batch",
                             spy("decode", decode))
-        monkeypatch.setattr(Namespace, "_finalize_shard_read",
+        monkeypatch.setattr(Namespace, "_assemble_read",
                             spy("finalize", finalize))
         try:
             ns.read_many(ids, START, START + 3 * BLOCK)
-            assert events == ["gather"] * 12 + ["decode"] + ["finalize"] * 4
+            assert events == ["gather"] * 12 + ["decode"] + ["finalize"]
         finally:
             db.close()
 
