@@ -240,9 +240,8 @@ class Collector:
         if d_groups > 0:
             d_wall = st.pipeline_wall_s - pw0
             d_stage = {k: round((v - ps0.get(k, 0.0)) * 1e3, 3)
-                       for k, v in st.pipeline_stage_s.items()
-                       if v - ps0.get(k, 0.0) > 0}
-            stage_sum = sum(d_stage.values())
+                       for k, v in st.pipeline_stage_s.items()}
+            stage_sum = querystats.pipeline_leg_sum(d_stage)
             entry["pipeline"] = {
                 "groups": d_groups,
                 "wall_ms": round(d_wall * 1e3, 3),

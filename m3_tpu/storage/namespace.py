@@ -7,6 +7,7 @@ Role parity with the reference dbNamespace
 from __future__ import annotations
 
 import itertools
+import time
 
 from m3_tpu.index.index import NamespaceIndex
 from m3_tpu.index.query import Query
@@ -326,6 +327,7 @@ class Namespace:
         from m3_tpu.storage import pipeline
         from m3_tpu.utils.instrument import default_registry
 
+        t_enter = time.perf_counter_ns()
         default_registry().record_many((), (
             ("storage.read_series", (), len(series_ids)),))
         by_shard: dict[int, list[int]] = {}
@@ -342,7 +344,7 @@ class Namespace:
             # flattened schedule of per-(shard, block) gather legs
             # across every shard, then one decode of what they missed
             return self._read_many_pipelined(series_ids, by_shard,
-                                             start_ns, end_ns,
+                                             start_ns, end_ns, t_enter,
                                              want_ragged=want_ragged)
         out: list = [None] * len(series_ids)
         for shard_id, idxs in by_shard.items():
@@ -358,7 +360,7 @@ class Namespace:
         return out
 
     def _read_many_pipelined(self, series_ids, by_shard, start_ns, end_ns,
-                             want_ragged: bool = False):
+                             t_enter: int, want_ragged: bool = False):
         """Per-(shard, block) groups through the executor seam
         (shard.run_read_groups): the fileset gathers of every shard run
         on the pool while this thread lands the cache hits of those that
@@ -368,10 +370,21 @@ class Namespace:
         path: the groups' hits and decoded misses land in the same
         nested order, and per-series parts keep the filesets-then-buffer
         order merge_dedup resolves last-write-wins.
+
+        A read whose groups went to the pool is timed where its parts
+        happen, one observation each a read (unlabelled, storage scope):
+        ``read_legs``; ``read_plan_seconds`` from ``t_enter`` (the
+        read's entry) to its first leg's submit; the legs' summed
+        ``read_leg_queue_seconds``; this thread's ``read_leg_wait_seconds``
+        for them (asking for a leg's result to holding it) and, of that,
+        ``read_leg_handover_seconds`` after a leg had finished;
+        ``read_consume_seconds``, the consume legs less the decode stages
+        beneath them (``pipeline.StageStats``). With the assembly's
+        histogram they tile the read's share of the stage ``read_many``.
         """
         from m3_tpu.ops import ragged
         from m3_tpu.utils import trace
-        from m3_tpu.utils.instrument import default_registry
+        from m3_tpu.utils.instrument import COUNT_BUCKETS, default_registry
 
         # one parts list a row, in query order: a shard's groups land
         # their hits and decoded misses in the same list objects, so the
@@ -386,7 +399,7 @@ class Namespace:
             plans.append((shard, idxs, sids, rows))
             groups.extend(shard.plan_read_groups(sids, start_ns, end_ns,
                                                  rows))
-        run_read_groups(groups)
+        stats = run_read_groups(groups)
         # the request thread's CSR assembly: a span (transparent to the
         # stage clock, so `read_many` keeps its meaning) and its histogram
         with trace.span(trace.READ_ASSEMBLE, series=len(series_ids),
@@ -397,9 +410,19 @@ class Namespace:
                 series_ids, plans, parts, start_ns, end_ns)
             if sp is not None:
                 sp.tags["parts"] = n_parts
-        default_registry().record_many((), (
+        timed = () if stats is None or not stats.legs else (
+            ("storage.read_legs", (), stats.legs),
+            ("storage.read_plan_seconds", (),
+             (stats.first_submit_ns - t_enter) / 1e9),
+            ("storage.read_leg_queue_seconds", (), stats.queue_ns / 1e9),
+            ("storage.read_leg_wait_seconds", (), stats.wait_ns / 1e9),
+            ("storage.read_leg_handover_seconds", (),
+             stats.handover_ns / 1e9),
+            ("storage.read_consume_seconds", (), stats.consume_ns / 1e9))
+        default_registry().record_many(timed, (
             ("storage.read_assemble.parts", (), n_parts),
-            ("storage.read_assemble.parts_apart", (), n_apart)))
+            ("storage.read_assemble.parts_apart", (), n_apart)),
+            bounds={"storage.read_legs": COUNT_BUCKETS})
         if want_ragged:
             return t, v, offs
         return ragged.split_csr(t, v, offs)

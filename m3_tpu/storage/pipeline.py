@@ -54,12 +54,10 @@ import queue
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 from m3_tpu.utils import faults
-from m3_tpu.utils.instrument import default_registry, monitor_queue
-
-_scope = default_registry().root_scope("pipeline")
+from m3_tpu.utils.instrument import monitor_queue
 
 # worker heartbeat cadence: long enough that a worker parked on a slow
 # (but legitimate) RPC leg doesn't trip the stall watchdog, short enough
@@ -111,20 +109,27 @@ def wal_chunk_entries() -> int:
 
 
 class _Future:
-    """Single-shot result slot (Event-based; no cancellation races)."""
+    """Single-shot result slot (Event-based; no cancellation races).
+    ``submitted_ns`` / ``started_ns`` / ``done_ns`` (``perf_counter_ns``)
+    are when the task was submitted, when a worker started it and when
+    it finished, just before the consumer is woken: the leg's time in
+    the queue, and where the consumer's hand-over begins."""
 
-    __slots__ = ("_done", "_result", "_exc")
+    __slots__ = ("_done", "_result", "_exc", "submitted_ns", "started_ns",
+                 "done_ns")
 
     def __init__(self) -> None:
         self._done = threading.Event()
         self._result = None
         self._exc: BaseException | None = None
+        self.submitted_ns = self.started_ns = self.done_ns = 0
 
     def run(self, fn) -> None:
         """Execute fn() capturing its outcome for the consumer. A
         SimulatedCrash escalates HERE (armed chaos kills the process at
         the point of injury) and is still handed to the consumer, which
         re-raises it in submission order — the serial path's semantics."""
+        self.started_ns = time.perf_counter_ns()
         try:
             self._result = fn()
         except faults.SimulatedCrash as e:
@@ -133,6 +138,7 @@ class _Future:
         except BaseException as e:  # delivered to the consumer's result()
             self._exc = e
         finally:
+            self.done_ns = time.perf_counter_ns()
             self._done.set()
 
     def result(self):
@@ -245,6 +251,7 @@ class PipelineExecutor:
         faults.check("pipeline.task", stage=point_ctx)
         self._ensure_started()
         fut = _Future()
+        fut.submitted_ns = time.perf_counter_ns()
         self._q.put((fn, fut))
         return fut
 
@@ -255,11 +262,15 @@ class PipelineExecutor:
                 ln = self._lanes[name] = SerialLane(self, name)
             return ln
 
-    def map_ordered(self, fns: list, depth: int):
+    def map_ordered(self, fns: list, depth: int,
+                    stats: "StageStats | None" = None):
         """Yield fn() results in input order with up to ``depth`` calls
         in flight ahead of the consumer — the bounded-depth prefetch the
         read path overlaps its gathers through. Falls back to a
-        plain inline loop from worker context (no nested waits)."""
+        plain inline loop from worker context (no nested waits).
+        ``stats``, where given, takes the legs submitted, when the first
+        was, their summed queue time, and the ``done_ns`` of the leg
+        whose result is being handed over."""
         if in_worker() or len(fns) <= 1:
             for fn in fns:
                 yield fn()
@@ -267,6 +278,8 @@ class PipelineExecutor:
         depth = max(1, depth)
         futs: deque = deque()
         it = iter(fns)
+        if stats is not None:
+            stats.first_submit_ns = time.perf_counter_ns()
         for fn in it:
             futs.append(self.submit(fn, point_ctx="map"))
             if len(futs) >= depth:
@@ -276,7 +289,12 @@ class PipelineExecutor:
             nxt = next(it, None)
             if nxt is not None:
                 futs.append(self.submit(nxt, point_ctx="map"))
-            yield fut.result()
+            res = fut.result()
+            if stats is not None:
+                stats.legs += 1
+                stats.queue_ns += fut.started_ns - fut.submitted_ns
+                stats.done_ns = fut.done_ns
+            yield res
 
 
 _default_lock = threading.Lock()
@@ -364,21 +382,43 @@ def submit_client_leg(fn, tracer, ctx, point_ctx: str) -> _Future:
 
 @dataclass
 class StageStats:
-    """Per-run overlap accounting: wall time vs sum-of-stage time. When
-    ``sum(stages.values()) > wall_s`` the pipeline overlapped work; the
-    ratio rides ``?explain=analyze`` via querystats.record_pipeline."""
+    """One ``run_stages`` pass, in ``perf_counter_ns``: the legs that
+    went to the pool (0: the pass ran inline) and when the first was
+    submitted; summed over them, the time each sat in the pool's queue,
+    the caller's wait for each (from asking the pool for the leg's
+    result, at the first submit or as the consume leg before it ended,
+    to holding it: the block in ``_Future.result``, the next leg's
+    submit and every re-take of the interpreter between) and the part
+    of that wait after the leg had finished (the hand-over); the
+    produce legs' wall time, and the consume legs' on the caller's
+    thread, their stage's open and close included, less what metered
+    stages beneath them covered. The waits, the consume legs and the
+    time before the first submit tile the caller's pass. ``stages`` is
+    the ``?explain=analyze`` pipeline block's (querystats
+    .record_pipeline): gather + consume over ``wall_s`` above 1.0 means
+    the legs overlapped."""
 
     items: int = 0
     wall_s: float = 0.0
-    stages: dict = field(default_factory=dict)
+    legs: int = 0
+    first_submit_ns: int = 0
+    done_ns: int = 0            # the leg whose result map_ordered yields
+    queue_ns: int = 0
+    wait_ns: int = 0
+    handover_ns: int = 0
+    gather_ns: int = 0
+    consume_ns: int = 0
 
-    def add_stage(self, name: str, dt: float) -> None:
-        self.stages[name] = self.stages.get(name, 0.0) + dt
+    @property
+    def stages(self) -> dict:
+        return {"gather": self.gather_ns / 1e9,
+                "consume": self.consume_ns / 1e9,
+                "leg_wait": self.wait_ns / 1e9,
+                "handover": self.handover_ns / 1e9}
 
 
-def run_stages(items: list, produce, consume, depth: int | None = None,
-               produce_stage: str = "gather",
-               consume_stage: str = "decode") -> StageStats:
+def run_stages(items: list, produce, consume,
+               depth: int | None = None) -> StageStats:
     """The two-stage overlap primitive: ``produce(item)`` runs on the
     pool up to ``depth`` items ahead (thread-safe leg: fileset gather,
     node RPC) while ``consume(item, payload)`` runs on the CALLING
@@ -404,24 +444,27 @@ def run_stages(items: list, produce, consume, depth: int | None = None,
         with tracer.activate(ctx), \
                 tracer.stage(trace.STAGE_GATHER, metered=pipelined) as fr:
             payload = produce(item)
-        return item, payload, fr.wall_s
+        return item, payload, fr.wall_ns
 
     if pipelined:
         ex = default_executor()
         results = ex.map_ordered(
             [lambda it=it: produce_leg(it) for it in items],
-            depth or prefetch_depth())
+            depth or prefetch_depth(), stats)
     else:
         results = (produce_leg(it) for it in items)
-    for item, payload, p_dt in results:
-        stats.add_stage(produce_stage, p_dt)
+    asked = 0
+    for item, payload, p_ns in results:
+        got = time.perf_counter_ns()
+        if pipelined:
+            asked = asked or stats.first_submit_ns
+            stats.wait_ns += got - asked
+            stats.handover_ns += max(0, got - max(stats.done_ns, asked))
+        stats.gather_ns += p_ns
         with tracer.stage(trace.PIPELINE_CONSUME, metered=False) as fr:
             consume(item, payload)
-        stats.add_stage(consume_stage, fr.wall_s)
+        asked = time.perf_counter_ns()
+        # what a metered stage beneath (the decode's) covered is its own
+        stats.consume_ns += asked - got - fr.covered_ns
     stats.wall_s = time.perf_counter() - t0
-    if stats.items:
-        _scope.subscope("stage", stage=produce_stage).observe(
-            "stage_seconds", stats.stages.get(produce_stage, 0.0))
-        _scope.subscope("stage", stage=consume_stage).observe(
-            "stage_seconds", stats.stages.get(consume_stage, 0.0))
     return stats

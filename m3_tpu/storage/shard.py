@@ -122,17 +122,18 @@ def decode_misses(pending: list[tuple], opts: NamespaceOptions,
         cache.put_many(fills)
 
 
-def run_read_groups(groups: "list[_FilesetReadGroup]") -> None:
+def run_read_groups(groups: "list[_FilesetReadGroup]"):
     """Drive a read's groups through the executor seam: gathers on the
     pool in submission order, each group's hits landed on this thread as
     its gather arrives, and when the last has landed one decode over
     what all of them missed. Every group's parts are complete on
-    return."""
+    return; so is the pass's ``pipeline.StageStats`` (None: no
+    groups)."""
     from m3_tpu.storage import pipeline
     from m3_tpu.utils import querystats
 
     if not groups:
-        return
+        return None
     pending: list[tuple] = []
 
     def consume(g, payload):
@@ -146,6 +147,7 @@ def run_read_groups(groups: "list[_FilesetReadGroup]") -> None:
     # namespace's flattened schedule, its limit-chunked loop and direct
     # shard callers)
     querystats.record_pipeline(stats.items, stats.wall_s, stats.stages)
+    return stats
 
 
 class Shard:

@@ -14,7 +14,10 @@ queue in order), which names the thread that launched each program. An
 idle gap on the programs' line is charged to the innermost stage open on
 the thread that launched the program which ended it, piece by piece.
 A trace without a device plane (the CPU backend) has no programs' line:
-the launches themselves stand in, so the tool can be rehearsed.
+the launches themselves stand in, so the tool can be rehearsed. The
+program keeps the same split always on, over the time in which no
+launch is outstanding (``compute_launch_gap_seconds{stage}``,
+utils/dispatch.py ``LaunchLedger``): a subset of the idle time read here.
 """
 
 from __future__ import annotations
@@ -25,8 +28,10 @@ import os
 import re
 import sys
 
+from m3_tpu.utils import trace
+
 MODULES_LINE = "XLA Modules"
-NO_STAGE = "(no stage open)"
+NO_STAGE = trace.NO_STAGE
 NO_LAUNCH = "(launch not in the trace)"
 
 
@@ -79,23 +84,14 @@ def _launches(threads: dict) -> dict:
 
 
 def _stages_in(events: list, stage_names: set, a: int, b: int) -> dict:
-    """Seconds of [a, b) per innermost stage open on one thread."""
-    open_in = [(s, e, n) for n, s, e in events
-               if n in stage_names and s < b and e > a]
-    cuts = sorted({a, b, *(t for s, e, _ in open_in for t in (s, e)
-                           if a < t < b)})
-    out: dict = {}
-    for lo, hi in zip(cuts, cuts[1:]):
-        inner = max((ev for ev in open_in if ev[0] <= lo and ev[1] >= hi),
-                    default=None)           # the latest start is innermost
-        name = inner[2] if inner else NO_STAGE
-        out[name] = out.get(name, 0.0) + (hi - lo) / 1e9
-    return out
+    """Seconds of [a, b) per innermost stage open on one thread: the
+    stage clock's own rule (``trace.stages_in``), which the launch-gap
+    ledger applies in process."""
+    return trace.stages_in([ev for ev in events if ev[0] in stage_names],
+                           a, b)
 
 
 def reduce(trace_dir: str, top: int = 10) -> dict:
-    from m3_tpu.utils import trace
-
     stage_names = {v for k, v in vars(trace).items()
                    if k.startswith("STAGE_")} | {trace.PIPELINE_CONSUME}
     programs, threads = load(trace_dir)

@@ -27,6 +27,10 @@ stage clock (utils/trace.py) enters a ``TraceAnnotation`` per sampled
 stage, so the request's stages land in the xplane beside the device
 planes, on the trace's own clock (m3_tpu/tools/trace_gaps.py reads
 them). Outside a session nothing is annotated and nothing is written.
+The stop also says what the process's launch-gap ledger
+(utils/dispatch.py ``launches``) counted over the session: the seconds
+in which no launch was outstanding, which the xplane's idle time holds,
+and those in which one was.
 """
 
 from __future__ import annotations
@@ -111,15 +115,16 @@ def describe() -> dict:
 
 _trace_lock = threading.Lock()
 _trace_dir: str | None = None
+_ledger_at_start = (0, 0)
 
 
 def start_device_trace(trace_dir: str) -> dict:
     """Start a profiler session into `trace_dir` and switch the stage
     clock's annotations on. One session at a time."""
-    global _trace_dir
+    global _trace_dir, _ledger_at_start
     import jax
 
-    from m3_tpu.utils import trace
+    from m3_tpu.utils import dispatch, trace
 
     with _trace_lock:
         if _trace_dir is not None:
@@ -129,6 +134,8 @@ def start_device_trace(trace_dir: str) -> dict:
         options.host_tracer_level = 1
         t0 = time.perf_counter()
         jax.profiler.start_trace(trace_dir, profiler_options=options)
+        _ledger_at_start = (dispatch.launches.gap_ns,
+                            dispatch.launches.outstanding_ns)
         _trace_dir = trace_dir
         trace.default_tracer().annotate = jax.profiler.TraceAnnotation
         return {"tracing": True, "dir": trace_dir,
@@ -137,23 +144,29 @@ def start_device_trace(trace_dir: str) -> dict:
 
 def stop_device_trace() -> dict:
     """Switch the annotations off, stop the session and say where the
-    xplane is and how long the stop took."""
+    xplane is, how long the stop took, and the launch-gap ledger's gap
+    and outstanding seconds over the session."""
     global _trace_dir
     import jax
 
-    from m3_tpu.utils import trace
+    from m3_tpu.utils import dispatch, trace
 
     with _trace_lock:
         if _trace_dir is None:
             raise RuntimeError("no device trace runs")
         trace.default_tracer().annotate = None
+        gap0, out0 = _ledger_at_start
+        ledger = {
+            "launch_gap_s": (dispatch.launches.gap_ns - gap0) / 1e9,
+            "launch_outstanding_s":
+                (dispatch.launches.outstanding_ns - out0) / 1e9}
         t0 = time.perf_counter()
         try:
             jax.profiler.stop_trace()
         finally:
             trace_dir, _trace_dir = _trace_dir, None
         return {"tracing": False, "dir": trace_dir,
-                "stop_seconds": time.perf_counter() - t0}
+                "stop_seconds": time.perf_counter() - t0, **ledger}
 
 
 def handle_debug_profile_device(method: str, q: dict, body: bytes):

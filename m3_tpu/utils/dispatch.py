@@ -19,8 +19,12 @@ was device kernels that only tests invoked.
 from __future__ import annotations
 
 import os
+import threading
+import time
 import weakref
 from collections import Counter
+
+from m3_tpu.utils import trace
 
 counters: Counter = Counter()
 
@@ -129,6 +133,64 @@ def _jit_scope(op: str, result: str):
 _last_sizes: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 
+class LaunchLedger:
+    """Device idle charged to what the host was doing, in process.
+
+    Every launch on the served paths is a ``jit_tracker`` block, which
+    holds the call and the wait for its result, so while no block is
+    open anywhere in the process nothing runs on the device or is on its
+    way there. The ledger counts the open blocks under a lock of its
+    own. Where the count falls to 0 a gap starts; at the next launch (0
+    to 1) the gap is added to ``compute_launch_gap_seconds{stage}``,
+    split over the launching thread's stages innermost first
+    (``Tracer.stages_between``: ``tools/trace_gaps.py``'s rule). Where a
+    block closes the count (1 to 0), its time since the 0-to-1 edge is
+    added to ``compute_launch_outstanding_seconds``: dispatch, device
+    time, transfers, a compile, and the wait for the interpreter to take
+    the result. Gap and outstanding tile the time from the first launch
+    on. The counters ride the thread's batched publish of its stages;
+    ``gap_ns`` and ``outstanding_ns`` are the same totals, current to
+    the last edge, for the device-trace session (utils/backend.py)."""
+
+    def __init__(self, tracer: "trace.Tracer | None" = None):
+        self._tracer = tracer
+        self._lock = threading.Lock()
+        self._open = 0
+        self._edge_ns = 0           # the last 0-to-1 or 1-to-0 edge
+        self.gap_ns = 0
+        self.outstanding_ns = 0
+
+    def opened(self) -> None:
+        with self._lock:
+            self._open += 1
+            if self._open > 1:
+                return
+            now = time.perf_counter_ns()
+            since, self._edge_ns = self._edge_ns, now
+            if not since:           # the first launch: no gap before it
+                return
+            self.gap_ns += now - since
+        tr = self._tracer or trace.default_tracer()
+        tr.add_counters([("compute.launch_gap_seconds", (("stage", name),),
+                          s) for name, s in tr.stages_between(
+                              since, now).items()])
+
+    def closed(self) -> None:
+        with self._lock:
+            self._open -= 1
+            if self._open:
+                return
+            now = time.perf_counter_ns()
+            since, self._edge_ns = self._edge_ns, now
+            self.outstanding_ns += now - since
+        (self._tracer or trace.default_tracer()).add_counters(
+            [("compute.launch_outstanding_seconds", (), (now - since) / 1e9)])
+
+
+# the process's ledger: every jit_tracker block opens and closes it
+launches = LaunchLedger()
+
+
 class jit_tracker:
     """`with jit_tracker("m3tsz_decode", jitted_fn, sig="..."): ...` —
     records compute.jit_calls{op,result=hit|miss}; on a miss, the
@@ -140,7 +202,8 @@ class jit_tracker:
     without the wait times the dispatch, not the program. The jitted
     function's private executable cache (`_cache_size`) is the ground
     truth; entries that vanished since the last tracked call bump
-    compute_jit_evictions{op}."""
+    compute_jit_evictions{op}. Each block opens and closes the process's
+    ``launches`` ledger."""
 
     def __init__(self, op: str, jitted_fn, sig: str | None = None):
         self.op = op
@@ -161,21 +224,19 @@ class jit_tracker:
         return self._size_fn() > self._before
 
     def __enter__(self):
-        import time
-
         self._before = self._size_fn()
         last = _last_sizes.get(self._fn)
         if last is not None and self._before < last:
             from m3_tpu.utils import compute_stats
 
             compute_stats.record_evictions(self.op, last - self._before)
+        launches.opened()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        import time
-
         dt = self.seconds = time.perf_counter() - self._t0
+        launches.closed()
         after = self._size_fn()
         miss = self.miss = after > self._before
         _last_sizes[self._fn] = after

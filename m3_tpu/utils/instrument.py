@@ -441,15 +441,25 @@ class MetricsRegistry:
     def root_scope(self, prefix: str = "") -> Scope:
         return Scope(self, prefix)
 
-    def record_many(self, observations, increments) -> None:
+    def record_many(self, observations, increments,
+                    bounds: dict | None = None) -> None:
         """Histogram observations [(name, tags, value)] and counter
         increments [(name, tags, delta)] under ONE acquisition of the
         lock: the stage clock (utils/trace.py) publishes a request's
         stages together when its outermost span closes, not two
-        acquisitions a stage from inside the request."""
+        acquisitions a stage from inside the request. ``bounds`` ({name:
+        bounds}) binds the buckets of a histogram an observation
+        creates, as ``Scope.observe``'s does."""
         with self._lock:
+            hists = self.histograms
             for name, tags, value in observations:
-                self.histograms[(name, tags)].observe_locked(value)
+                h = hists.get((name, tags))
+                if h is None:
+                    b = bounds.get(name) if bounds else None
+                    h = hists[(name, tags)] = _Histogram(
+                        bounds=b, counts=[0] * (len(b) + 1)) \
+                        if b else _Histogram()
+                h.observe_locked(value)
             for name, tags, delta in increments:
                 self.counters[(name, tags)].value += delta
 

@@ -55,10 +55,33 @@ HISTOGRAMS = {
     "fetch_many_seconds",       # session batched fetch
     "request_seconds",          # coordinator request + per-tenant SLO
     "flush_seconds",            # aggregator flush
-    # pipelined dataflow (storage/pipeline)
-    "stage_seconds",            # pipeline.stage{stage=gather|decode}:
-    #                             per-run stage-time sums; compared with
-    #                             the run's wall time they expose overlap
+    # a namespace read whose groups went to the pool
+    # (Namespace._read_many_pipelined), storage scope, unlabelled, one
+    # observation a read; the request thread's parts, with the
+    # assembly's read_assemble_seconds, make up its share of the stage
+    # read_many (stamped on objects the read has: the executor's
+    # futures, map_ordered's wait, the consume stage's frame)
+    "read_legs",                # gather legs the read submitted
+    "read_plan_seconds",        # the read's entry to its first leg's
+    #                             submit: shard routing, grouping by
+    #                             shard, every plan_read_groups
+    "read_leg_queue_seconds",   # summed over the legs: submit to a
+    #                             worker starting the leg
+    "read_leg_wait_seconds",    # the request thread from asking for a
+    #                             leg's result (its first submit, or the
+    #                             end of the consume leg before) to
+    #                             holding it: the block in
+    #                             _Future.result, the next leg's submit
+    #                             and every re-take of the interpreter
+    #                             between (pipeline.run_stages)
+    "read_leg_handover_seconds",  # of that wait, the part after the leg
+    #                             had finished: the interpreter handing
+    #                             the request thread its turn back
+    "read_consume_seconds",     # the consume legs on the request thread
+    #                             (landing each group's cache hits, the
+    #                             decode's scatter), their stage's open
+    #                             and close included, less the decode
+    #                             stages beneath them
     # profiling & saturation plane (utils/profiler)
     "sample_seconds",           # profiler per-pass sampling wall time
     "wait_seconds",             # lock.wait_seconds{cls=site}: per-class
@@ -235,6 +258,20 @@ TIMERS = {
 #       wrote (storage/shard.py snapshot)
 #   storage_commitlog_rotations                commitlog files retired
 #       (storage/database.py _rotate_commitlog)
+#
+# The launch-gap ledger (utils/dispatch.py LaunchLedger), compute scope,
+# counters that ride the stage clock's batched publish: every
+# jit_tracker block (the five served ops' calls and the waits for their
+# results) opens and closes it, process-wide:
+#   compute_launch_gap_seconds {stage}         time in which no tracked
+#       call was open, charged when the next launch opens to the stages
+#       of the launching thread, innermost first (trace.stages_in, the
+#       rule of tools/trace_gaps.py); "(no stage open)" where none was,
+#       or beyond the thread's record of closed stages
+#   compute_launch_outstanding_seconds         time in which one was:
+#       dispatch, device time, transfers, compiles and the wait for the
+#       interpreter to take the result. The two tile the time from the
+#       first launch on; the device idles in every gap
 #
 # Tier-resolution read routing (query/resolver.resolve_read), query.tier
 # scope with a {tier=...} label (raw / stitched / pinned_raw /

@@ -65,8 +65,10 @@ class QueryStats:
     node_legs: dict = field(default_factory=dict)
     # pipelined-dataflow overlap (storage/pipeline.py run_stages): how
     # many (shard, block) groups rode the executor, the wall time of the
-    # pipelined pass, and the per-stage (gather/decode) time sums —
-    # stage_sum > wall is overlap, surfaced on ?explain=analyze
+    # pipelined pass, and its time sums: the `gather` and `consume` legs
+    # (gather + consume > wall is overlap), the caller's `leg_wait` for
+    # the pool and, of it, the `handover` after a leg had finished —
+    # surfaced on ?explain=analyze
     pipeline_groups: int = 0
     pipeline_wall_s: float = 0.0
     pipeline_stage_s: dict = field(default_factory=dict)
@@ -107,14 +109,14 @@ class QueryStats:
         if self.index_segments:
             out["index"] = self.index_block()
         if self.pipeline_groups:
-            stage_sum = sum(self.pipeline_stage_s.values())
+            stage_sum = pipeline_leg_sum(self.pipeline_stage_s)
             out["pipeline"] = {
                 "groups": self.pipeline_groups,
                 "wall_ms": round(self.pipeline_wall_s * 1e3, 3),
                 "stage_ms": {k: round(v * 1e3, 3)
                              for k, v in self.pipeline_stage_s.items()},
                 "stage_sum_ms": round(stage_sum * 1e3, 3),
-                # >1.0 means stages overlapped in wall time
+                # >1.0 means the gather and consume legs overlapped
                 "overlap": round(stage_sum / self.pipeline_wall_s, 3)
                 if self.pipeline_wall_s > 0 else 0.0,
             }
@@ -267,11 +269,18 @@ def record(series_matched: int = 0, blocks_read: int = 0,
         st.decode_rungs[decode_rung] = st.decode_rungs.get(decode_rung, 0) + 1
 
 
+def pipeline_leg_sum(stages: dict) -> float:
+    """The legs' time of a pipeline block, gather + consume (the wait
+    and hand-over keys are the caller's, not legs)."""
+    return stages.get("gather", 0.0) + stages.get("consume", 0.0)
+
+
 def record_pipeline(groups: int, wall_s: float, stages: dict) -> None:
     """Accrue one pipelined-dataflow pass (storage/pipeline run_stages)
     onto the active query's record: groups scheduled, wall time, and
-    per-stage time sums. ?explain=analyze renders wall vs stage-sum so
-    the gather/decode overlap is visible per query. No-op outside one."""
+    the pass's time sums (``StageStats.stages``). ?explain=analyze
+    renders wall vs the legs' sum so the gather/consume overlap is
+    visible per query. No-op outside one."""
     st = getattr(_tls, "current", None)
     if st is None or not groups:
         return

@@ -44,10 +44,20 @@ that sum. While a device-trace session runs (utils/backend
 ``start_device_trace``), a sampled stage also enters a
 ``jax.profiler.TraceAnnotation`` of its name, so it lands in the xplane
 on the device trace's clock.
+
+What a thread was doing at a given instant. Each thread keeps the start
+of its open stages and a record of the last ``_CLOSED_RECORD`` stages it
+closed, metered or not; ``Tracer.stages_between(a, b)`` charges an
+interval of the thread's past to the innermost stage open in each piece
+of it (``stages_in``, the rule ``tools/trace_gaps.py`` applies to an
+xplane). The launch-gap ledger (utils/dispatch.py) charges the device's
+unlaunched time to stages with it, and its counters reach the registry
+with the thread's stages (``add_counters``).
 """
 
 from __future__ import annotations
 
+import heapq
 import itertools
 import os
 import random
@@ -70,8 +80,9 @@ SHARD_FLUSH = "storage.shard.flush"
 SESSION_FETCH = "client.session.fetch_many"
 AGG_FLUSH = "aggregator.flush"
 READ_MANY = "storage.ns.read_many"
-# the request thread's CSR assembly of a batched read, every shard's
-# finalize and the namespace-level combine: a plain span, not a stage
+# the request thread's CSR assembly of a pipelined batched read
+# (Namespace._assemble_read: every shard's buffer leg, the one join and
+# merge over every row, the limits' charge): a plain span, not a stage
 READ_ASSEMBLE = "storage.ns.read_assemble"
 DBNODE_HANDLE = "dbnode.handle"
 FANOUT_READ = "query.fanout.read_many"
@@ -131,6 +142,12 @@ ROUTE_TICK = STAGE_TICK   # the mediator's cycle: root stage and route
 # a thread whose outermost span stays open publishes its closed stages
 # at this many (a request closes some twenty)
 _PENDING_CAP = 512
+# closed stages a thread remembers for stages_between: a 56-group read
+# closes a consume stage a group, so a few requests' worth
+_CLOSED_RECORD = 256
+# the name of time in which no stage was open on the thread, or that
+# lies before its record of closed stages
+NO_STAGE = "(no stage open)"
 
 _ZERO_SPAN_ID = "0" * 16
 # placeholder trace id carried by a negative head decision's context —
@@ -237,10 +254,12 @@ class StageFrame:
     miss renames its stage), ``tag()`` reaches the ring span when the
     request is sampled, ``wall_ns``/``cpu_ns`` are readable after the
     close. ``covered_*`` is what the metered stages beneath have
-    covered of this frame's time, on this thread."""
+    covered of this frame's time, on this thread. ``start_ns`` is a
+    stage's ``perf_counter_ns`` at its open; 0 on a plain span's frame,
+    which is no stage."""
 
     __slots__ = ("name", "span", "covered_ns", "covered_cpu_ns",
-                 "wall_ns", "cpu_ns")
+                 "wall_ns", "cpu_ns", "start_ns")
 
     def __init__(self, name: str, span: "Span | None" = None):
         self.name = name
@@ -249,6 +268,7 @@ class StageFrame:
         self.covered_cpu_ns = 0
         self.wall_ns = 0
         self.cpu_ns = 0
+        self.start_ns = 0
 
     def tag(self, **tags) -> None:
         if self.span is not None:
@@ -304,19 +324,77 @@ class Tracer:
             pending = self._tl.pending = []
         return pending
 
+    def _closed(self) -> deque:
+        """This thread's last closed stages, (name, start_ns, end_ns),
+        in the order they closed (so their ends ascend)."""
+        closed = getattr(self._tl, "closed", None)
+        if closed is None:
+            # a per-thread record that forgets its oldest entry by
+            # design (stages_between names what it has forgotten)
+            # m3lint: disable=inv-queue-gauge
+            closed = self._tl.closed = deque(maxlen=_CLOSED_RECORD)
+        return closed
+
     def _publish(self) -> None:
-        """The thread's outermost span has closed: its stages reach the
-        two families in one acquisition of the registry's lock. From
-        inside the request they cost two acquisitions a stage of a lock
-        every metric of the process shares, which on the chip cost
-        `double-groupby-1` 5% of its latency (PERF.md section 6)."""
-        pending = getattr(self._tl, "pending", None)
-        if not pending:
+        """The thread's outermost span has closed: its stages, and the
+        counters added beside them, reach the registry in one
+        acquisition of its lock. From inside the request they cost two
+        acquisitions a stage of a lock every metric of the process
+        shares, which on the chip cost `double-groupby-1` 5% of its
+        latency (PERF.md section 6)."""
+        tl = self._tl
+        pending = getattr(tl, "pending", None)
+        counts = getattr(tl, "counts", None)
+        if not pending and not counts:
             return
+        increments = [("query.stage.cpu_seconds", tags, c)
+                      for tags, _w, c in pending or ()]
+        if counts:
+            increments += counts
         _instrument.default_registry().record_many(
-            [("query.stage.seconds", tags, w) for tags, w, _c in pending],
-            [("query.stage.cpu_seconds", tags, c) for tags, _w, c in pending])
-        pending.clear()
+            [("query.stage.seconds", tags, w) for tags, w, _c in pending
+             or ()], increments)
+        if pending:
+            pending.clear()
+        if counts:
+            counts.clear()
+
+    def add_counters(self, increments) -> None:
+        """Counter increments [(name, tags, delta)] that reach the
+        registry with this thread's stages (``_publish``); at once where
+        no span is open on the thread."""
+        counts = getattr(self._tl, "counts", None)
+        if counts is None:
+            counts = self._tl.counts = []
+        counts.extend(increments)
+        if not self._stack() or len(counts) >= _PENDING_CAP:
+            self._publish()
+
+    def stages_between(self, a_ns: int, b_ns: int) -> dict:
+        """Seconds of [a_ns, b_ns) (``perf_counter_ns``, b_ns at most
+        now) on this thread, per innermost stage open in each piece of
+        it: the stages still open and those its record holds. Time in
+        which no stage was open, or older than the record reaches, is
+        ``NO_STAGE``'s."""
+        events = []
+        horizon = a_ns
+        closed = getattr(self._tl, "closed", None)
+        if closed:
+            if len(closed) == closed.maxlen:
+                # a stage that closed before the record's oldest end may
+                # have been dropped from it: no piece before that end
+                # can be named
+                horizon = min(b_ns, max(a_ns, closed[0][2]))
+            for ev in reversed(closed):
+                if ev[2] <= a_ns:
+                    break
+                events.append(ev)
+        events += [(fr.name, fr.start_ns, b_ns) for fr in self._stack()
+                   if fr.start_ns]
+        out = stages_in(events, horizon, b_ns)
+        if horizon > a_ns:
+            out[NO_STAGE] = out.get(NO_STAGE, 0.0) + (horizon - a_ns) / 1e9
+        return out
 
     # -- context plumbing --
 
@@ -461,7 +539,7 @@ class Tracer:
             ann.__enter__()
         # the wall pair encloses the CPU pair, so CPU <= wall per stage;
         # an unmetered stage feeds no CPU counter and reads no CPU clock
-        t0 = time.perf_counter_ns()
+        t0 = frame.start_ns = time.perf_counter_ns()
         c0 = time.thread_time_ns() if metered else 0
         try:
             yield frame
@@ -471,6 +549,7 @@ class Tracer:
             if ann is not None:
                 ann.__exit__(None, None, None)
             stack.pop()
+            self._closed().append((frame.name, t0, t0 + wall))
             parent = stack[-1] if stack else None
             if metered:
                 if parent is not None:
@@ -530,6 +609,32 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
+
+
+def stages_in(events, a: int, b: int) -> dict:
+    """Seconds of [a, b) per innermost stage among ``events``, (name,
+    start, end) on one thread's clock in ns: the interval is cut at every
+    start and end inside it, and each piece goes to the latest-starting
+    event that covers it (the later end, then the later name, on a tie),
+    or to ``NO_STAGE`` where none does. The one rule for the launch-gap
+    ledger and ``tools/trace_gaps.py``."""
+    evs = sorted((s, e, n) for n, s, e in events if s < b and e > a)
+    cuts = sorted({a, b, *(t for s, e, _ in evs for t in (s, e)
+                           if a < t < b)})
+    out: dict = {}
+    heap: list = []     # (-start, -end, -index): the innermost first
+    i = 0
+    for lo, hi in zip(cuts, cuts[1:]):
+        while i < len(evs) and evs[i][0] <= lo:
+            s, e, _ = evs[i]
+            heapq.heappush(heap, (-s, -e, -i))
+            i += 1
+        # an end lies on a cut, so one before `hi` has passed for good
+        while heap and -heap[0][1] < hi:
+            heapq.heappop(heap)
+        name = evs[-heap[0][2]][2] if heap else NO_STAGE
+        out[name] = out.get(name, 0.0) + (hi - lo) / 1e9
+    return out
 
 
 def build_tree(spans: list[dict]) -> list[dict]:

@@ -8,7 +8,11 @@ originals read (``api_query_ms.live`` alone by another reader: its
 original's histogram has no route label), its queries are
 ``tsbs-cpu-2k.dash``'s, its scrapes offer the fleet's own rate, and
 every one of its twenty-six metrics comes back as a number from a sound
-window (the driver refuses a traced line that lacks one). Pure JSON and the harness's readers, no JAX.
+window (a traced line that lacks one is refused); and the read
+pipeline's parts and the launch-gap share that ``tsbs-cpu-2k.dash`` and
+``tsbs-cpu-14h.ranges`` read come back as numbers from a sound window and
+are left out where the program lacks their families. Pure JSON and the
+harness's readers, no JAX.
 """
 
 import os
@@ -303,8 +307,15 @@ RANGES_TWINS = [("hot_tier_hit_pct.ranges", "hot_tier_hit_pct"),
         "api_query_ms", "fetch_ms", "index_match_ms", "decode_host_ms",
         "decode_device_wait_ms", "decode_streams_per_launch", "slab_prep_ms",
         "plan_device_wait_ms", "render_ms", "query_cpu_ms")]
+# the read pipeline's parts and the launch-gap ledger's share, one of
+# each in .dash and in .ranges
+READ_PARTS = ["fetch_legs_per_read", "fetch_plan_ms", "fetch_leg_queue_ms",
+              "fetch_leg_wait_ms", "fetch_leg_handover_ms", "fetch_consume_ms"]
+DASH_NEW = READ_PARTS + ["device_unlaunched_pct.dash"]
 RANGES_NEW = ["block_cache_hit_pct.ranges", "blocks_per_series.ranges",
-              "fetch_assemble_ms.ranges"]
+              "fetch_assemble_ms.ranges"] + [
+    name + ".ranges" for name in READ_PARTS] + [
+    "device_unlaunched_pct.ranges"]
 # TSBS uses/devops: (range, step and window, hosts, fields) of each type
 TSBS_RANGES = {"single-groupby-1-1-12": (43200, 60, 1, 1),
                "single-groupby-5-1-12": (43200, 60, 1, 5),
@@ -432,9 +443,34 @@ def _ranges_window(queries=500):
         m["coordinator_request_seconds_sum"] = k * queries * 0.3
         m["coordinator_request_seconds_count"] = float(k * queries)
         m["jit_run[miss]"] = 3.0
+        m.update(_read_parts(k * 450, 56))
         return m
 
     return at(1), at(2)
+
+
+def _read_parts(reads, legs):
+    """What /metrics holds of the read pipeline's histograms after
+    `reads` pooled reads of `legs` legs, and of the launch-gap ledger."""
+    m = {}
+    per_read = {"legs": legs, "plan_seconds": 0.002,
+                "leg_queue_seconds": 0.001, "leg_wait_seconds": 0.15,
+                "leg_handover_seconds": 0.1, "consume_seconds": 0.02}
+    for fam, v in per_read.items():
+        m[f"storage_read_{fam}_sum"] = reads * v
+        m[f"storage_read_{fam}_count"] = float(reads)
+        m[f'storage_read_{fam}_bucket{{le="+Inf"}}'] = float(reads)
+    m['compute_launch_gap_seconds{stage="read_many"}'] = reads * 0.03
+    m['compute_launch_gap_seconds{stage="(no stage open)"}'] = reads * 0.01
+    m["compute_launch_outstanding_seconds"] = reads * 0.01
+    return m
+
+
+# the numbers _read_parts gives, as the readers should read them
+READ_PARTS_WANT = {"fetch_plan_ms": 2.0, "fetch_leg_queue_ms": 1.0,
+                   "fetch_leg_wait_ms": 150.0, "fetch_leg_handover_ms": 100.0,
+                   "fetch_consume_ms": 20.0, "device_unlaunched_pct": 80.0}
+_LACKS_READ_PARTS = re.compile(r"storage_read_(?!series)|compute_launch_")
 
 
 def _ranges_reading(before, after):
@@ -461,7 +497,9 @@ def test_a_ranges_metric_reads_a_number_from_a_sound_window(name):
             "api_query_ms.ranges": 300.0, "render_ms.ranges": 50.0,
             "fetch_ms.ranges": 0.9 * 50.0,
             "decode_streams_per_launch.ranges": 60.0,
-            "compiles_in_window.ranges": 0.0}
+            "compiles_in_window.ranges": 0.0,
+            "fetch_legs_per_read.ranges": 56.0}
+    want.update({k + ".ranges": v for k, v in READ_PARTS_WANT.items()})
     if name in want:
         assert value == pytest.approx(want[name])
     if spec["unit"] == "%":
@@ -471,13 +509,14 @@ def test_a_ranges_metric_reads_a_number_from_a_sound_window(name):
 @pytest.mark.parametrize("name", RANGES_METRICS)
 def test_a_ranges_metric_is_left_out_where_the_program_lacks_its_source(
         name):
-    """The parent's program has neither the block cache's counters nor
-    the series counter nor the assembly's histogram: the three new
-    metrics' readers return nothing and do not raise; the twins read."""
+    """A program without the block cache's counters, the series
+    counter, the assembly's histogram, the read pipeline's histograms or
+    the launch-gap ledger: the new metrics' readers return nothing and
+    do not raise; the twins read."""
     from harness.readers import READERS
 
-    new = re.compile(r"storage_block_cache_|storage_read_series"
-                     r"|db_read_assemble_seconds")
+    new = re.compile(r"storage_block_cache_|storage_read_"
+                     r"|db_read_assemble_seconds|compute_launch_")
     before, after = ({k: v for k, v in m.items() if not new.search(k)}
                      for m in _ranges_window())
     spec = _metric(name)
@@ -489,8 +528,9 @@ def test_a_ranges_metric_is_left_out_where_the_program_lacks_its_source(
 @pytest.mark.parametrize("name", RANGES_NEW)
 def test_a_new_ranges_metric_reads_a_counter_or_histogram_the_program_has(
         name):
-    """Each name the three new metrics read is a counter or a histogram
-    of storage/ (read as text: no import of the program)."""
+    """Each name the new metrics read is a counter or a histogram of
+    storage/ or of the launch-gap ledger (read as text: no import of the
+    program)."""
     spec = _metric(name)
     regexes = list(spec["args"].get("num", [])) + list(
         spec["args"].get("den", []))
@@ -498,15 +538,73 @@ def test_a_new_ranges_metric_reads_a_counter_or_histogram_the_program_has(
         facts = _ranges_cell()["traffic_params"]["window_facts"]
         regexes = [rx for fact in (spec["args"]["num"], spec["args"]["den"])
                    for rx in facts[fact]["keys"]]
+    if spec["reader"] == "prom_hist_mean":
+        regexes = [spec["args"]["family"]]
     text = "".join(open(os.path.join(REPO, "m3_tpu", "storage", f)).read()
                    for f in ("shard.py", "namespace.py"))
+    with open(os.path.join(REPO, "m3_tpu", "utils", "dispatch.py")) as f:
+        ledger = f.read()
     for rx in regexes:
         m = re.fullmatch(r"storage_(\w+?)_(hit|miss|series)", rx)
         if m:
             assert f'"storage.{m.group(1)}.{m.group(2)}"' in text \
                 or f'"storage.{m.group(1)}_{m.group(2)}"' in text
+        elif rx.startswith("storage_read_"):
+            assert f'"storage.{rx[len("storage_"):]}"' in text
+        elif rx.startswith("compute_launch_"):
+            leaf = re.match(r"compute_(launch_\w+)", rx).group(1)
+            assert f'"compute.{leaf}"' in ledger
         elif rx.startswith("db_"):
             leaf = rx[len("db_"):-len("_sum")]
             assert f'.histogram("{leaf}")' in text
         else:
             assert "query_stage_seconds_count" in rx
+
+
+# -- the read pipeline's parts in tsbs-cpu-2k.dash ----------------------------
+
+
+def test_the_dash_cells_new_metrics_are_listed_for_it_alone():
+    dash = [m["name"] for m in MANIFEST["per_layer"]
+            if DASH in m["workloads"]]
+    assert [n for n in dash if n in DASH_NEW] == DASH_NEW
+    assert all(m["workloads"] == [DASH] for m in MANIFEST["per_layer"]
+               if m["name"] in DASH_NEW)
+    # each reads what its .ranges twin reads
+    for name in DASH_NEW:
+        twin = name.replace(".dash", "") + ".ranges"
+        got, spec = _metric(name), _metric(twin)
+        assert (got["reader"], got["args"], got["unit"], got["source"],
+                got["layer"], got["moves"]) == (
+            spec["reader"], spec["args"], spec["unit"], spec["source"],
+            spec["layer"], spec["moves"])
+
+
+def _dash_window():
+    """(before, after): the read pipeline's families over a window of
+    300 pooled reads of 8 legs."""
+    return _read_parts(300, 8), _read_parts(600, 8)
+
+
+@pytest.mark.parametrize("name", DASH_NEW)
+def test_a_new_dash_metric_reads_a_number_from_a_sound_window(name):
+    from harness.readers import READERS, Reading
+
+    spec = _metric(name)
+    value = READERS[spec["reader"]](
+        Reading(*_dash_window(), 51.0, {}, {}), **spec.get("args", {}))
+    assert isinstance(value, float)
+    want = {"fetch_legs_per_read": 8.0, **READ_PARTS_WANT}
+    assert value == pytest.approx(want[name.replace(".dash", "")])
+
+
+@pytest.mark.parametrize("name", DASH_NEW)
+def test_a_new_dash_metric_is_left_out_where_the_program_lacks_it(name):
+    from harness.readers import READERS, Reading
+
+    before, after = ({k: v for k, v in m.items()
+                      if not _LACKS_READ_PARTS.search(k)}
+                     for m in _dash_window())
+    spec = _metric(name)
+    assert READERS[spec["reader"]](Reading(before, after, 51.0, {}, {}),
+                                   **spec.get("args", {})) is None

@@ -10,7 +10,9 @@ reference (``benchmarks/harness/ranges.py``) and with the float64
 interpreter; the three counters the cell reads add up (lookups = series x
 blocks touched, ``storage_read_series`` = the ids read), the CSR
 assembly is timed once a read, and a repeat of the same range is served
-from the hot tier without a read.
+from the hot tier without a read. On a seven-block namespace the request
+thread's timed parts of a pooled read (plan, leg wait, consume, assembly)
+make up the stage ``read_many``'s self-time.
 """
 
 import json
@@ -190,3 +192,89 @@ def test_an_evicting_cache_both_hits_and_misses(deployment):
     grew = _grew(before)
     assert grew["hit"] > 0 and grew["miss"] > 0
     assert grew["series"] == 3 * 10
+
+
+# -- the read's parts against its stage --------------------------------------
+
+PARTS = ("storage.read_plan_seconds", "storage.read_leg_wait_seconds",
+         "storage.read_consume_seconds", "db.read_assemble_seconds")
+
+
+def _seven_blocks(root, n_series=320, points=720):
+    """Eight shards of seven sealed 2 h blocks, written as fileset volumes
+    (the shape of a 12 h read in tsbs-cpu-14h: 56 groups)."""
+    from m3_tpu.encoding.m3tsz import hostpath
+    from m3_tpu.storage.database import Database
+    from m3_tpu.storage.fileset import FilesetWriter
+    from m3_tpu.storage.options import (DatabaseOptions, IndexOptions,
+                                        NamespaceOptions, RetentionOptions)
+    from m3_tpu.utils.xtime import TimeUnit
+
+    block = 2 * 3600 * NS
+    first = 1_790_006_400 * NS
+    db = Database(str(root), DatabaseOptions(n_shards=8,
+                                             block_cache_entries=400))
+    ns = db.create_namespace("default", NamespaceOptions(
+        retention=RetentionOptions(retention_ns=100 * block,
+                                   block_size_ns=block),
+        index=IndexOptions(enabled=False),
+        writes_to_commitlog=False, snapshot_enabled=False))
+    ids = [b"closure-%04d" % i for i in range(n_series)]
+    by_shard: dict = {}
+    for sid in ids:
+        by_shard.setdefault(ns.shard_set.lookup(sid), []).append(sid)
+    rng = np.random.default_rng(41)
+    for shard_id, sids in by_shard.items():
+        for b in range(7):
+            bs = first + b * block
+            times = np.broadcast_to(
+                bs + np.arange(points, dtype=np.int64) * 10 * NS,
+                (len(sids), points)).copy()
+            values = rng.normal(50.0, 10.0, (len(sids), points))
+            streams = hostpath.encode_blocks(
+                times, values.view(np.uint64), np.full(len(sids), bs),
+                np.full(len(sids), points, np.int32), TimeUnit.SECOND,
+                False)
+            w = FilesetWriter(db.fs_root, "default", shard_id, bs, block, 0)
+            for sid, stream in zip(sids, streams):
+                w.write_series(sid, b"", stream)
+            w.close()
+    db.open(first + 7 * block)
+    return db, ns, ids, first, first + 7 * block
+
+
+def _read_sums(route):
+    counters, _g, _t, hists = default_registry().snapshot()
+    out = {name: hists[(name, ())][2] if (name, ()) in hists else 0.0
+           for name in PARTS}
+    key = ("query.stage.seconds", (("route", route), ("stage", "read_many")))
+    out["read_many"] = hists[key][2] if key in hists else 0.0
+    return out
+
+
+def test_the_reads_timed_parts_make_up_its_stage(tmp_path, monkeypatch):
+    """Plan + leg wait + consume + assembly of seven-block reads equal the
+    stage read_many's self-time within 5%: the rest is the submits, the
+    span and the histograms' own bookkeeping."""
+    from m3_tpu.utils import trace
+
+    monkeypatch.setenv("M3_TPU_PIPELINE", "1")
+    db, ns, ids, lo, hi = _seven_blocks(tmp_path / "seven")
+    route = "t_read_closure"
+    tr = trace.default_tracer()
+    try:
+        ns.read_many_ragged(ids, lo, hi)            # warm the readers
+        before = _read_sums(route)
+        with tr.activate(trace.SpanContext(trace.new_trace_id(), "", True,
+                                           route)):
+            for _ in range(4):
+                with tr.stage(trace.STAGE_READ_MANY):
+                    ns.read_many_ragged(ids, lo, hi)
+        after = _read_sums(route)
+    finally:
+        db.close()
+    grew = {k: after[k] - before[k] for k in after}
+    parts = sum(grew[k] for k in PARTS)
+    assert grew["read_many"] > 0
+    assert parts == pytest.approx(grew["read_many"], rel=0.05)
+

@@ -167,11 +167,12 @@ class TestExecutor:
             lambda i: (time.sleep(0.004), i)[1],
             lambda i, p: time.sleep(0.004), depth=4)
         assert stats.items == 8
-        assert set(stats.stages) == {"gather", "decode"}
+        assert set(stats.stages) == {"gather", "consume", "leg_wait",
+                                     "handover"}
         assert stats.wall_s > 0
         if pipeline.active():
-            # stage sums exceed wall when legs genuinely overlapped
-            assert sum(stats.stages.values()) > stats.wall_s
+            # the legs' sums exceed wall when they genuinely overlapped
+            assert querystats.pipeline_leg_sum(stats.stages) > stats.wall_s
 
     def test_task_queues_ride_the_saturation_plane(self):
         from m3_tpu.utils.instrument import default_registry
@@ -251,7 +252,7 @@ class TestPipelinedReads:
     def test_one_decode_per_read(self, tmp_path, n_shards, n_blocks):
         """The read's whole miss set is one batched decode, whatever its
         (shard, block) groups; the query record still counts the groups
-        and the decode still sits in the pipeline's decode leg."""
+        and the consume legs that land them."""
         from m3_tpu.utils import dispatch
 
         db, ns, ids = build_multiblock_db(
@@ -268,7 +269,7 @@ class TestPipelinedReads:
             assert st.pipeline_groups == n_shards * n_blocks
             assert sum(st.decode_rungs.values()) == 1
             assert st.cache_misses == 128 * n_blocks
-            assert st.pipeline_stage_s["decode"] > 0
+            assert st.pipeline_stage_s["consume"] > 0
             assert all(len(t) == 6 * n_blocks for t, _ in res)
         finally:
             db.close()
@@ -371,16 +372,23 @@ class TestPipelinedReads:
             db.close()
 
     def test_querystats_and_explain_report_overlap(self, tmp_path):
+        from m3_tpu.query import explain
+
         db, ns, ids = build_multiblock_db(tmp_path)
         try:
             st = querystats.start(query="pipeline-test")
-            ns.read_many(ids, START, START + 4 * BLOCK)
+            col = explain.Collector(analyze=True)
+            with col.node(object()) as entry:
+                ns.read_many(ids, START, START + 4 * BLOCK)
             assert st.pipeline_groups > 0
-            assert set(st.pipeline_stage_s) == {"gather", "decode"}
+            keys = {"gather", "consume", "leg_wait", "handover"}
+            assert set(st.pipeline_stage_s) == keys
             doc = st.to_dict()
             assert doc["pipeline"]["groups"] == st.pipeline_groups
             assert doc["pipeline"]["stage_sum_ms"] >= 0
             assert "overlap" in doc["pipeline"]
+            # ?explain=analyze's node block carries the same four
+            assert set(entry["pipeline"]["stage_ms"]) == keys
             querystats.finish(st)
         finally:
             db.close()
@@ -405,6 +413,138 @@ class TestPipelinedReads:
             db.limits.end_query()
         finally:
             db.close()
+
+
+class TestReadTimers:
+    """A namespace read whose groups went to the pool observes its parts
+    once a read (Namespace._read_many_pipelined): legs, plan, the legs'
+    queue, the request thread's wait for them and the hand-over part of
+    it, and the consume legs."""
+
+    FAMILIES = ("legs", "plan_seconds", "leg_queue_seconds",
+                "leg_wait_seconds", "leg_handover_seconds",
+                "consume_seconds")
+
+    @staticmethod
+    def _sums() -> dict:
+        from m3_tpu.utils.instrument import default_registry
+
+        _c, _g, _t, hists = default_registry().snapshot()
+        out = {}
+        for fam in TestReadTimers.FAMILIES:
+            h = hists.get(("storage.read_" + fam, ()))
+            out[fam] = (h[2], h[3]) if h else (0.0, 0)
+        return out
+
+    def _read(self, ns, ids, blocks) -> dict:
+        before = self._sums()
+        ns.read_many(ids, START, START + blocks * BLOCK)
+        after = self._sums()
+        grew = {k: (after[k][0] - before[k][0], after[k][1] - before[k][1])
+                for k in after}
+        assert {n for _s, n in grew.values()} == {1}     # once a read
+        return {k: s for k, (s, _n) in grew.items()}
+
+    def test_legs_that_sleep_read_back_as_the_wait(self, tmp_path,
+                                                   monkeypatch):
+        """Two groups, both on the pool at once (depth 2), each gather
+        sleeping 50 ms: the request thread waits about 50 ms, for the
+        first; the second has finished by then."""
+        from m3_tpu.storage.shard import _FilesetReadGroup
+
+        db, ns, ids = build_multiblock_db(tmp_path, n_series=32,
+                                          n_blocks=1, n_shards=2)
+        gather = _FilesetReadGroup.gather
+        monkeypatch.setattr(_FilesetReadGroup, "gather",
+                            lambda g: (time.sleep(0.05), gather(g))[1])
+        monkeypatch.setenv("M3_TPU_PIPELINE_DEPTH", "2")
+        try:
+            grew = self._read(ns, ids, 1)
+            assert grew["legs"] == 2
+            assert 0.045 <= grew["leg_wait_seconds"] <= 0.1
+            assert grew["leg_handover_seconds"] <= grew["leg_wait_seconds"]
+            assert grew["leg_queue_seconds"] < 0.04
+            assert 0 < grew["plan_seconds"] < 0.04
+        finally:
+            db.close()
+
+    def test_a_consumer_slower_than_every_leg_neither_waits_nor_is_handed_over(
+            self, tmp_path, monkeypatch):
+        """Each consume sleeps 30 ms, far past the next legs' ends: the
+        request thread finds every later leg done, so no wait and no
+        hand-over beyond the first leg's start-up."""
+        from m3_tpu.storage.shard import _FilesetReadGroup
+
+        db, ns, ids = build_multiblock_db(tmp_path, n_series=32,
+                                          n_blocks=3, n_shards=2)
+        consume = _FilesetReadGroup.consume
+        monkeypatch.setattr(_FilesetReadGroup, "consume",
+                            lambda g, p: (time.sleep(0.03), consume(g, p))[1])
+        try:
+            grew = self._read(ns, ids, 3)
+            assert grew["legs"] == 6
+            assert grew["consume_seconds"] >= 6 * 0.03
+            assert grew["leg_wait_seconds"] < 0.015
+            assert grew["leg_handover_seconds"] < 0.01
+        finally:
+            db.close()
+
+    def test_a_spinning_thread_makes_the_hand_over_cost(self, tmp_path):
+        """A pure-Python thread spinning beside the read holds the
+        interpreter: a finished leg waits for the request thread to get
+        it back, which is the hand-over."""
+        import threading
+
+        db, ns, ids = build_multiblock_db(tmp_path, n_series=64,
+                                          n_blocks=4, n_shards=4)
+        stop = threading.Event()
+        deadline = time.monotonic() + 30
+
+        def spin():
+            while not stop.is_set() and time.monotonic() < deadline:
+                pass
+
+        spinner = threading.Thread(target=spin, daemon=True)
+        try:
+            spinner.start()
+            grew = self._read(ns, ids, 4)
+        finally:
+            stop.set()
+            spinner.join(timeout=10)
+            db.close()
+        assert not spinner.is_alive()
+        assert grew["legs"] == 16
+        assert grew["leg_handover_seconds"] > 0.001
+        assert grew["leg_handover_seconds"] <= grew["leg_wait_seconds"]
+
+    def test_an_inline_read_observes_nothing(self, tmp_path, monkeypatch):
+        """One group runs inline, and the serial hatch runs every group
+        inline: no read went to the pool, so none is timed."""
+        db, ns, ids = build_multiblock_db(tmp_path, n_series=32,
+                                          n_blocks=1, n_shards=1)
+        try:
+            before = self._sums()
+            ns.read_many(ids, START, START + BLOCK)
+            monkeypatch.setenv("M3_TPU_PIPELINE", "0")
+            db2, ns2, ids2 = build_multiblock_db(tmp_path / "serial")
+            try:
+                ns2.read_many(ids2, START, START + 4 * BLOCK)
+            finally:
+                db2.close()
+            assert self._sums() == before
+        finally:
+            db.close()
+
+    def test_the_pipelines_own_histogram_is_gone(self, tmp_path):
+        from m3_tpu.utils import metric_catalog
+        from m3_tpu.utils.instrument import default_registry
+
+        pipeline.run_stages(list(range(4)), lambda i: i, lambda i, p: None)
+        _c, _g, _t, hists = default_registry().snapshot()
+        assert not [k for k in hists if k[0].startswith("pipeline.")]
+        assert "stage_seconds" not in metric_catalog.HISTOGRAMS
+        assert {"read_" + f for f in self.FAMILIES} \
+            <= metric_catalog.HISTOGRAMS
 
 
 # ---------------------------------------------------------------------------

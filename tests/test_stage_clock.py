@@ -420,12 +420,168 @@ class TestPipelineLegs:
         # the per-leg seconds are the spans'
         assert stats.stages["gather"] == pytest.approx(
             sum(s["duration_us"] for s in legs[:3]) / 1e6, abs=1e-5)
-        assert stats.stages["decode"] > 0 and one.stages["gather"] >= 0.002
+        assert stats.stages["consume"] > 0 and one.stages["gather"] >= 0.002
         # three worker legs are the stage read_many.gather; the one run
         # inline is the caller's own (read_many's self-time)
         m = _stage_metrics("t_legs")
         assert m[trace.STAGE_GATHER][1] == 3
         assert m[trace.STAGE_READ_MANY][0] >= 0.002
+
+
+# -- the launch-gap ledger ---------------------------------------------------
+
+
+def _ledger_counters() -> dict:
+    """{stage: gap seconds} and the outstanding seconds, from the default
+    registry."""
+    counters, _g, _t, _h = default_registry().snapshot()
+    gaps = {dict(tags)["stage"]: v for (name, tags), v in counters.items()
+            if name == "compute.launch_gap_seconds"}
+    return {"gap": gaps, "outstanding": counters.get(
+        ("compute.launch_outstanding_seconds", ()), 0.0)}
+
+
+def _old_stages_in(events, a, b):
+    """The split as tools/trace_gaps.py wrote it before it was shared: a
+    brute-force reference, seconds of [a, b) per latest-starting event."""
+    open_in = [(s, e, n) for n, s, e in events if s < b and e > a]
+    cuts = sorted({a, b, *(t for s, e, _ in open_in for t in (s, e)
+                           if a < t < b)})
+    out: dict = {}
+    for lo, hi in zip(cuts, cuts[1:]):
+        inner = max((ev for ev in open_in if ev[0] <= lo and ev[1] >= hi),
+                    default=None)
+        name = inner[2] if inner else trace.NO_STAGE
+        out[name] = out.get(name, 0.0) + (hi - lo) / 1e9
+    return out
+
+
+class TestLaunchLedger:
+    def test_a_gap_is_charged_to_the_stage_that_filled_it(self):
+        prog = _FakeProgram(np.zeros(1))
+        tr = trace.default_tracer()
+        ledger = dispatch.launches
+        with tr.activate(_ctx("t_ledger")):
+            with dispatch.jit_tracker("t_ledger_op", prog):
+                time.sleep(0.01)
+            t_first = time.perf_counter_ns()   # just after the 1-to-0 edge
+            before = _ledger_counters()
+            totals = ledger.gap_ns + ledger.outstanding_ns
+            with tr.stage("t.ledger.host"):
+                time.sleep(0.05)
+            with dispatch.jit_tracker("t_ledger_op", prog):
+                time.sleep(0.02)
+            t_last = time.perf_counter_ns()
+        after = _ledger_counters()
+        grew = {k: v - before["gap"].get(k, 0.0)
+                for k, v in after["gap"].items()}
+        # the sleep's stage has the gap, what lies around it no stage
+        assert 0.05 <= grew["t.ledger.host"] < 0.06
+        assert sum(grew.values()) - grew["t.ledger.host"] < 0.005
+        assert not [k for k, v in grew.items() if v and k not in (
+            "t.ledger.host", trace.NO_STAGE)]
+        outstanding = after["outstanding"] - before["outstanding"]
+        assert 0.02 <= outstanding < 0.03
+        # gap + outstanding tile the time between the two 1-to-0 edges,
+        # on the counters and on the ledger's own totals alike
+        elapsed = (t_last - t_first) / 1e9
+        assert sum(grew.values()) + outstanding == pytest.approx(
+            elapsed, abs=5e-4)
+        assert (ledger.gap_ns + ledger.outstanding_ns - totals) / 1e9 == \
+            pytest.approx(elapsed, abs=5e-4)
+
+    def test_nested_blocks_open_once_and_a_raise_closes(self):
+        prog = _FakeProgram(np.zeros(1))
+        ledger = dispatch.LaunchLedger(Tracer())
+        dispatch.launches, saved = ledger, dispatch.launches
+        try:
+            with dispatch.jit_tracker("t_nest_op", prog):
+                with dispatch.jit_tracker("t_nest_op", prog):
+                    pass
+            assert ledger._open == 0 and ledger.gap_ns == 0
+            with pytest.raises(ValueError):
+                with dispatch.jit_tracker("t_nest_op", prog):
+                    raise ValueError("the call failed")
+            assert ledger._open == 0 and ledger.gap_ns > 0
+        finally:
+            dispatch.launches = saved
+
+    def test_the_ledger_and_trace_gaps_split_a_thread_alike(self):
+        """The thread's record of closed stages and its open ones, as an
+        event list, split by trace_gaps' reduction over the same gap
+        give what the ledger's counters got."""
+        from m3_tpu.tools import trace_gaps
+
+        tr = Tracer()
+        ledger = dispatch.LaunchLedger(tr)
+        got: dict = {}
+        tr.add_counters = lambda incs: [
+            got.__setitem__(dict(t).get("stage"), got.get(
+                dict(t).get("stage"), 0.0) + v) for _n, t, v in incs]
+        with tr.activate(_ctx("t_split")):
+            with tr.stage("t.root") as root:
+                ledger.opened()
+                ledger.closed()
+                edge = ledger._edge_ns
+                with tr.stage("t.a"):
+                    time.sleep(0.004)
+                    with tr.stage("t.b", metered=False):
+                        time.sleep(0.003)
+                time.sleep(0.002)
+                with tr.stage("t.c") as c:
+                    time.sleep(0.002)
+                    got.clear()
+                    ledger.opened()
+                    now = ledger._edge_ns
+                    ledger.closed()
+        events = list(tr._closed()) + [("t.root", root.start_ns, now),
+                                       ("t.c", c.start_ns, now)]
+        names = {"t.root", "t.a", "t.b", "t.c"}
+        want = trace_gaps._stages_in(events, names, edge, now)
+        got.pop(None, None)
+        assert set(got) == set(want) == names
+        for k in want:
+            assert got[k] == pytest.approx(want[k], abs=1e-9)
+        assert want["t.b"] >= 0.003 and want["t.a"] >= 0.004
+
+    @pytest.mark.parametrize("seed", range(6))
+    def test_the_shared_split_keeps_trace_gaps_old_rule(self, seed):
+        """Random, overlapping and non-nested events, ties included: the
+        one implementation gives what the brute-force rule gave."""
+        rng = np.random.default_rng(seed)
+        events = []
+        for i in range(40):
+            s = int(rng.integers(0, 1000))
+            e = s + int(rng.integers(1, 300))
+            events.append((f"s{i % 7}", s, e))
+        events += [("tie-a", 100, 400), ("tie-b", 100, 400)]
+        for a, b in ((0, 1400), (150, 151), (250, 900), (1300, 1400)):
+            want = _old_stages_in(events, a, b)
+            got = trace.stages_in(events, a, b)
+            assert set(got) == set(want)
+            for k in want:
+                assert got[k] == pytest.approx(want[k], abs=1e-12)
+
+    def test_time_older_than_the_record_is_no_stages(self, monkeypatch):
+        monkeypatch.setattr(trace, "_CLOSED_RECORD", 4)
+        tr = Tracer()
+        t0 = time.perf_counter_ns()
+        with tr.activate(_ctx("t_horizon")):
+            for _ in range(6):
+                with tr.stage("t.short"):
+                    time.sleep(0.001)
+            now = time.perf_counter_ns()
+            split = tr.stages_between(t0, now)
+        closed = tr._closed()
+        assert len(closed) == 4
+        # the two dropped stages' time is no stage's: the split starts
+        # at the oldest remembered end
+        assert split[trace.NO_STAGE] >= (closed[0][2] - t0) / 1e9 - 1e-9
+        assert split["t.short"] == pytest.approx(
+            sum(e - max(s, closed[0][2]) for _n, s, e in closed) / 1e9,
+            abs=1e-9)
+        assert sum(split.values()) == pytest.approx((now - t0) / 1e9,
+                                                    abs=1e-9)
 
 
 # -- a served query_range on the forced device rungs -------------------------
@@ -619,6 +775,9 @@ class TestServedQueryRange:
         assert served["started"]["tracing"] is True
         assert served["stopped"]["tracing"] is False
         assert served["stopped"]["stop_seconds"] >= 0
+        # the launch-gap ledger over the session: ten requests launched
+        assert served["stopped"]["launch_outstanding_s"] > 0
+        assert served["stopped"]["launch_gap_s"] > 0
         assert served["status"] == {"tracing": False, "dir": None}
         [path] = glob.glob(os.path.join(
             served["trace_dir"], "plugins", "profile", "*", "*.xplane.pb"))

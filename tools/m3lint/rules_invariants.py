@@ -68,8 +68,9 @@ EXEMPT = {
     os.path.join("tools", "race_check.py"),  # stress harness
 }
 
-_OBS_ATTRS = {"span", "histogram", "observe", "counter", "timer", "gauge",
-              "subscope", "root_scope"}
+# `stage` is the stage clock's span (utils/trace.py stage())
+_OBS_ATTRS = {"span", "stage", "histogram", "observe", "counter", "timer",
+              "gauge", "subscope", "root_scope"}
 
 SERVICE_ENTRYPOINTS = (
     os.path.join("services", "coordinator.py"),
