@@ -15,6 +15,7 @@ import re
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+from itertools import compress
 from urllib.parse import parse_qs, urlparse
 
 import numpy as np
@@ -149,14 +150,48 @@ def _influx_unescape(raw: bytes) -> bytes:
     return raw.replace(b"\\,", b",").replace(b"\\ ", b" ").replace(b"\\=", b"=")
 
 
+# json.dumps' own string escaper under its default ensure_ascii=True
+_json_str = json.encoder.encode_basestring_ascii
+# the sample values Prometheus' JSON spells otherwise than Python's repr
+_VALUE_TEXT = {"nan": "NaN", "inf": "+Inf", "-inf": "-Inf"}
+
+
 def _fmt_value(v: float) -> str:
-    if np.isnan(v):
-        return "NaN"
-    if np.isposinf(v):
-        return "+Inf"
-    if np.isneginf(v):
-        return "-Inf"
-    return repr(float(v))
+    """One sample value as Prometheus' JSON writes it: the float's repr,
+    NaN and the infinities spelled its way."""
+    s = repr(float(v))
+    return _VALUE_TEXT.get(s, s)
+
+
+def _points_text(ts_sec: np.ndarray, values) -> list:
+    """Each row of `values` [S, n] as the JSON text of its `[t, "v"]`
+    points at the instants `ts_sec` [n], NaN points left out and without
+    the list's own brackets; None for a row with no point left. The text
+    is json.dumps' own for the same lists, byte for byte, made with one
+    NaN mask and one `tolist` an answer, one repr a timestamp and a
+    value, and one join a row."""
+    vals = np.asarray(values, dtype=np.float64)
+    ok = ~np.isnan(vals)
+    heads = ['[%s, "' % t for t in map(repr, ts_sec.tolist())]
+    texts = map(repr, vals.ravel().tolist())
+    if np.isinf(vals).any():
+        texts = [_VALUE_TEXT.get(s, s) for s in texts]
+    pairs = map(str.__add__, heads * len(vals), texts)
+    if not ok.all():
+        pairs = compress(pairs, ok.ravel().tolist())
+    pairs = list(pairs)
+    out, start = [], 0
+    for end in np.cumsum(ok.sum(axis=1)).tolist():
+        out.append('"], '.join(pairs[start:end]) + '"]'
+                   if end > start else None)
+        start = end
+    return out
+
+
+def _metric_text(lb: dict) -> str:
+    """A series' labels as json.dumps writes them as its `metric`."""
+    return "{%s}" % ", ".join([_json_str(k.decode()) + ": "
+                               + _json_str(v.decode()) for k, v in lb.items()])
 
 
 def _wants_openmetrics(q, headers) -> bool:
@@ -920,79 +955,56 @@ class CoordinatorAPI:
 
     def _render_json(self, result, eval_ts, matrix: bool, engine,
                      explain_doc):
+        """The answer as the bytes json.dumps gives for Prometheus'
+        envelope, `data` written a row at a time (`_points_text`)."""
         ts_sec = eval_ts.astype(np.float64) / NS
         if isinstance(result, Scalar):
             if matrix:
-                data = {
-                    "resultType": "matrix",
-                    "result": [
-                        {
-                            "metric": {},
-                            "values": [
-                                [t, _fmt_value(v)]
-                                for t, v in zip(ts_sec, result.values)
-                                if not np.isnan(v)
-                            ],
-                        }
-                    ],
-                }
+                (points,) = _points_text(ts_sec, np.reshape(result.values,
+                                                            (1, -1)))
+                kind = "matrix"
+                body = '[{"metric": {}, "values": [%s]}]' % (points or "")
             else:
-                data = {
-                    "resultType": "scalar",
-                    "result": [ts_sec[0], _fmt_value(result.values[0])],
-                }
+                kind = "scalar"
+                body = '[%r, "%s"]' % (float(ts_sec[0]),
+                                       _fmt_value(result.values[0]))
         elif isinstance(result, Vector):
             if matrix:
-                out = []
-                for i, lb in enumerate(result.labels):
-                    values = [
-                        [t, _fmt_value(v)]
-                        for t, v in zip(ts_sec, result.values[i])
-                        if not np.isnan(v)
-                    ]
-                    if values:
-                        out.append(
-                            {
-                                "metric": {
-                                    k.decode(): v.decode() for k, v in lb.items()
-                                },
-                                "values": values,
-                            }
-                        )
-                data = {"resultType": "matrix", "result": out}
+                kind, series = "matrix", '{"metric": %s, "values": [%s]}'
+                rows = (_points_text(ts_sec, result.values)
+                        if result.labels else [])
             else:
-                out = []
-                for i, lb in enumerate(result.labels):
-                    v = result.values[i, 0]
-                    if not np.isnan(v):
-                        out.append(
-                            {
-                                "metric": {
-                                    k.decode(): val.decode() for k, val in lb.items()
-                                },
-                                "value": [ts_sec[0], _fmt_value(v)],
-                            }
-                        )
-                data = {"resultType": "vector", "result": out}
+                kind, series = "vector", '{"metric": %s, "value": %s}'
+                rows = (_points_text(ts_sec[:1], result.values[:, :1])
+                        if result.labels else [])
+            body = "[%s]" % ", ".join([
+                series % (_metric_text(lb), points)
+                for lb, points in zip(result.labels, rows) if points])
         else:
-            data = {"resultType": "string", "result": [ts_sec[0], result.value]}
-        doc = {"status": "success", "data": data}
+            kind = "string"
+            body = "[%r, %s]" % (float(ts_sec[0]), json.dumps(result.value))
+        doc = ('{"status": "success", "data": {"resultType": "%s", '
+               '"result": %s}' % (kind, body))
         engine = engine or self.engine
+        # what json.dumps writes after `data`, spliced in without its braces
+        extra = {}
         # prometheus envelope convention: a top-level "warnings" list
         # accompanies a SUCCEEDING partial result (mirrors M3-Warnings)
         warns = getattr(engine, "last_warnings", None)
         if warns:
-            doc["warnings"] = [str(w) for w in warns]
+            extra["warnings"] = [str(w) for w in warns]
         # per-query stats (series matched, blocks read, bytes decoded,
         # cache hit/miss, decode rungs, stage timings) ride the envelope
         stats = getattr(engine, "last_stats", None)
         if stats is not None:
-            doc["stats"] = stats.to_dict()
+            extra["stats"] = stats.to_dict()
         # ?explain= : the resolved plan tree (with per-stage timings,
         # dispatch rungs and per-node legs under analyze) rides along
         if explain_doc is not None:
-            doc["explain"] = explain_doc
-        return json.dumps(doc).encode()
+            extra["explain"] = explain_doc
+        if extra:
+            doc += ", " + json.dumps(extra)[1:-1]
+        return (doc + "}").encode()
 
     def _time_range(self, q):
         ns = self.db.namespaces[self.namespace]
